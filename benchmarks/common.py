@@ -67,14 +67,12 @@ def spec_metadata(spec) -> Dict[str, Any]:
     JSON-able FLConfig knob (None / int / [clients, model]);
     ``mesh_shape`` the resolved (clients, model) pair (None-spec resolves
     to every local device on the client axis, matching ``make_fl_mesh``);
-    ``fused_kernels`` the raw tri-state knob; ``kernel_variant`` the
-    active fused sparse-decision kernel (the ``REPRO_LBGM_TWO_PASS_TOPK``
-    Mosaic-safety env knob); ``codec``/``codec_kw`` the wire codec. Rows
+    ``fused_kernels`` the raw tri-state knob; ``codec``/``codec_kw`` the
+    wire codec. Rows
     from different PRs stay diffable because the path is in the row, not
     in the CI log."""
     import jax
 
-    from repro.kernels.ops import _default_two_pass
     fl = spec.fl
     shape = fl.mesh_shape
     if shape is None and fl.scheduler == "sharded":
@@ -83,8 +81,6 @@ def spec_metadata(spec) -> Dict[str, Any]:
         "mesh": fl.mesh,
         "mesh_shape": list(shape) if shape is not None else None,
         "fused_kernels": fl.fused_kernels,
-        "kernel_variant": ("two_pass_topk" if _default_two_pass()
-                          else "one_pass_topk"),
         "scheduler": fl.scheduler,
         "model_sharding": fl.model_sharding,
         "codec": fl.codec,

@@ -23,6 +23,8 @@ def main() -> None:
                          "every code path incl. the BENCH_engine.json "
                          "trajectory, makes no perf claims")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     want = set(args.only.split(",")) if args.only else None
 
     def on(name):

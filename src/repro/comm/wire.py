@@ -66,10 +66,8 @@ WIRE_KEY = "_wire_seed"
 #: e4m3 largest finite magnitude (S.1111.110 = 1.75 * 2^8)
 E4M3_MAX = 448.0
 
-# fp8 storage dtype for the wire representation; fall back to fp32 (the
-# grid values are identical — only the buffer dtype widens) on jax builds
-# without ml_dtypes' float8
-_F8 = getattr(jnp, "float8_e4m3fn", jnp.float32)
+#: fp8 storage dtype for the wire representation
+_F8 = jnp.float8_e4m3fn
 
 
 def codec_rng(seed: int) -> np.random.RandomState:
@@ -155,7 +153,8 @@ class WireCodec:
     :meth:`encode_dense` at the tail of ``client_fn`` — after the uplink
     pipeline and the LBGM store step, i.e. on exactly what would be
     serialized — and the aggregator seam dequantizes via
-    :meth:`decode_leaf` (or the fused dequant-accumulate kernel).
+    :meth:`decode_leaf` (or inside the dequantizing scatter-accumulate,
+    ``kernels.ref.lbgm_dequant_accum_ref``).
     """
 
     name = "none"

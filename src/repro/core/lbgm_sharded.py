@@ -26,18 +26,6 @@ from repro.core.lbgm import (LBGMStats, _block_layout, _to_blocks,
                              decision_from_scalars, topk_step_core,
                              topk_uplink_stats)
 
-# newer jax promotes shard_map to the top level; on the 0.4.x line it
-# lives in jax.experimental. The replication-check kwarg was also renamed
-# (check_rep -> check_vma) on its own schedule, so detect it by signature.
-import inspect as _inspect
-
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-_SM_KW = ({"check_vma": False}
-          if "check_vma" in _inspect.signature(_shard_map).parameters
-          else {"check_rep": False})
-
 
 def _spec_axes(spec: P) -> Tuple[str, ...]:
     out = []
@@ -109,7 +97,7 @@ def make_local_topk_step(delta: float, k_frac: float, *, corr=None,
       of the round is the server aggregate's psum.
 
     ``sparse_out`` / ``fused`` pass through to :func:`topk_step_core`
-    (sparse scalar-round aggregation payload / one-pass Pallas decision).
+    (sparse scalar-round aggregation payload / fused Pallas decision).
     """
     def step(grads, lbg):
         return topk_step_core(grads, lbg, delta, k_frac, corr=corr,
@@ -299,8 +287,8 @@ def make_sharded_topk_step(cfg, mesh: Mesh, gspecs: Dict[str, P],
                                     psum_axes=all_axes, out_dtypes=True)
 
     stat_spec = LBGMStats(*([P()] * 5))
-    return _shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(gspecs, lbg_specs),
         out_specs=(gspecs, lbg_specs, stat_spec),
-        **_SM_KW)
+        check_vma=False)

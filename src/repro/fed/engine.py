@@ -190,7 +190,7 @@ with one power-of-two scale per block row. Encoding happens in
 server-decodable values, so recycle rounds stay deployment-faithful);
 decoding happens at the aggregator seam — for quantized streaming
 aggregation the dequantize is fused into the scatter-accumulate
-(:class:`SparseCodecAggregator` -> ``kernels.ops.lbgm_dequant_accum``), so
+(:class:`SparseCodecAggregator` -> ``kernels.ref.lbgm_dequant_accum_ref``), so
 no fp32 payload stack is ever materialized. Per-client ``wire_bytes`` ride
 the scheduler outputs next to ``uplink`` and land in the
 :class:`~repro.comm.accounting.CommLedger` (savings vs vanilla fp32 dense).
@@ -213,8 +213,7 @@ from repro.comm.accounting import CommLedger
 from repro.comm.wire import WIRE_KEY, codec_rng, make_codec
 from repro.compression import make_uplink_pipeline
 from repro.core import lbgm as lbgm_lib
-from repro.core.lbgm_sharded import (_SM_KW, _shard_map,
-                                     bank_model_partition,
+from repro.core.lbgm_sharded import (bank_model_partition,
                                      make_local_topk_step,
                                      make_mesh_topk_step)
 from repro.core.tree_math import tree_size, tree_zeros_like
@@ -228,7 +227,6 @@ from repro.fed.registry import (LBG_STORES, SCHEDULERS, register_lbg_store,
 from repro.fed.robust import (CollectDenseAggregator,
                               CollectSparseAggregator,
                               ScalarMedianSparseAggregator, make_robust_rule)
-from repro.kernels.ops import lbgm_dequant_accum
 from repro.kernels.ref import lbgm_dequant_accum_ref
 
 #: reserved batch key: per-client local-step budgets (the buffered
@@ -568,24 +566,21 @@ class SparseCodecAggregator(SparseTopKAggregator):
     :class:`SparseTopKAggregator`, but each client's payload arrives in
     the wire layout ``{idx, val (int8/fp8), scale}`` and the dequantize
     (widen + per-block-row scale multiply) happens *inside* the
-    accumulate: one ``kernels.ops.lbgm_dequant_accum`` Pallas pass per
-    leaf per chunk when ``fused=True``, the ``lbgm_dequant_accum_ref``
-    XLA scan otherwise (bit-identical op order — the interpreted kernel
-    is validated against exactly that oracle). Either way the fp32
-    (C, nb, kb) payload stack is never materialized — the values widen
-    on the fly as they scatter into the fp32 accumulator.
+    accumulate: one ``kernels.ref.lbgm_dequant_accum_ref`` gather-modify-
+    scatter scan per leaf per chunk, so the fp32 (C, nb, kb) payload stack
+    is never materialized — the values widen on the fly as they scatter
+    into the fp32 accumulator. Plain XLA on every backend: its work is
+    O(C·nb·kb), which a dense one-hot Pallas fold (O(C·nb·block)) cannot
+    beat.
     """
 
     payload_keys = ("idx", "val", "scale")
 
-    def __init__(self, params, k_frac: float, fused: bool = False):
-        super().__init__(params, k_frac)
-        self._accum = lbgm_dequant_accum if fused else lbgm_dequant_accum_ref
-
     def accumulate(self, acc, w, out):
         send, gscale = out   # idx/val (C, nb, kb); scale (C, nb, 1)
-        return {name: self._accum(acc[name], w, gscale, sk["idx"],
-                                  sk["val"], sk["scale"])
+        return {name: lbgm_dequant_accum_ref(acc[name], w, gscale,
+                                             sk["idx"], sk["val"],
+                                             sk["scale"])
                 for name, sk in send.items()}
 
 
@@ -606,7 +601,7 @@ def make_aggregator(cfg: FLConfig, store, params, codec):
     and reduced once per round (see ``repro.fed.robust``).
 
     The *codec* is the third axis: a lossy codec hands sparse payloads to
-    the fused dequant-accumulate (:class:`SparseCodecAggregator`) on the
+    the dequantizing scatter-accumulate (:class:`SparseCodecAggregator`) on the
     streaming path, and hands the collect adapters its ``decode_leaf`` /
     ``payload_keys`` so the robust rules see fp32 values again. The
     ``scalar_median`` rule additionally demands the sparse payload
@@ -626,9 +621,7 @@ def make_aggregator(cfg: FLConfig, store, params, codec):
     if getattr(rule, "streaming", False):
         if sparse:
             if codec.lossy:
-                return SparseCodecAggregator(
-                    params, store.k_frac,
-                    fused=resolve_fused_kernels(cfg)), True
+                return SparseCodecAggregator(params, store.k_frac), True
             return store.make_aggregator(params), True
         return DenseAggregator(), False
     if getattr(rule, "scalar_structured", False):
@@ -1168,14 +1161,14 @@ class ShardedScheduler(ChunkedScheduler):
                         blk=layouts[name][1]:
                         lbgm_lib._to_blocks(g, nb, blk))(asg[name])
                     for name in asg}
-                manual = _shard_map(
+                manual = jax.shard_map(
                     manual_fn, mesh=mesh,
                     in_specs=(acc_specs,
                               {name: blk_spec(name) for name in blocked},
                               lbg_specs, cl,
                               jax.tree.map(lambda _: cl, thru), cl, cl),
                     out_specs=(acc_specs, lbg_specs, cl, cl, cl),
-                    **_SM_KW)
+                    check_vma=False)
                 acc, nl, uplink, scalar, wire = manual(
                     acc, blocked, l, cost, thru, w_c, m_c)
                 return (acc, nl, _keep_sampled(m_c, nr, r), loss, uplink,
@@ -1202,11 +1195,11 @@ class ShardedScheduler(ChunkedScheduler):
                             cl)
             else:
                 gt_specs = cl
-            sharded_chunk = _shard_map(
+            sharded_chunk = jax.shard_map(
                 local_chunk, mesh=self.mesh,
                 in_specs=(rep, cl, lbg_specs, cl, cl, cl),
                 out_specs=(gt_specs, lbg_specs, cl, cl, cl, cl, cl),
-                **_SM_KW)
+                check_vma=False)
         else:
             def local_chunk(acc, p, b, l, r, w_c, m_c):
                 gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
@@ -1226,11 +1219,11 @@ class ShardedScheduler(ChunkedScheduler):
                         _keep_sampled(m_c, nr, r), loss, uplink, scalar,
                         wire)
 
-            sharded_chunk = _shard_map(
+            sharded_chunk = jax.shard_map(
                 local_chunk, mesh=self.mesh,
                 in_specs=(acc_specs, rep, cl, lbg_specs, cl, cl, cl),
                 out_specs=(acc_specs, lbg_specs, cl, cl, cl, cl, cl),
-                **_SM_KW)
+                check_vma=False)
 
         idx_at = lambda t, i: jax.tree.map(
             lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False),
@@ -1595,6 +1588,32 @@ class FLEngine:
 
         return client_update
 
+    def _sparse_encoder(self):
+        """``codec.encode_sparse`` for this engine's bank placement.
+
+        Where the sharded scheduler splits a leaf's block rows over the
+        model axis, each model rank encodes only its rows, so the codec's
+        byte count covers a 1/n_model share of that leaf: sum those
+        leaves' full-round bytes over the model axis (replicated leaves
+        count once), so every mesh shape reports the bytes one client
+        ships."""
+        codec = self.codec
+        sharded = getattr(self.sched, "_msharded", None) or {}
+        if not any(sharded.values()):
+            return codec.encode_sparse
+        axis = self.sched.MODEL_AXIS
+
+        def encode(out, new_lbg, stats, seed):
+            out, new_lbg, _ = codec.encode_sparse(out, new_lbg, stats, seed)
+            part = lambda on: {n: s for n, s in out[0].items()
+                               if sharded[n] == on}
+            full = (jax.lax.psum(codec.sparse_full_bytes(part(True)), axis)
+                    + codec.sparse_full_bytes(part(False)))
+            return out, new_lbg, jnp.where(stats.sent_scalar,
+                                           codec.scalar_bytes, full)
+
+        return encode
+
     def _build_client_halves(self):
         """``client_fn`` split at the decision seam, for the sharded
         scheduler's ``model_sharding="auto"`` path.
@@ -1612,7 +1631,7 @@ class FLEngine:
         store = self.store
         pipeline = self._pipeline
         attack = self._payload_attack
-        codec = self.codec
+        encode = self._sparse_encoder()
         client_update = self._make_client_update()
         blocked_step = store.blocked_sparse_step(self._auto_layouts)
 
@@ -1634,8 +1653,7 @@ class FLEngine:
             gt, lbg_k, stats = blocked_step(blocked_k, lbg_k)
             uplink = jnp.where(stats.sent_scalar, 1.0,
                                store.full_round_cost(cost, stats))
-            gt, lbg_k, wire = codec.encode_sparse(gt, lbg_k, stats,
-                                                  thru.get(WIRE_KEY))
+            gt, lbg_k, wire = encode(gt, lbg_k, stats, thru.get(WIRE_KEY))
             return gt, lbg_k, uplink, stats.sent_scalar, wire
 
         return pre, post
@@ -1649,6 +1667,7 @@ class FLEngine:
         sparse = self._sparse_agg
         attack = self._payload_attack
         codec = self.codec
+        encode = self._sparse_encoder()
         # the legacy dense-aggregation oracle over a top-k store ships the
         # same conceptual (idx, val) payload as the sparse path, so its
         # wire bytes come from the store's static block layout — the two
@@ -1695,8 +1714,7 @@ class FLEngine:
             # (and, for lossy codecs, re-point the bank at the values the
             # server will decode) + account the real bytes on the wire
             if sparse:
-                gt, lbg_k, wire = codec.encode_sparse(gt, lbg_k, stats,
-                                                      wire_seed)
+                gt, lbg_k, wire = encode(gt, lbg_k, stats, wire_seed)
             elif sparse_wire is not None:
                 wire = jnp.where(stats.sent_scalar, codec.scalar_bytes,
                                  sparse_wire)
@@ -2333,7 +2351,9 @@ class RoundPrefetcher:
             if self._err is not None and self._q.empty():
                 raise RuntimeError(
                     "round prefetch thread failed") from self._err
-            if self._stop.is_set() and self._q.empty():
+            # not "and empty": a put() blocked on a full queue while close()
+            # drained it lands afterwards, leaving a stale item behind
+            if self._stop.is_set():
                 raise RuntimeError("RoundPrefetcher used after close()")
             try:
                 item = self._q.get(timeout=0.05)

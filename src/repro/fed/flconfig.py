@@ -9,8 +9,8 @@ shared defaults are read from ``FLConfig``'s fields and it converts via
 ``LBGMConfig.to_fl()`` / ``FLConfig.from_lbgm()``), so the two can no
 longer drift.
 
-``fused_kernels`` gates the engine's fused decision hot path (one-pass
-Pallas projection/decision kernels + sparse scalar-round aggregation);
+``fused_kernels`` gates the engine's fused decision hot path (Pallas
+projection/decision kernels + sparse scalar-round aggregation);
 like every other field it is a plain JSON value (``None``/``true``/
 ``false``) and round-trips losslessly through ``to_dict``/``from_dict``
 and any ``ExperimentSpec`` embedding it.
@@ -101,7 +101,7 @@ class FLConfig:
     fused_kernels: Optional[bool] = None
     # ^ the LBGM decision hot path. None (default) = auto: sparse
     #   scalar-round aggregation wherever the LBG store supports it (any
-    #   backend) + one-pass Pallas decision kernels on TPU only (XLA
+    #   backend) + the Pallas decision kernels on TPU only (XLA
     #   fallback elsewhere). True forces the Pallas kernels on too
     #   (interpret mode off-TPU — for testing). False = the legacy dense
     #   path: per-client dense g_tilde scatter + 3-pass XLA decision,

@@ -90,6 +90,7 @@ import json
 import sys
 from typing import Optional
 
+from repro.compile_cache import enable_compile_cache
 from repro.fed.experiment import (ComponentSpec, EvalPolicy, ExperimentSpec,
                                   run_experiment)
 from repro.fed.flconfig import FLConfig
@@ -146,6 +147,7 @@ def main(argv: Optional[list] = None) -> int:
                          "the uninterrupted run's")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     spec = (ExperimentSpec.load(args.spec) if args.spec else default_spec())
     overrides = parse_set(args.sets)
     if overrides:

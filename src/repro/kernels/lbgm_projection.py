@@ -8,16 +8,16 @@ kernel fuses them into ONE pass (each operand read exactly once), with
 block (TPU grid is sequential, so across-step accumulation into the same
 output block is well-defined).
 
-Two entry points:
-
-* :func:`lbgm_projection_pallas` — one (g, l) pair of flat vectors.
-* :func:`lbgm_projection_batched_pallas` — a stack of B pairs with a LEADING
-  BATCH GRID DIMENSION ``grid=(B, tiles)``: the client axis of the FL
-  engine's schedulers maps straight onto grid dim 0, so one ``pallas_call``
-  covers a whole vmap'd client block (``kernels.ops.lbgm_projection``
-  routes ``jax.vmap`` here through a ``custom_vmap`` rule). The tile loop
-  (dim 1) is innermost, so the per-row accumulator init at ``tile == 0``
-  stays correct under the sequential TPU grid.
+:func:`lbgm_projection_batched_pallas` takes a stack of B pairs with a
+LEADING BATCH GRID DIMENSION ``grid=(B, tiles)``: the client axis of the FL
+engine's schedulers maps straight onto grid dim 0, so one ``pallas_call``
+covers a whole vmap'd client block (``kernels.ops.lbgm_projection`` routes
+``jax.vmap`` here through a ``custom_vmap`` rule). The batch dim of every
+block is squeezed, so each block's last two dims are (BLOCK_R, 128) tiles or
+the whole (1, 128) accumulator row — the shapes Mosaic accepts. The tile
+loop (dim 1) is innermost, so the per-row accumulator init at ``tile == 0``
+stays correct under the sequential TPU grid.
+:func:`lbgm_projection_pallas` is the B = 1 view.
 """
 from __future__ import annotations
 
@@ -33,67 +33,9 @@ LANES = 128       # TPU lane width
 
 
 def _proj_kernel(g_ref, l_ref, out_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    g = g_ref[...].astype(jnp.float32)
-    l = l_ref[...].astype(jnp.float32)
-    gl = jnp.sum(g * l)
-    gg = jnp.sum(g * g)
-    ll = jnp.sum(l * l)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
-    vec = (jnp.where(lane == 0, gl, 0.0) + jnp.where(lane == 1, gg, 0.0)
-           + jnp.where(lane == 2, ll, 0.0))
-    out_ref[...] += vec
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def lbgm_projection_pallas(g: jax.Array, l: jax.Array,
-                           interpret: Optional[bool] = None):
-    """g, l: flat 1-D arrays (any float dtype), same length.
-    Returns (gl, gg, ll) fp32 scalars.
-
-    ``interpret=None`` auto-detects the backend (compiled Mosaic on TPU,
-    interpreter elsewhere) — same policy as the ``ops.py`` wrappers, so
-    direct callers no longer silently run the interpreter on real TPUs.
-    """
-    if interpret is None:
-        from repro.kernels.ops import _default_interpret
-        interpret = _default_interpret()
-    assert g.ndim == 1 and g.shape == l.shape
-    n = g.shape[0]
-    tile = BLOCK_R * LANES
-    pad = (-n) % tile
-    if pad:
-        g = jnp.pad(g, (0, pad))
-        l = jnp.pad(l, (0, pad))
-    rows = (n + pad) // LANES
-    g2 = g.reshape(rows, LANES)
-    l2 = l.reshape(rows, LANES)
-    grid = rows // BLOCK_R
-    out = pl.pallas_call(
-        _proj_kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((BLOCK_R, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_R, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, LANES), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, LANES), jnp.float32),
-        interpret=interpret,
-    )(g2, l2)
-    return out[0, 0], out[0, 1], out[0, 2]
-
-
-def _proj_kernel_batched(g_ref, l_ref, out_ref):
-    # grid = (B, tiles); dim 1 (tiles) is innermost, so each batch row's
-    # accumulator is initialized once and then swept over all of its tiles
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
+    # grid = (B, tiles); refs are the squeezed (BLOCK_R, LANES) tiles and
+    # the batch row's (1, LANES) accumulator
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -114,9 +56,8 @@ def lbgm_projection_batched_pallas(g: jax.Array, l: jax.Array,
     """g, l: (B, n) stacks of flat vectors (any float dtype).
     Returns (gl, gg, ll) fp32 arrays of shape (B,) — one fused pass per row.
 
-    The batch axis is grid dimension 0, so the same compiled kernel serves
-    any client-block size; each row accumulates into its own (1, LANES)
-    output block exactly like the unbatched kernel.
+    ``interpret=None`` auto-detects the backend (compiled Mosaic on TPU,
+    the interpreter elsewhere).
     """
     if interpret is None:
         from repro.kernels.ops import _default_interpret
@@ -131,16 +72,29 @@ def lbgm_projection_batched_pallas(g: jax.Array, l: jax.Array,
     rows = (n + pad) // LANES
     g3 = g.reshape(B, rows, LANES)
     l3 = l.reshape(B, rows, LANES)
-    tiles = rows // BLOCK_R
     out = pl.pallas_call(
-        _proj_kernel_batched,
-        grid=(B, tiles),
+        _proj_kernel,
+        grid=(B, rows // BLOCK_R),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_R, LANES), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, BLOCK_R, LANES), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((pl.squeezed, BLOCK_R, LANES),
+                         lambda b, i: (b, i, 0)),
+            pl.BlockSpec((pl.squeezed, BLOCK_R, LANES),
+                         lambda b, i: (b, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, LANES), lambda b, i: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, LANES), jnp.float32),
+        out_specs=pl.BlockSpec((pl.squeezed, 1, LANES),
+                               lambda b, i: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, LANES), jnp.float32),
         interpret=interpret,
+        name="lbgm_projection",
     )(g3, l3)
-    return out[:, 0], out[:, 1], out[:, 2]
+    return out[:, 0, 0], out[:, 0, 1], out[:, 0, 2]
+
+
+def lbgm_projection_pallas(g: jax.Array, l: jax.Array,
+                           interpret: Optional[bool] = None):
+    """g, l: flat 1-D arrays (any float dtype), same length.
+    Returns (gl, gg, ll) fp32 scalars."""
+    assert g.ndim == 1 and g.shape == l.shape
+    gl, gg, ll = lbgm_projection_batched_pallas(g[None], l[None],
+                                                interpret=interpret)
+    return gl[0], gg[0], ll[0]

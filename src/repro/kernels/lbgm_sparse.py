@@ -7,33 +7,33 @@ separate passes over each dense gradient leaf per client:
 2. ``jnp.vdot(g, g)``     — the squared norm for the sin^2 test,
 3. ``leaf_topk``          — |g| + block-wise top-k for the refresh branch.
 
-This kernel fuses all three into ONE pass over the (nb, block) block layout:
-each grid step reads one block row of g exactly once and emits that row's
-partial ||g||^2, the gathered values at the LBG's block-local indices, and
-the row's top-k candidates (signed values + indices). The engine-facing
-entry has a LEADING BATCH GRID DIMENSION ``grid=(B, nb)`` so the client
-axis of a vmap'd scheduler block maps straight onto grid dim 0
+This kernel fuses all three into ONE read of the (nb, block) block layout:
+each grid step loads ``ROWS`` block rows of g once and emits their partial
+||g||^2, the gathered values at the LBG's block-local indices, and each
+row's top-k entries (signed values + indices). The engine-facing entry has
+a LEADING BATCH GRID DIMENSION ``grid=(B, nb / ROWS)`` so the client axis
+of a vmap'd scheduler block maps straight onto grid dim 0
 (``kernels.ops.lbgm_sparse_decision`` routes ``jax.vmap`` here via a
-``custom_vmap`` rule); ``nb`` is innermost so the per-row ||g||^2
-accumulator init at ``row == 0`` is correct under the sequential TPU grid.
+``custom_vmap`` rule); the row axis is innermost so the per-client ||g||^2
+accumulator init at ``j == 0`` is correct under the sequential TPU grid.
 
-Validated against ``kernels/ref.py`` in interpret mode (tests); on TPU the
-win is structural — one HBM read of g instead of three.
+The body needs no sort and no dynamic gather, neither of which Mosaic
+lowers:
 
-Mosaic-safety fallback (ROADMAP open item): the default kernel leans on
-``lax.top_k`` and ``take_along_axis`` *inside* the kernel body, whose
-Mosaic lowering has not been exercised on real TPU hardware. The
-``two_pass`` variant below removes both: pass 1 bisects the per-row
-top-k |value| threshold exactly — in int32 IEEE bit space, so every
-magnitude regime resolves — with nothing but bitcasts, compares and
-sums; pass 2
-compacts the selected entries (and gathers the LBG positions) with tiled
-one-hot matmuls — iota / compare / select / dot / fori_loop only, the
-op set Mosaic lowers everywhere. Same one-HBM-read structure, same
-outputs as a *set* per row (slot order is by index, not descending
-value; every consumer treats the (idx, val) pairs as a set). Enable with
-``REPRO_LBGM_TWO_PASS_TOPK=1`` (see ``kernels.ops.lbgm_sparse_decision``)
-if the default kernel fails to compile or mis-lowers on hardware.
+* pass 1 bisects each row's kb-th largest |value| exactly, in int32 IEEE
+  bit space (monotone in value for non-negative floats), with nothing but
+  bitcasts, compares and sums — all rows of the step at once;
+* pass 2 sweeps the row in lane tiles loaded straight from the ref and
+  compacts the selected entries (and gathers the LBG positions) with
+  one-hot matmuls at HIGHEST precision, so the selected values and
+  positions come out exact.
+
+The per-row (idx, val) SET equals ``lax.top_k``'s, ties included (ties
+fill in index order — ``lax.top_k``'s lowest-index rule); slot order is by
+index, not by descending value, and every consumer treats the pairs as a
+set. Block dims are squeezed over the batch axis and tiled over rows, and
+the lane axes are padded to 128, so every block satisfies Mosaic's (8, 128)
+rule.
 """
 from __future__ import annotations
 
@@ -44,34 +44,139 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
+#: block rows per grid step (one f32 sublane tile); a leaf with fewer rows
+#: is taken whole (a block dim equal to the array's is legal), one with more
+#: is padded to a multiple with zero rows, so a step never holds more
+ROWS = 8
+#: pass-2 compaction tile (lanes per one-hot matmul)
+TILE = 256
+#: bisection steps for the per-row top-k threshold. 32 integer halvings of
+#: [-1, bits(max)] always terminate with lo/hi ADJACENT — hi is exactly the
+#: kb-th largest |value|'s bit pattern and the tie band holds only exact
+#: ties, at every magnitude (a float-interval bisection has absolute
+#: resolution ~max/2^iters and silently mis-selects rows whose |values| all
+#: sit below it)
+BISECT_ITERS = 32
 
-def _sparse_decision_kernel(g_ref, idx_ref, gg_ref, gath_ref, ti_ref,
-                            tv_ref):
-    # grid = (B, nb); dim 1 (block rows) is innermost
-    j = pl.program_id(1)
+_NT = (((1,), (1,)), ((), ()))      # contract the lane axes: a @ b.T
+_HI = jax.lax.Precision.HIGHEST
 
-    @pl.when(j == 0)
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _decision_kernel(g_ref, idx_ref, gg_ref, gath_ref, ti_ref, tv_ref, *,
+                     kb: int, tile: int, iters: int):
+    """One (client, row-group) grid step.
+
+    Refs: g (R, Bp) f32 rows; idx (R, kbp) int32 LBG positions (-1 pads);
+    gg (1, LANES) per-client accumulator; gath/ti/tv (R, kbp) f32 outputs.
+    """
+    R, Bp = g_ref.shape
+    kbp = idx_ref.shape[1]
+
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         gg_ref[...] = jnp.zeros_like(gg_ref)
 
-    g = g_ref[...].reshape(1, -1).astype(jnp.float32)   # (1, block)
-    idx = idx_ref[...].reshape(1, -1)                   # (1, kb)
-    kb = idx.shape[1]
-    # one read of g feeds all three outputs
-    gg_ref[...] += jnp.sum(g * g).reshape(1, 1)
-    gath_ref[...] = jnp.take_along_axis(g, idx, axis=1).reshape(1, 1, kb)
-    _, ti = jax.lax.top_k(jnp.abs(g), kb)
-    ti_ref[...] = ti.astype(jnp.int32).reshape(1, 1, kb)
-    tv_ref[...] = jnp.take_along_axis(g, ti, axis=1).reshape(1, 1, kb)
+    g = g_ref[...]
+    gg_ref[...] += jnp.sum(g * g)
+
+    # ---- pass 1: bisect each row's kb-th largest |value| t* into (lo, hi]
+    # in bit space. Invariant: count(ai > lo) >= kb > count(ai > hi)
+    # (ai >= 0, so the initial lo = -1 counts Bp >= kb; nothing exceeds
+    # the max). lo + (hi - lo) // 2, NOT (lo + hi) // 2: bit patterns of
+    # values >= 2.0 exceed 2^30, so the naive midpoint overflows int32.
+    ai = jax.lax.bitcast_convert_type(jnp.abs(g), jnp.int32)
+    top = jax.lax.bitcast_convert_type(
+        jnp.max(jnp.abs(g), axis=1, keepdims=True), jnp.int32)
+
+    def bis(_, lh):
+        lo, hi = lh
+        mid = lo + ((hi - lo) >> 1)
+        big = jnp.sum((ai > mid).astype(jnp.float32), axis=1,
+                      keepdims=True) >= kb
+        return jnp.where(big, mid, lo), jnp.where(big, hi, mid)
+
+    lo, hi = jax.lax.fori_loop(
+        0, iters, bis, (jnp.full((R, 1), -1, jnp.int32), top))
+    # "definite" entries sit strictly above the band; ties (== t*) fill
+    # the remaining slots in index order. For rows with fewer than kb
+    # nonzeros the band is the zeros, so every nonzero is still kept.
+    m = jnp.sum((ai > hi).astype(jnp.float32), axis=1, keepdims=True)
+
+    # ---- pass 2: tiled compaction + gather (one-hot matmuls)
+    gath_ref[...] = jnp.zeros_like(gath_ref)
+    ti_ref[...] = jnp.zeros_like(ti_ref)
+    tv_ref[...] = jnp.zeros_like(tv_ref)
+    # inclusive-cumsum operator tri[i, j] = (i <= j); 0/1 is exact in bf16
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+           ).astype(jnp.bfloat16)
+    # 1-indexed output slot of each one-hot row; pad slots (>= kb) never
+    # match, which is also the cap for selections past kb
+    srow = jax.lax.broadcasted_iota(jnp.int32, (kbp, tile), 0)
+    slot_id = jnp.where(srow < kb, srow + 1, -1).astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (tile, kbp), 0)
+    idx = idx_ref[...]
+
+    def tl(t, carry):
+        cdef, ctie = carry
+        off = pl.multiple_of(t * tile, tile)
+        g_t = g_ref[:, pl.ds(off, tile)]                  # (R, T)
+        # classify in the same bit space the threshold lives in (lo may be
+        # -1, which is not a valid float to compare against)
+        ai_t = jax.lax.bitcast_convert_type(jnp.abs(g_t), jnp.int32)
+        dm = ai_t > hi
+        sm = (ai_t > lo) & (ai_t <= hi)
+        cum_d = jnp.dot(dm.astype(jnp.bfloat16), tri,
+                        preferred_element_type=jnp.float32) + cdef
+        cum_t = jnp.dot(sm.astype(jnp.bfloat16), tri,
+                        preferred_element_type=jnp.float32) + ctie
+        # definites take slots 1..m (m < kb), ties m+1..; 0 = unselected
+        slot = jnp.where(dm, cum_d, jnp.where(sm, m + cum_t, 0.0))
+        pos = (lane + off).astype(jnp.float32)            # (1, T)
+        hit = (sub + off)                                 # (T, kbp)
+        for r in range(R):
+            g_r = g_t[r:r + 1, :]
+            oh = (slot[r:r + 1, :] == slot_id).astype(jnp.float32)
+            tv_ref[r:r + 1, :] += jax.lax.dot_general(
+                g_r, oh, _NT, precision=_HI,
+                preferred_element_type=jnp.float32)
+            ti_ref[r:r + 1, :] += jax.lax.dot_general(
+                pos, oh, _NT, precision=_HI,
+                preferred_element_type=jnp.float32)
+            # gather at the LBG positions (positions < 2^24, exact in f32)
+            oh2 = (hit == idx[r:r + 1, :]).astype(jnp.float32)
+            gath_ref[r:r + 1, :] += jnp.dot(
+                g_r, oh2, precision=_HI,
+                preferred_element_type=jnp.float32)
+        return (cdef + jnp.sum(dm.astype(jnp.float32), axis=1, keepdims=True),
+                ctie + jnp.sum(sm.astype(jnp.float32), axis=1, keepdims=True))
+
+    zero = jnp.zeros((R, 1), jnp.float32)
+    jax.lax.fori_loop(0, Bp // tile, tl, (zero, zero))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def lbgm_sparse_decision_batched_pallas(blocks: jax.Array, idx: jax.Array,
                                         interpret: Optional[bool] = None):
-    """blocks: (B, nb, block) f32 block-layout gradients; idx: (B, nb, kb)
+    """blocks: (B, nb, block) block-layout gradients; idx: (B, nb, kb)
     int32 block-local LBG positions. Returns
     ``(gg (B,), gathered (B, nb, kb), top_idx (B, nb, kb) int32,
-    top_val (B, nb, kb) f32)`` — each client's row of g read exactly once.
+    top_val (B, nb, kb) f32)`` — each client's rows of g read once, each
+    top-k row emitted in index order.
+
+    The lane axes are padded before the call: g with zeros up to a tile
+    multiple (pass 1's strict compares never select a pad zero ahead of
+    real data — pads sit at the highest positions and a row holds at
+    least ``block >= kb`` real entries — and pads add exact zeros to
+    ||g||^2), idx with -1 up to a multiple of 128 (matches no position).
+    Pad rows (up to a multiple of ``ROWS``) add zeros to ||g||^2 and are
+    sliced off the outputs.
     """
     if interpret is None:
         from repro.kernels.ops import _default_interpret
@@ -80,28 +185,34 @@ def lbgm_sparse_decision_batched_pallas(blocks: jax.Array, idx: jax.Array,
     assert blocks.shape[:2] == idx.shape[:2]
     B, nb, block = blocks.shape
     kb = idx.shape[2]
+    tile = min(TILE, _round_up(block, LANES))
+    Bp = _round_up(block, tile)
+    kbp = _round_up(kb, LANES)
+    rows = min(ROWS, nb)
+    nbp = _round_up(nb, rows)
+    blocks = jnp.pad(blocks.astype(jnp.float32),
+                     ((0, 0), (0, nbp - nb), (0, Bp - block)))
+    idx = jnp.pad(idx.astype(jnp.int32),
+                  ((0, 0), (0, nbp - nb), (0, kbp - kb)), constant_values=-1)
+    kernel = functools.partial(_decision_kernel, kb=kb, tile=tile,
+                               iters=BISECT_ITERS)
+    row_spec = lambda w: pl.BlockSpec((pl.squeezed, rows, w),
+                                      lambda b, j: (b, j, 0))
+    out_rows = jax.ShapeDtypeStruct((B, nbp, kbp), jnp.float32)
     gg, gath, ti, tv = pl.pallas_call(
-        _sparse_decision_kernel,
-        grid=(B, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, block), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, kb), lambda b, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, 1, kb), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, kb), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, kb), lambda b, j: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, nb, kb), jnp.float32),
-            jax.ShapeDtypeStruct((B, nb, kb), jnp.int32),
-            jax.ShapeDtypeStruct((B, nb, kb), jnp.float32),
-        ],
+        kernel,
+        grid=(B, nbp // rows),
+        in_specs=[row_spec(Bp), row_spec(kbp)],
+        out_specs=[pl.BlockSpec((pl.squeezed, 1, LANES),
+                                lambda b, j: (b, 0, 0)),
+                   row_spec(kbp), row_spec(kbp), row_spec(kbp)],
+        out_shape=[jax.ShapeDtypeStruct((B, 1, LANES), jnp.float32),
+                   out_rows, out_rows, out_rows],
         interpret=interpret,
+        name="lbgm_sparse_decision",
     )(blocks, idx)
-    return gg[:, 0], gath, ti, tv
+    return (gg[:, 0, 0], gath[:, :nb, :kb],
+            ti[:, :nb, :kb].astype(jnp.int32), tv[:, :nb, :kb])
 
 
 def lbgm_sparse_decision_pallas(blocks: jax.Array, idx: jax.Array,
@@ -111,253 +222,3 @@ def lbgm_sparse_decision_pallas(blocks: jax.Array, idx: jax.Array,
     gg, gath, ti, tv = lbgm_sparse_decision_batched_pallas(
         blocks[None], idx[None], interpret=interpret)
     return gg[0], gath[0], ti[0], tv[0]
-
-
-# ---------------------------------------- two-pass threshold-select variant
-
-#: pass-2 compaction tile (lanes per one-hot matmul); multiples of 128
-#: keep the dynamic lane slices MXU/VPU aligned
-TWO_PASS_TILE = 512
-#: bisection steps for the per-row top-k threshold. The bisection runs on
-#: the int32 IEEE bit patterns of |g| (monotone in value for non-negative
-#: floats), so 32 integer halvings of [-1, bits(max)] always terminate
-#: with lo/hi ADJACENT — hi is exactly the kb-th largest |value|'s bit
-#: pattern and the tie band holds only exact ties, at every magnitude
-#: (a float-interval bisection has absolute resolution ~max/2^iters and
-#: silently mis-selects rows whose |values| all sit below it)
-TWO_PASS_BISECT_ITERS = 32
-
-
-def _two_pass_kernel(g_ref, idx_ref, gg_ref, gath_ref, ti_ref, tv_ref, *,
-                     tile: int, iters: int):
-    """Sort-free / gather-free fused decision (see module docstring).
-
-    Per (client, block-row) grid step: bisect the row's kb-th largest
-    |value| (pass 1: compares + sums only), then one tiled sweep (pass 2)
-    emits the compacted top-k entries, the values gathered at the LBG
-    positions, and the row's ||g||^2 partial — all through one-hot
-    matmuls, so nothing in the body needs a sort or a dynamic gather.
-    """
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        gg_ref[...] = jnp.zeros_like(gg_ref)
-
-    g = g_ref[...].reshape(1, -1).astype(jnp.float32)   # (1, Bp)
-    idx = idx_ref[...].reshape(1, -1)                   # (1, kb)
-    kb = idx.shape[1]
-    Bp = g.shape[1]
-    a = jnp.abs(g)
-    gg_ref[...] += jnp.sum(g * g).reshape(1, 1)
-
-    # ---- pass 1: bisect t* (the kb-th largest |value|) into (lo, hi] —
-    # in IEEE BIT space: for non-negative f32 the int32 bit pattern is
-    # monotone in value, so integer halvings of [-1, bits(max)] converge
-    # to ADJACENT lo/hi in <= 32 steps. hi is then exactly t*'s bit
-    # pattern: the "tie band" (lo, hi] holds only exact t* ties, for
-    # subnormal-scale rows as much as unit-scale ones. Invariant:
-    # count(ai > lo) >= kb > count(ai > hi) (ai >= 0 everywhere, so the
-    # initial lo = -1 count is Bp >= kb; count(ai > max) == 0 < kb).
-    ai = jax.lax.bitcast_convert_type(a, jnp.int32)     # (1, Bp), >= 0
-
-    def bis(_, lh):
-        lo, hi = lh
-        # lo + (hi - lo)//2, NOT (lo + hi)//2: bit patterns of values
-        # >= 2.0 exceed 2^30, so the naive midpoint overflows int32
-        mid = lo + (hi - lo) // 2
-        big = jnp.sum((ai > mid).astype(jnp.float32)) >= kb
-        return (jnp.where(big, mid, lo), jnp.where(big, hi, mid))
-
-    lo, hi = jax.lax.fori_loop(
-        0, iters, bis, (jnp.int32(-1), jnp.max(ai)))
-    # "definite" entries sit strictly above the band; ties (== t*) fill
-    # the remaining slots in index order — exactly lax.top_k's
-    # lowest-index tie rule, and for rows with fewer than kb nonzeros the
-    # band is the zeros, so every nonzero is still kept
-    m = jnp.sum((ai > hi).astype(jnp.float32))          # < kb by invariant
-
-    # ---- pass 2: tiled compaction + gather (one-hot matmuls)
-    n_tiles = Bp // tile
-    # inclusive-cumsum operator: mask (1, T) @ tri (T, T) with
-    # tri[i, j] = (i <= j)
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
-           <= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
-           ).astype(jnp.float32)
-    slot_iota = jax.lax.broadcasted_iota(jnp.float32, (tile, kb), 1)
-    lane_iota = jax.lax.broadcasted_iota(jnp.float32, (1, tile), 1)
-    idx_f = idx.astype(jnp.float32)
-
-    def tl(t, carry):
-        cdef, ctie, tiv, tvv, gv = carry
-        g_t = jax.lax.dynamic_slice(g, (0, t * tile), (1, tile))
-        # classify in the same bit space the threshold lives in (lo may
-        # be -1, which is not a valid float to compare against)
-        ai_t = jax.lax.bitcast_convert_type(jnp.abs(g_t), jnp.int32)
-        dmask = (ai_t > hi).astype(jnp.float32)         # (1, T)
-        smask = ((ai_t > lo) & (ai_t <= hi)).astype(jnp.float32)
-        cum_d = jax.lax.dot(dmask, tri) + cdef          # running 1-indexed
-        cum_t = jax.lax.dot(smask, tri) + ctie          # rank per class
-        # output slot (1-indexed; 0 = unselected): definites first (their
-        # global count m < kb), then ties; slots > kb match no one-hot
-        # column below, which is the cap
-        slot = dmask * cum_d + smask * (m + cum_t)
-        oh = ((slot[0][:, None] == slot_iota + 1.0)
-              & ((dmask + smask)[0][:, None] > 0)).astype(jnp.float32)
-        pos = jnp.float32(t * tile) + lane_iota         # global positions
-        tvv = tvv + jax.lax.dot(g_t, oh)                # (1, kb)
-        tiv = tiv + jax.lax.dot(pos, oh)
-        # gather at the LBG positions: positions < 2^24, exact in f32
-        oh2 = (pos[0][:, None] == idx_f[0][None, :]).astype(jnp.float32)
-        gv = gv + jax.lax.dot(g_t, oh2)
-        return (cdef + jnp.sum(dmask), ctie + jnp.sum(smask), tiv, tvv, gv)
-
-    zk = jnp.zeros((1, kb), jnp.float32)
-    _, _, tiv, tvv, gv = jax.lax.fori_loop(
-        0, n_tiles, tl, (jnp.float32(0), jnp.float32(0), zk, zk, zk))
-    gath_ref[...] = gv.reshape(1, 1, kb)
-    ti_ref[...] = tiv.astype(jnp.int32).reshape(1, 1, kb)
-    tv_ref[...] = tvv.reshape(1, 1, kb)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def lbgm_sparse_decision_two_pass_batched_pallas(
-        blocks: jax.Array, idx: jax.Array,
-        interpret: Optional[bool] = None):
-    """Two-pass threshold-select twin of
-    :func:`lbgm_sparse_decision_batched_pallas` (same signature, same
-    contract) with the per-row (idx, val) set emitted in *index* order
-    instead of descending |value| — every consumer treats it as a set.
-
-    The lane axis is zero-padded up to a tile multiple before the call;
-    pass 1's strict compares never select a pad zero ahead of real data
-    (pads sit at the highest positions, and a row holds at least
-    ``block >= kb`` real entries), and pad contributions to ||g||^2 are
-    exact zeros.
-    """
-    if interpret is None:
-        from repro.kernels.ops import _default_interpret
-        interpret = _default_interpret()
-    assert blocks.ndim == 3 and idx.ndim == 3
-    assert blocks.shape[:2] == idx.shape[:2]
-    B, nb, block = blocks.shape
-    kb = idx.shape[2]
-    tile = min(TWO_PASS_TILE, block)
-    pad = (-block) % tile
-    if pad:
-        blocks = jnp.pad(blocks, ((0, 0), (0, 0), (0, pad)))
-    Bp = block + pad
-    kernel = functools.partial(_two_pass_kernel, tile=tile,
-                               iters=TWO_PASS_BISECT_ITERS)
-    gg, gath, ti, tv = pl.pallas_call(
-        kernel,
-        grid=(B, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, Bp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, kb), lambda b, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, 1, kb), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, kb), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, kb), lambda b, j: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, nb, kb), jnp.float32),
-            jax.ShapeDtypeStruct((B, nb, kb), jnp.int32),
-            jax.ShapeDtypeStruct((B, nb, kb), jnp.float32),
-        ],
-        interpret=interpret,
-    )(blocks, idx)
-    return gg[:, 0], gath, ti, tv
-
-
-def lbgm_sparse_decision_two_pass_pallas(blocks: jax.Array, idx: jax.Array,
-                                         interpret: Optional[bool] = None):
-    """Unbatched view of the two-pass fused decision."""
-    gg, gath, ti, tv = lbgm_sparse_decision_two_pass_batched_pallas(
-        blocks[None], idx[None], interpret=interpret)
-    return gg[0], gath[0], ti[0], tv[0]
-
-
-# ------------------------------------------ fused dequant + accumulate
-
-def _dequant_accum_kernel(w_ref, gs_ref, idx_ref, qv_ref, sc_ref, acc_ref,
-                          out_ref):
-    """One (nb,) grid step folds all C clients' quantized payload rows
-    into one accumulator block row.
-
-    The payload values arrive in their WIRE dtype (int8 / fp8) and are
-    widened client-by-client inside the kernel — the fused fast path
-    never materializes an fp32 (C, nb, kb) payload buffer. The fold is a
-    strictly sequential fori_loop (same client order as the XLA scan
-    path) of gather-modify-scatter updates: coeff = (w * gscale) * scale
-    is folded before the multiply with the quantized values, exactly the
-    :func:`repro.kernels.ref.lbgm_dequant_accum_ref` op order, so the
-    interpret-mode kernel is bit-identical to the oracle. The ``w > 0``
-    gate keeps phantom pad clients' NaN payloads out of the aggregate
-    (fp8 NaN widens to fp32 NaN — multiplying by a zero coeff is not
-    enough).
-
-    Mosaic caveat (same as the default decision kernel): the body uses
-    ``take_along_axis``/``put_along_axis``; validated in interpret mode,
-    structural one-HBM-pass win on TPU.
-    """
-    row = acc_ref[...].reshape(1, -1)                   # (1, block)
-    C = qv_ref.shape[0]
-
-    def fold(c, r):
-        wc = w_ref[c, 0]
-        coeff = (wc * gs_ref[c, 0]) * sc_ref[c, 0, 0]
-        q = qv_ref[c].reshape(1, -1).astype(jnp.float32)  # (1, kb)
-        ix = idx_ref[c].reshape(1, -1)
-        cur = jnp.take_along_axis(r, ix, axis=1)
-        new = cur + jnp.where(wc > 0, coeff * q, 0.0)
-        return jnp.put_along_axis(r, ix, new, axis=1, inplace=False)
-
-    out_ref[...] = jax.lax.fori_loop(0, C, fold, row).reshape(
-        out_ref.shape)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def lbgm_dequant_accum_pallas(acc: jax.Array, w: jax.Array,
-                              gscale: jax.Array, idx: jax.Array,
-                              qv: jax.Array, scale: jax.Array,
-                              interpret: Optional[bool] = None):
-    """Fused dequantize + scatter-accumulate for one quantized sparse leaf.
-
-    acc: (nb, block) f32 accumulator; w, gscale: (C,) client weights and
-    scalar-round multipliers; idx: (C, nb, kb) int32 block-local
-    positions; qv: (C, nb, kb) wire-dtype quantized values; scale:
-    (C, nb, 1) f32 per-block-row dequantization scales. Returns
-    ``acc + sum_c [w_c > 0] (w_c * gscale_c * scale_c) * f32(qv_c)``
-    scattered at ``idx_c``, clients folded in order. The accumulator
-    input buffer is donated (``input_output_aliases``) — the carry is
-    updated in place across the round's chunk scan.
-    """
-    if interpret is None:
-        from repro.kernels.ops import _default_interpret
-        interpret = _default_interpret()
-    assert idx.ndim == 3 and qv.shape == idx.shape
-    C, nb, kb = idx.shape
-    assert acc.shape[0] == nb and scale.shape == (C, nb, 1)
-    block = acc.shape[1]
-    w2 = w.reshape(C, 1).astype(jnp.float32)
-    gs2 = gscale.reshape(C, 1).astype(jnp.float32)
-    return pl.pallas_call(
-        _dequant_accum_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((C, 1), lambda j: (0, 0)),
-            pl.BlockSpec((C, 1), lambda j: (0, 0)),
-            pl.BlockSpec((C, 1, kb), lambda j: (0, j, 0)),
-            pl.BlockSpec((C, 1, kb), lambda j: (0, j, 0)),
-            pl.BlockSpec((C, 1, 1), lambda j: (0, j, 0)),
-            pl.BlockSpec((1, block), lambda j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
-        input_output_aliases={5: 0},
-        interpret=interpret,
-    )(w2, gs2, idx, qv, scale, acc)

@@ -16,7 +16,6 @@ compiles to ONE batched ``pallas_call`` instead of per-client dispatches.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -25,28 +24,13 @@ from jax.custom_batching import custom_vmap
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.lbgm_projection import (lbgm_projection_batched_pallas,
                                            lbgm_projection_pallas)
-from repro.kernels.lbgm_sparse import (
-    lbgm_dequant_accum_pallas, lbgm_sparse_decision_batched_pallas,
-    lbgm_sparse_decision_pallas,
-    lbgm_sparse_decision_two_pass_batched_pallas,
-    lbgm_sparse_decision_two_pass_pallas)
+from repro.kernels.lbgm_sparse import (lbgm_sparse_decision_batched_pallas,
+                                       lbgm_sparse_decision_pallas)
 from repro.kernels.rwkv6_scan import rwkv6_scan_pallas
-
-#: Mosaic-safety knob for the fused sparse decision: "1" routes
-#: lbgm_sparse_decision through the two-pass threshold-select kernel
-#: (no lax.top_k / take_along_axis inside the kernel body — see
-#: kernels/lbgm_sparse.py). Flip it if the default kernel fails to
-#: compile or mis-lowers on real TPU hardware; no config surgery needed.
-TWO_PASS_ENV = "REPRO_LBGM_TWO_PASS_TOPK"
 
 
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _default_two_pass() -> bool:
-    return os.environ.get(TWO_PASS_ENV, "0").lower() not in (
-        "0", "", "false", "off", "no")
 
 
 def _bcast(x, batched, axis_size):
@@ -73,22 +57,19 @@ def _proj_leaf(interpret: bool):
 
 
 @functools.lru_cache(maxsize=None)
-def _sparse_decision(interpret: bool, two_pass: bool):
+def _sparse_decision(interpret: bool):
     """Fused sparse decision with vmap routed to the batched kernel."""
-    one = (lbgm_sparse_decision_two_pass_pallas if two_pass
-           else lbgm_sparse_decision_pallas)
-    batched = (lbgm_sparse_decision_two_pass_batched_pallas if two_pass
-               else lbgm_sparse_decision_batched_pallas)
 
     @custom_vmap
     def f(blocks, idx):
-        return one(blocks, idx, interpret=interpret)
+        return lbgm_sparse_decision_pallas(blocks, idx, interpret=interpret)
 
     @f.def_vmap
     def _rule(axis_size, in_batched, blocks, idx):
         blocks = _bcast(blocks, in_batched[0], axis_size)
         idx = _bcast(idx, in_batched[1], axis_size)
-        out = batched(blocks, idx, interpret=interpret)
+        out = lbgm_sparse_decision_batched_pallas(blocks, idx,
+                                                  interpret=interpret)
         return out, (True, True, True, True)
 
     return f
@@ -109,32 +90,15 @@ def lbgm_projection(g_tree, l_tree, interpret=None):
     return gl, gg, ll
 
 
-def lbgm_sparse_decision(blocks, idx, interpret=None, two_pass=None):
+def lbgm_sparse_decision(blocks, idx, interpret=None):
     """One fused pass over a (nb, block) gradient block layout: returns
     ``(gg, gathered, top_idx, top_val)`` — the three dense passes of the
     sparse-LBG client step (gather at LBG positions, ||g||^2, block-wise
-    top-k) in a single read of g. vmap over the client axis maps onto the
-    kernel's leading batch grid dimension.
-
-    ``two_pass=None`` reads the ``REPRO_LBGM_TWO_PASS_TOPK`` env knob:
-    the Mosaic-safety fallback that replaces in-kernel ``lax.top_k`` /
-    ``take_along_axis`` with bisection threshold-select + one-hot-matmul
-    compaction (per-row (idx, val) set equal, index-ordered)."""
+    top-k) in a single read of g, each top-k row as an index-ordered set
+    (see ``kernels/lbgm_sparse.py``). vmap over the client axis maps onto
+    the kernel's leading batch grid dimension."""
     interpret = _default_interpret() if interpret is None else interpret
-    two_pass = _default_two_pass() if two_pass is None else two_pass
-    return _sparse_decision(bool(interpret), bool(two_pass))(blocks, idx)
-
-
-def lbgm_dequant_accum(acc, w, gscale, idx, qv, scale, interpret=None):
-    """Fused dequantize + scatter-accumulate of C clients' quantized
-    sparse payload rows into a (nb, block) accumulator leaf (see
-    ``kernels/lbgm_sparse.py``). The wire-dtype (int8/fp8) values widen
-    inside the kernel — no fp32 (C, nb, kb) payload buffer. Called once
-    per leaf per chunk by the engine's quantized sparse aggregator; no
-    vmap routing needed (the client axis is an explicit argument)."""
-    interpret = _default_interpret() if interpret is None else interpret
-    return lbgm_dequant_accum_pallas(acc, w, gscale, idx, qv, scale,
-                                     interpret=bool(interpret))
+    return _sparse_decision(bool(interpret))(blocks, idx)
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, interpret=None):

@@ -28,14 +28,14 @@ def lbgm_sparse_decision_ref(blocks: jax.Array, idx: jax.Array):
 
 def lbgm_dequant_accum_ref(acc: jax.Array, w: jax.Array, gscale: jax.Array,
                            idx: jax.Array, qv: jax.Array, scale: jax.Array):
-    """Sequential dequantize + scatter-accumulate (the fused kernel's
-    oracle, and the engine's XLA fallback for quantized payloads).
+    """Sequential dequantize + scatter-accumulate: the engine's one fold
+    for quantized sparse payloads (``SparseCodecAggregator``).
 
     acc: (nb, block) f32; w, gscale: (C,); idx: (C, nb, kb) int32; qv:
     (C, nb, kb) wire-dtype values; scale: (C, nb, 1) f32 row scales.
     Gather-modify-scatter with ``coeff = (w * gscale) * scale`` folded
-    before the multiply with the widened values — the same op order as
-    the kernel, and the same ``a + where(w > 0, c * v, 0)`` shape as
+    before the multiply with the widened values — the same
+    ``a + where(w > 0, c * v, 0)`` shape as
     ``SparseTopKAggregator`` so full-round aggregates stay bit-equal to
     the unquantized path when the values are on the fp32 grid already.
     """
@@ -55,10 +55,10 @@ def lbgm_dequant_accum_ref(acc: jax.Array, w: jax.Array, gscale: jax.Array,
 def sort_topk_rows(idx: jax.Array, val: jax.Array):
     """Canonicalize a block-row top-k (idx, val) pair by ascending index.
 
-    The one-pass kernel emits entries in descending-|value| order
-    (``lax.top_k``), the two-pass threshold-select variant in index
-    order; consumers treat each row as a set, so equivalence tests
-    compare through this canonical form.
+    ``lax.top_k`` emits entries in descending-|value| order, the
+    threshold-select decision kernel in index order; consumers treat each
+    row as a set, so equivalence tests compare through this canonical
+    form.
     """
     order = jnp.argsort(idx, axis=-1)
     return (jnp.take_along_axis(idx, order, axis=-1),

@@ -6,6 +6,7 @@ from typing import Optional, Sequence, Union
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 #: JSON-able FL mesh spec (see ``repro.fed.flconfig.FLConfig.mesh``):
@@ -23,11 +24,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     if len(devices) == n:
         return jax.make_mesh(shape, axes)
     if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices for mesh {shape}, have {len(devices)}; "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
-            "BEFORE importing jax (launch/dryrun.py does this)")
+        raise RuntimeError(_too_few(n, f"mesh {shape}", devices))
     return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+
+
+def _too_few(n: int, what: str, devices) -> str:
+    return (f"need {n} devices for the {what}, but JAX found "
+            f"{len(devices)} {devices[0].platform} device(s)")
 
 
 def make_debug_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -70,12 +73,13 @@ def make_fl_mesh(spec: MeshSpec = None, *, client_axis: str = "clients",
     n = shape[0] * shape[1]
     if n > len(devices):
         raise RuntimeError(
-            f"need {n} devices for the {shape} (clients, model) FL mesh, "
-            f"have {len(devices)}; set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} "
-            "BEFORE importing jax (launch/dryrun.py does this)")
-    return Mesh(np.asarray(devices[:n]).reshape(shape),
-                (client_axis, model_axis))
+            _too_few(n, f"{shape} (clients, model) FL mesh", devices))
+    # all of the host's devices: lay the mesh out on the physical topology
+    # (TPU ICI rings); a subset keeps device order
+    grid = (mesh_utils.create_device_mesh(shape, devices)
+            if n == len(devices)
+            else np.asarray(devices[:n]).reshape(shape))
+    return Mesh(grid, (client_axis, model_axis))
 
 
 def make_client_mesh(num_devices: Optional[int] = None,
@@ -90,8 +94,5 @@ def make_client_mesh(num_devices: Optional[int] = None,
     if n < 1:
         raise ValueError(f"client mesh needs >= 1 device, got {n}")
     if n > len(devices):
-        raise RuntimeError(
-            f"need {n} devices for the client mesh, have {len(devices)}; "
-            f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
-            "BEFORE importing jax (launch/dryrun.py does this)")
+        raise RuntimeError(_too_few(n, "client mesh", devices))
     return Mesh(np.asarray(devices[:n]), (axis,))

@@ -4,22 +4,8 @@ from __future__ import annotations
 from typing import Dict, Optional, Tuple
 
 import jax
-from jax.sharding import AbstractMesh, Mesh, NamedSharding, \
-    PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
-def abstract_mesh(axis_sizes: Tuple[int, ...],
-                  axis_names: Tuple[str, ...]) -> AbstractMesh:
-    """Version-portable AbstractMesh constructor.
-
-    jax <= 0.4.x wants one ``((name, size), ...)`` tuple; newer releases
-    take ``(axis_sizes, axis_names)`` positionally. Callers always pass the
-    latter form and this helper adapts.
-    """
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
 
 # logical axes that shard over the `model` mesh axis in every mode
 _MODEL_AXES = {"vocab", "heads", "kv_heads", "ff", "expert", "embed2",

@@ -71,10 +71,15 @@ def test_sparse_decision_kernel_matches_ref(key, nb, block, kb):
     perm = jnp.argsort(
         jax.random.normal(jax.random.fold_in(key, 2), (nb, block)), axis=1)
     idx = perm[:, :kb].astype(jnp.int32)
-    got = ops.lbgm_sparse_decision(blocks, idx, interpret=True)
-    want = ref.lbgm_sparse_decision_ref(blocks, idx)
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5)
+    gg, gath, ti, tv = ops.lbgm_sparse_decision(blocks, idx, interpret=True)
+    rgg, rgath, rti, rtv = ref.lbgm_sparse_decision_ref(blocks, idx)
+    np.testing.assert_allclose(float(gg), float(rgg), rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(gath), np.asarray(rgath))
+    # top-k rows are index-ordered sets: compare in canonical order
+    si, sv = ref.sort_topk_rows(ti, tv)
+    ri, rv = ref.sort_topk_rows(rti, rtv)
+    np.testing.assert_array_equal(np.asarray(si), np.asarray(ri))
+    np.testing.assert_array_equal(np.asarray(sv), np.asarray(rv))
 
 
 def test_sparse_decision_vmap_over_clients(key):
@@ -86,9 +91,14 @@ def test_sparse_decision_vmap_over_clients(key):
         x, i, interpret=True))(blocks, idx)
     for b in range(B):
         want = ref.lbgm_sparse_decision_ref(blocks[b], idx[b])
-        for a, w in zip((got[0][b], got[1][b], got[2][b], got[3][b]), want):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(w),
-                                       rtol=1e-5)
+        np.testing.assert_allclose(float(got[0][b]), float(want[0]),
+                                   rtol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got[1][b]),
+                                      np.asarray(want[1]))
+        si, sv = ref.sort_topk_rows(got[2][b], got[3][b])
+        ri, rv = ref.sort_topk_rows(want[2], want[3])
+        np.testing.assert_array_equal(np.asarray(si), np.asarray(ri))
+        np.testing.assert_array_equal(np.asarray(sv), np.asarray(rv))
 
 
 # ---------------------------------------------------- (b) step-level logic
@@ -154,10 +164,16 @@ def test_topk_step_fused_matches_unfused(key):
     gt_b, nl_b, st_b = lbgm_lib.lbgm_topk_client_step(g, lbg, 0.5, k_frac,
                                                       fused=True)
     assert bool(st_a.sent_scalar) == bool(st_b.sent_scalar)
-    for a, b in zip(jax.tree.leaves((gt_a, nl_a)),
-                    jax.tree.leaves((gt_b, nl_b))):
+    for a, b in zip(jax.tree.leaves(gt_a), jax.tree.leaves(gt_b)):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
+                                   rtol=1e-5, atol=1e-7)
+    # the kernel emits each refreshed bank row as an index-ordered set
+    for name in g:
+        ai, av = ref.sort_topk_rows(nl_a[name]["idx"], nl_a[name]["val"])
+        bi, bv = ref.sort_topk_rows(nl_b[name]["idx"], nl_b[name]["val"])
+        np.testing.assert_array_equal(np.asarray(ai), np.asarray(bi))
+        np.testing.assert_allclose(np.asarray(av), np.asarray(bv),
                                    rtol=1e-5, atol=1e-7)
     np.testing.assert_allclose(float(st_a.sin2), float(st_b.sin2),
                                rtol=1e-4, atol=1e-6)
@@ -375,23 +391,23 @@ def test_lbg_kw_reserved_key_actionable_error(fcn_setup):
                     lbg_kw={"k_frac": 0.1, "model_axis": "x"})
 
 
-# ---------------------------------- (e) two-pass threshold-select fallback
+# ----------------------------- (e) threshold-select decision kernel edges
 
 
 @pytest.mark.parametrize("nb,block,kb", [(1, 700, 33), (3, 512, 17),
-                                         (16, 1000, 9), (4, 256, 256)])
+                                         (16, 1000, 9), (4, 256, 256),
+                                         (12, 300, 7)])
 def test_two_pass_kernel_matches_ref_setwise(key, nb, block, kb):
-    """The Mosaic-safety variant (no in-kernel top_k / take_along_axis)
-    must select the exact same (idx, val) SET per block row as the sorted
+    """The decision kernel (no in-kernel top_k / take_along_axis) must
+    select the exact same (idx, val) SET per block row as the sorted
     oracle — slot order is by index, so compare through the canonical
     form — with the gathered values and ||g||^2 agreeing too."""
-    from repro.kernels.lbgm_sparse import \
-        lbgm_sparse_decision_two_pass_pallas
+    from repro.kernels.lbgm_sparse import lbgm_sparse_decision_pallas
     blocks = jax.random.normal(key, (nb, block))
     perm = jnp.argsort(
         jax.random.normal(jax.random.fold_in(key, 2), (nb, block)), axis=1)
     idx = perm[:, :kb].astype(jnp.int32)
-    gg, gath, ti, tv = lbgm_sparse_decision_two_pass_pallas(
+    gg, gath, ti, tv = lbgm_sparse_decision_pallas(
         blocks, idx, interpret=True)
     rgg, rgath, rti, rtv = ref.lbgm_sparse_decision_ref(blocks, idx)
     np.testing.assert_allclose(float(gg), float(rgg), rtol=1e-5)
@@ -407,12 +423,11 @@ def test_two_pass_kernel_tiny_magnitudes(key):
     |values| are far below any absolute float resolution (a float-interval
     bisection left such rows entirely inside the tie band and selected by
     index instead of magnitude)."""
-    from repro.kernels.lbgm_sparse import \
-        lbgm_sparse_decision_two_pass_pallas
+    from repro.kernels.lbgm_sparse import lbgm_sparse_decision_pallas
     for scale in (1e-20, 1e-35, 1e30):
         blocks = jax.random.normal(key, (2, 256)) * scale
         idx = jnp.tile(jnp.arange(4, dtype=jnp.int32)[None], (2, 1))
-        _, gath, ti, tv = lbgm_sparse_decision_two_pass_pallas(
+        _, gath, ti, tv = lbgm_sparse_decision_pallas(
             blocks, idx, interpret=True)
         _, rgath, rti, rtv = ref.lbgm_sparse_decision_ref(blocks, idx)
         np.testing.assert_array_equal(np.asarray(gath), np.asarray(rgath))
@@ -427,12 +442,11 @@ def test_two_pass_kernel_degenerate_rows(key):
     """All-zero rows and rows with fewer nonzeros than kb: the threshold
     collapses to 0 and the tie-fill must keep every nonzero plus the
     lowest-index zeros — exactly lax.top_k's tie rule."""
-    from repro.kernels.lbgm_sparse import \
-        lbgm_sparse_decision_two_pass_pallas
+    from repro.kernels.lbgm_sparse import lbgm_sparse_decision_pallas
     z = jnp.zeros((2, 300))
     z = z.at[1, 250].set(3.0).at[1, 7].set(-2.0)
     idx = jnp.tile(jnp.arange(5, dtype=jnp.int32)[None], (2, 1))
-    gg, gath, ti, tv = lbgm_sparse_decision_two_pass_pallas(
+    gg, gath, ti, tv = lbgm_sparse_decision_pallas(
         z, idx, interpret=True)
     want = ref.lbgm_sparse_decision_ref(z, idx)
     np.testing.assert_array_equal(np.asarray(gath), np.asarray(want[1]))
@@ -444,15 +458,19 @@ def test_two_pass_kernel_degenerate_rows(key):
     assert {7, 250} <= set(np.asarray(ti[1]).tolist())
 
 
-def test_two_pass_vmap_and_env_knob(key, monkeypatch):
-    """vmap routes to the batched two-pass grid; REPRO_LBGM_TWO_PASS_TOPK
-    flips the ops-level default without touching any config."""
-    from repro.kernels.ops import TWO_PASS_ENV, _default_two_pass
-    B, nb, block, kb = 3, 2, 256, 11
+def test_two_pass_vmap_and_env_knob(key):
+    """vmap routes to the batched decision grid (rows tiled 8 at a time
+    here: nb = 16), and the decision has one kernel — no env switch or
+    variant argument picks another."""
+    import inspect
+    assert not hasattr(ops, "TWO_PASS_ENV")
+    assert list(inspect.signature(ops.lbgm_sparse_decision).parameters) \
+        == ["blocks", "idx", "interpret"]
+    B, nb, block, kb = 3, 16, 256, 11
     blocks = jax.random.normal(key, (B, nb, block))
     idx = jnp.tile(jnp.arange(kb, dtype=jnp.int32)[None, None], (B, nb, 1))
     got = jax.vmap(lambda x, i: ops.lbgm_sparse_decision(
-        x, i, interpret=True, two_pass=True))(blocks, idx)
+        x, i, interpret=True))(blocks, idx)
     for b in range(B):
         want = ref.lbgm_sparse_decision_ref(blocks[b], idx[b])
         np.testing.assert_allclose(float(got[0][b]), float(want[0]),
@@ -463,37 +481,20 @@ def test_two_pass_vmap_and_env_knob(key, monkeypatch):
         ri, rv = ref.sort_topk_rows(want[2], want[3])
         np.testing.assert_array_equal(np.asarray(si), np.asarray(ri))
         np.testing.assert_array_equal(np.asarray(sv), np.asarray(rv))
-    monkeypatch.delenv(TWO_PASS_ENV, raising=False)
-    assert not _default_two_pass()
-    monkeypatch.setenv(TWO_PASS_ENV, "1")
-    assert _default_two_pass()
-    for off in ("false", "0", "off", "no", "False"):
-        monkeypatch.setenv(TWO_PASS_ENV, off)
-        assert not _default_two_pass(), off
 
 
 def test_two_pass_step_level_agrees(key):
-    """topk_step_core(fused=True) under the two-pass env knob: same
+    """topk_step_core(fused=True) through the decision kernel: same
     accept/recycle decision and fp32-tolerance g_tilde vs the legacy
     step (bank sets match; element order inside a row may differ)."""
-    import os
-    from repro.kernels.ops import TWO_PASS_ENV
     k_frac = 0.1
     g = _rand_grad(key, SHAPES)
     lbg = lbgm_lib.init_topk_lbg(g, k_frac)
     _, lbg, _ = lbgm_lib.lbgm_topk_client_step(
         _rand_grad(jax.random.fold_in(key, 7), SHAPES), lbg, -1.0, k_frac)
     gt_a, _, st_a = lbgm_lib.lbgm_topk_client_step(g, lbg, 0.5, k_frac)
-    old = os.environ.get(TWO_PASS_ENV)
-    os.environ[TWO_PASS_ENV] = "1"
-    try:
-        gt_b, _, st_b = lbgm_lib.lbgm_topk_client_step(g, lbg, 0.5, k_frac,
-                                                       fused=True)
-    finally:
-        if old is None:
-            os.environ.pop(TWO_PASS_ENV, None)
-        else:
-            os.environ[TWO_PASS_ENV] = old
+    gt_b, _, st_b = lbgm_lib.lbgm_topk_client_step(g, lbg, 0.5, k_frac,
+                                                   fused=True)
     assert bool(st_a.sent_scalar) == bool(st_b.sent_scalar)
     np.testing.assert_allclose(float(st_a.sin2), float(st_b.sin2),
                                rtol=1e-4, atol=1e-6)

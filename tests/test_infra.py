@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.analysis.pca import GradientSpaceTracker, cosine_matrix, n_pca
 from repro.analysis.roofline import (RooflineReport, build_report,
@@ -36,15 +36,43 @@ def test_checkpoint_roundtrip(tmp_path):
     assert int(loaded["step"]) == 7
 
 
+# ---------------------------------------------------------- compile cache
+
+def _cache_updates(monkeypatch):
+    """Record the config updates enable_compile_cache makes (and keep them
+    out of this process's JAX config)."""
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, val: calls.append((name, val)))
+    return calls
+
+
+def test_compile_cache_leaves_env_dir_to_jax(monkeypatch):
+    from repro.compile_cache import compile_cache_dir, enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/shared/cache")
+    calls = _cache_updates(monkeypatch)
+    assert compile_cache_dir() is None
+    assert enable_compile_cache() is None
+    assert calls == []
+
+
+def test_compile_cache_fixed_dir_under_checkout(monkeypatch):
+    from repro.compile_cache import compile_cache_dir, enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    calls = _cache_updates(monkeypatch)
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(os.path.realpath(checkout), ".jax_cache")
+    assert compile_cache_dir() == want
+    assert enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]
+    # the same path on every call: no pid, time or temp directory in it
+    assert compile_cache_dir() == want
+    assert os.path.isdir(os.path.join(os.path.dirname(want), "src", "repro"))
+
+
 # ------------------------------------------------------------- sharding
 
-MESH = shd.abstract_mesh((16, 16), ("data", "model"))
-
-
-def test_abstract_mesh_roundtrips():
-    assert MESH.axis_names == ("data", "model")
-    assert dict(MESH.shape) == {"data": 16, "model": 16}
-    assert MESH.shape_tuple == (("data", 16), ("model", 16))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_param_pspec_rules():

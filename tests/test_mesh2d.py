@@ -111,9 +111,11 @@ def test_make_fl_mesh_shapes_and_errors():
     assert dict(mesh.shape) == {"clients": 1, "model": 1}
     mesh = make_fl_mesh([1, 1], client_axis="c", model_axis="m")
     assert mesh.axis_names == ("c", "m")
-    with pytest.raises(RuntimeError, match="device"):
+    # the error names what JAX found, not a workaround
+    found = f"found {n} {jax.devices()[0].platform} device"
+    with pytest.raises(RuntimeError, match=found):
         make_fl_mesh([n + 1, 1])
-    with pytest.raises(RuntimeError, match="device"):
+    with pytest.raises(RuntimeError, match=found):
         make_fl_mesh([1, n + 1])
     with pytest.raises(ValueError, match="axis"):
         make_fl_mesh([0, 1])
@@ -356,6 +358,10 @@ assert hc[0]["uplink_floats"] == h81[0]["uplink_floats"] \
     == h24[0]["uplink_floats"], (hc[0], h81[0], h24[0])
 assert hc[0]["frac_scalar"] == h81[0]["frac_scalar"] \
     == h24[0]["frac_scalar"]
+# ...and so are the wire bytes (codec "none": static per full round), the
+# model-sharded leaves' rows summed over the model axis
+assert hc[0]["wire_bytes"] == h81[0]["wire_bytes"] \
+    == h24[0]["wire_bytes"], (hc[0], h81[0], h24[0])
 M = sum(int(v.size) for v in params.values())
 flip = 1.5 * 0.25 * M  # one client's full-round topk cost
 for a, b in zip(hc, h24):
@@ -483,6 +489,14 @@ for a, b in zip(res_r.records, res_a.records):
     np.testing.assert_allclose(a.loss, b.loss, rtol=1e-5, atol=1e-7)
     assert a.uplink_floats == b.uplink_floats, (a, b)
     assert a.frac_scalar == b.frac_scalar, (a, b)
+    assert a.wire_bytes == b.wire_bytes, (a, b)
+# round 1 is all full rounds: codec "none" ships 4-byte indices and 4-byte
+# values for every block-layout slot of every leaf, on every model rank's
+# rows together
+from repro.core.lbgm import _block_layout
+full = sum(8.0 * nb * kb for nb, _, kb in (
+    _block_layout(int(v.size), 0.01) for v in eng_a.params.values()))
+assert res_a.records[0].wire_bytes == 8 * full, (res_a.records[0], full)
 
 # --- (b2) whole-round memory envelope (XLA memory_analysis; params
 # exact from the shards above). The 1/M scaling lands on the
