@@ -1,6 +1,6 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived``
 # CSV rows. Figures covered: 1 (PCA), 5 (standalone), 6 (threshold),
-# 7 (plug-and-play), 8 (SignSGD distributed), + kernel micro-bench.
+# 7 (plug-and-play), 8 (SignSGD distributed).
 #
 # Run from the repo root as a module (the package __init__ bootstraps the
 # src/ path, same convention as pytest.ini's ``pythonpath = src``):
@@ -14,12 +14,12 @@ import argparse
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help="comma list: fig1,fig5,fig6,fig7,fig8,kernels,"
-                         "cohort,robustness,wire_bytes,async")
+                    help="comma list: fig1,fig5,fig6,fig7,fig8,cohort,"
+                         "robustness,wire_bytes,async")
     ap.add_argument("--rounds", type=int, default=30)
     ap.add_argument("--toy", action="store_true",
-                    help="tiny problem sizes (CI smoke): small kernel "
-                         "vectors, small cohorts, narrow model — exercises "
+                    help="tiny problem sizes (CI smoke): small cohorts, "
+                         "narrow model — exercises "
                          "every code path incl. the BENCH_engine.json "
                          "trajectory, makes no perf claims")
     args = ap.parse_args()
@@ -46,12 +46,6 @@ def main() -> None:
     if on("fig8"):
         from benchmarks import fig8_signsgd
         fig8_signsgd.run(rounds=args.rounds)
-    if on("kernels"):
-        from benchmarks import kernel_bench
-        if args.toy:
-            kernel_bench.run(n=1 << 16, batch=4, iters=2)
-        else:
-            kernel_bench.run()
     if on("cohort"):
         from benchmarks import cohort_scaling
         if args.toy:

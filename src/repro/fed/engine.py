@@ -194,6 +194,14 @@ aggregation the dequantize is fused into the scatter-accumulate
 no fp32 payload stack is ever materialized. Per-client ``wire_bytes`` ride
 the scheduler outputs next to ``uplink`` and land in the
 :class:`~repro.comm.accounting.CommLedger` (savings vs vanilla fp32 dense).
+
+Each stage of the round carries a name from ``repro.fed.spans`` for a
+profiler trace: the named scopes ``fl.client_sgd`` (local SGD),
+``fl.decision`` (the store step), ``fl.codec`` (the client-side encode) and
+``fl.fold`` (every aggregator call, the bank write-back and the server
+step) are placed once in ``client_fn`` and at the schedulers' fold sites,
+so every scheduler inherits them; the host spans ``fl.prefetch_wait``,
+``fl.host_prep``, ``fl.h2d`` and ``fl.readback`` mark the host's share.
 """
 from __future__ import annotations
 
@@ -227,6 +235,8 @@ from repro.fed.registry import (LBG_STORES, SCHEDULERS, register_lbg_store,
 from repro.fed.robust import (CollectDenseAggregator,
                               CollectSparseAggregator,
                               ScalarMedianSparseAggregator, make_robust_rule)
+from repro.fed.spans import (CLIENT_SGD, CODEC, DECISION, FOLD, H2D,
+                             HOST_PREP, PREFETCH_WAIT, READBACK, scope, span)
 from repro.kernels.ref import lbgm_dequant_accum_ref
 
 #: reserved batch key: per-client local-step budgets (the buffered
@@ -689,15 +699,16 @@ class VmapScheduler:
     def run(self, client_fn, agg, params, batch, lbg, resid, w, maskf):
         gt, new_lbg, new_res, loss, uplink, scalar, wire = jax.vmap(
             lambda b, l, r: client_fn(params, b, l, r))(batch, lbg, resid)
-        if getattr(agg, "collect", False):
-            # robust rules need the whole per-client stack at once — vmap
-            # already has it in hand
-            out = agg.reduce(w, gt)
-        else:
-            out = agg.finalize(agg.accumulate(agg.init(params), w, gt))
-        return (out, _keep_sampled(maskf, new_lbg, lbg),
-                _keep_sampled(maskf, new_res, resid), loss, uplink, scalar,
-                wire)
+        with scope(FOLD):
+            if getattr(agg, "collect", False):
+                # robust rules need the whole per-client stack at once —
+                # vmap already has it in hand
+                out = agg.reduce(w, gt)
+            else:
+                out = agg.finalize(agg.accumulate(agg.init(params), w, gt))
+            new_lbg = _keep_sampled(maskf, new_lbg, lbg)
+            new_res = _keep_sampled(maskf, new_res, resid)
+        return out, new_lbg, new_res, loss, uplink, scalar, wire
 
 
 @register_scheduler("chunked")
@@ -758,24 +769,30 @@ class ChunkedScheduler:
                 # (O(Kp·payload) — the documented collect-mode memory)
                 ys = (loss, uplink, scalar, wire, gt)
             else:
-                acc = agg.accumulate(acc, w_c, gt)
+                with scope(FOLD):
+                    acc = agg.accumulate(acc, w_c, gt)
                 ys = (loss, uplink, scalar, wire)
-            lbg_bank = update_at(lbg_bank, _keep_sampled(m_c, nl, l_c), i)
-            res_bank = update_at(res_bank, _keep_sampled(m_c, nr, r_c), i)
+            with scope(FOLD):
+                lbg_bank = update_at(lbg_bank, _keep_sampled(m_c, nl, l_c),
+                                     i)
+                res_bank = update_at(res_bank, _keep_sampled(m_c, nr, r_c),
+                                     i)
             return (acc, lbg_bank, res_bank), ys
 
-        init = (jnp.zeros(()) if collect else agg.init(params), lbg, resid)
+        with scope(FOLD):
+            acc0 = jnp.zeros(()) if collect else agg.init(params)
         (acc, new_lbg, new_res), ys = jax.lax.scan(
-            chunk_body, init,
+            chunk_body, (acc0, lbg, resid),
             (jnp.arange(n_chunks), batch, w.reshape(n_chunks, chunk),
              maskf.reshape(n_chunks, chunk)))
-        if collect:
-            loss, uplink, scalar, wire, gt = ys
-            out = agg.reduce(w, jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), gt))
-        else:
-            loss, uplink, scalar, wire = ys
-            out = agg.finalize(acc)
+        with scope(FOLD):
+            if collect:
+                loss, uplink, scalar, wire, gt = ys
+                out = agg.reduce(w, jax.tree.map(
+                    lambda x: x.reshape((-1,) + x.shape[2:]), gt))
+            else:
+                loss, uplink, scalar, wire = ys
+                out = agg.finalize(acc)
         return (out, new_lbg, new_res, loss.reshape(Kp)[:K],
                 uplink.reshape(Kp)[:K], scalar.reshape(Kp)[:K],
                 wire.reshape(Kp)[:K])
@@ -843,8 +860,11 @@ class BufferedScheduler(ChunkedScheduler):
             l_c, r_c = slice_at(lbg_bank, i), slice_at(res_bank, i)
             gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
                 lambda b, l, r: client_fn(params, b, l, r))(b_c, l_c, r_c)
-            lbg_bank = update_at(lbg_bank, _keep_sampled(m_c, nl, l_c), i)
-            res_bank = update_at(res_bank, _keep_sampled(m_c, nr, r_c), i)
+            with scope(FOLD):
+                lbg_bank = update_at(lbg_bank, _keep_sampled(m_c, nl, l_c),
+                                     i)
+                res_bank = update_at(res_bank, _keep_sampled(m_c, nr, r_c),
+                                     i)
             return (lbg_bank, res_bank), (gt, loss, uplink, scalar, wire)
 
         (new_lbg, new_res), ys = jax.lax.scan(
@@ -878,20 +898,21 @@ class BufferedScheduler(ChunkedScheduler):
         wn = wd / jnp.maximum(jnp.sum(wd), 1e-12)
         wnp = jnp.concatenate([wn, jnp.zeros(pad, wn.dtype)]) if pad \
             else wn
-        if getattr(agg, "collect", False):
-            out = agg.reduce(wnp, (nbuf["send"], nbuf["gscale"]))
-        else:
-            def fold_body(acc, xs):
-                w_c, send_c, gs_c = xs
-                return agg.accumulate(acc, w_c, (send_c, gs_c)), None
+        with scope(FOLD):
+            if getattr(agg, "collect", False):
+                out = agg.reduce(wnp, (nbuf["send"], nbuf["gscale"]))
+            else:
+                def fold_body(acc, xs):
+                    w_c, send_c, gs_c = xs
+                    return agg.accumulate(acc, w_c, (send_c, gs_c)), None
 
-            acc, _ = jax.lax.scan(
-                fold_body, agg.init(params),
-                (wnp.reshape(n_chunks, chunk),
-                 jax.tree.map(lambda x: x.reshape(
-                     (n_chunks, chunk) + x.shape[1:]), nbuf["send"]),
-                 nbuf["gscale"].reshape(n_chunks, chunk)))
-            out = agg.finalize(acc)
+                acc, _ = jax.lax.scan(
+                    fold_body, agg.init(params),
+                    (wnp.reshape(n_chunks, chunk),
+                     jax.tree.map(lambda x: x.reshape(
+                         (n_chunks, chunk) + x.shape[1:]), nbuf["send"]),
+                     nbuf["gscale"].reshape(n_chunks, chunk)))
+                out = agg.finalize(acc)
         dlv = lambda x: x[:K] * deliverf
         return (out, new_lbg, new_res, nbuf, loss[:K],
                 dlv(nbuf["uplink"]), dlv(nbuf["scalar"]),
@@ -1140,12 +1161,13 @@ class ShardedScheduler(ChunkedScheduler):
                 # identical carry seeding + clients psum to the
                 # "replicate" local_chunk below, so per-chunk accumulation
                 # order matches ChunkedScheduler
-                first = jax.lax.axis_index(ax) == 0
-                acc_i = jax.tree.map(
-                    lambda a: jnp.where(first, a, 0.0), acc_i)
-                acc_i = jax.lax.psum(agg.accumulate(acc_i, w_, gt), ax)
-                return (acc_i, _keep_sampled(m_, nl_, l_), uplink, scalar,
-                        wire)
+                with scope(FOLD):
+                    first = jax.lax.axis_index(ax) == 0
+                    acc_i = jax.tree.map(
+                        lambda a: jnp.where(first, a, 0.0), acc_i)
+                    acc_i = jax.lax.psum(agg.accumulate(acc_i, w_, gt), ax)
+                    nl_ = _keep_sampled(m_, nl_, l_)
+                return acc_i, nl_, uplink, scalar, wire
 
             def sharded_chunk(acc, p, b, l, r, w_c, m_c):
                 cst = lambda v, s: jax.lax.with_sharding_constraint(
@@ -1171,8 +1193,9 @@ class ShardedScheduler(ChunkedScheduler):
                     check_vma=False)
                 acc, nl, uplink, scalar, wire = manual(
                     acc, blocked, l, cost, thru, w_c, m_c)
-                return (acc, nl, _keep_sampled(m_c, nr, r), loss, uplink,
-                        scalar, wire)
+                with scope(FOLD):
+                    nr = _keep_sampled(m_c, nr, r)
+                return acc, nl, nr, loss, uplink, scalar, wire
 
         collect = getattr(agg, "collect", False)
         if auto:
@@ -1186,9 +1209,10 @@ class ShardedScheduler(ChunkedScheduler):
             def local_chunk(p, b, l, r, w_c, m_c):
                 gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
                     lambda bb, ll, rr: client_fn(p, bb, ll, rr))(b, l, r)
-                return (gt, _keep_sampled(m_c, nl, l),
-                        _keep_sampled(m_c, nr, r), loss, uplink, scalar,
-                        wire)
+                with scope(FOLD):
+                    nl = _keep_sampled(m_c, nl, l)
+                    nr = _keep_sampled(m_c, nr, r)
+                return gt, nl, nr, loss, uplink, scalar, wire
 
             if getattr(agg, "sparse", False):
                 gt_specs = ((self._payload_specs(agg, lbg) if ms else cl),
@@ -1212,12 +1236,14 @@ class ShardedScheduler(ChunkedScheduler):
                 # aggregator — is replicated along `clients`; model-sharded
                 # carry leaves hold disjoint rows per model rank, never
                 # summed over model)
-                first = jax.lax.axis_index(ax) == 0
-                acc = jax.tree.map(lambda a: jnp.where(first, a, 0.0), acc)
-                acc = jax.lax.psum(agg.accumulate(acc, w_c, gt), ax)
-                return (acc, _keep_sampled(m_c, nl, l),
-                        _keep_sampled(m_c, nr, r), loss, uplink, scalar,
-                        wire)
+                with scope(FOLD):
+                    first = jax.lax.axis_index(ax) == 0
+                    acc = jax.tree.map(lambda a: jnp.where(first, a, 0.0),
+                                       acc)
+                    acc = jax.lax.psum(agg.accumulate(acc, w_c, gt), ax)
+                    nl = _keep_sampled(m_c, nl, l)
+                    nr = _keep_sampled(m_c, nr, r)
+                return acc, nl, nr, loss, uplink, scalar, wire
 
             sharded_chunk = jax.shard_map(
                 local_chunk, mesh=self.mesh,
@@ -1244,21 +1270,25 @@ class ShardedScheduler(ChunkedScheduler):
                 acc, nl, nr, loss, uplink, scalar, wire = sharded_chunk(
                     acc, params, b_c, l_c, r_c, w_c, m_c)
                 ys = (loss, uplink, scalar, wire)
-            return ((acc, put_at(lbg_bank, nl, i), put_at(res_bank, nr, i)),
-                    ys)
+            with scope(FOLD):
+                lbg_bank = put_at(lbg_bank, nl, i)
+                res_bank = put_at(res_bank, nr, i)
+            return (acc, lbg_bank, res_bank), ys
 
-        init = (jnp.zeros(()) if collect else agg.init(params), lbg, resid)
+        with scope(FOLD):
+            acc0 = jnp.zeros(()) if collect else agg.init(params)
         (acc, new_lbg, new_res), ys = jax.lax.scan(
-            chunk_body, init,
+            chunk_body, (acc0, lbg, resid),
             (jnp.arange(n_chunks), batch, w.reshape(n_chunks, chunk),
              maskf.reshape(n_chunks, chunk)))
-        if collect:
-            loss, uplink, scalar, wire, gt = ys
-            out = agg.reduce(w, jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), gt))
-        else:
-            loss, uplink, scalar, wire = ys
-            out = agg.finalize(acc)
+        with scope(FOLD):
+            if collect:
+                loss, uplink, scalar, wire, gt = ys
+                out = agg.reduce(w, jax.tree.map(
+                    lambda x: x.reshape((-1,) + x.shape[2:]), gt))
+            else:
+                loss, uplink, scalar, wire = ys
+                out = agg.finalize(acc)
         return (out, new_lbg, new_res, loss.reshape(Kp)[:K],
                 uplink.reshape(Kp)[:K], scalar.reshape(Kp)[:K],
                 wire.reshape(Kp)[:K])
@@ -1643,17 +1673,21 @@ class FLEngine:
                 thru[WIRE_KEY] = batches.pop(WIRE_KEY)
             extras = {k: batches.pop(k) for k in list(batches)
                       if k.startswith("_atk_")}
-            asg, loss = client_update(params, batches)
+            with scope(CLIENT_SGD):
+                asg, loss = client_update(params, batches)
             if attack is not None:
                 asg = attack.apply(asg, byz, extras)
             asg, resid_k, cost = pipeline(asg, resid_k)
             return asg, resid_k, loss, cost, thru
 
         def post(blocked_k, lbg_k, cost, thru):
-            gt, lbg_k, stats = blocked_step(blocked_k, lbg_k)
-            uplink = jnp.where(stats.sent_scalar, 1.0,
-                               store.full_round_cost(cost, stats))
-            gt, lbg_k, wire = encode(gt, lbg_k, stats, thru.get(WIRE_KEY))
+            with scope(DECISION):
+                gt, lbg_k, stats = blocked_step(blocked_k, lbg_k)
+                uplink = jnp.where(stats.sent_scalar, 1.0,
+                                   store.full_round_cost(cost, stats))
+            with scope(CODEC):
+                gt, lbg_k, wire = encode(gt, lbg_k, stats,
+                                         thru.get(WIRE_KEY))
             return gt, lbg_k, uplink, stats.sent_scalar, wire
 
         return pre, post
@@ -1690,10 +1724,11 @@ class FLEngine:
             tau_k = batches.pop(TAU_KEY, None)
             extras = {k: batches.pop(k) for k in list(batches)
                       if k.startswith("_atk_")}
-            if hetero_tau:
-                asg, loss = client_update(params, batches, tau_k)
-            else:
-                asg, loss = client_update(params, batches)
+            with scope(CLIENT_SGD):
+                if hetero_tau:
+                    asg, loss = client_update(params, batches, tau_k)
+                else:
+                    asg, loss = client_update(params, batches)
             if attack is not None:
                 # the Byzantine client corrupts its accumulated gradient
                 # BEFORE the uplink pipeline and the LBGM decision: its
@@ -1706,20 +1741,23 @@ class FLEngine:
             # pair the SparseTopKAggregator scatter-adds — the dense
             # g_tilde is never materialized
             step = store.sparse_client_step if sparse else store.client_step
-            gt, lbg_k, stats = step(asg, lbg_k)
-            # scalar rounds upload 1 float; full rounds pay the base cost
-            uplink = jnp.where(stats.sent_scalar, 1.0,
-                               store.full_round_cost(cost, stats))
+            with scope(DECISION):
+                gt, lbg_k, stats = step(asg, lbg_k)
+                # scalar rounds upload 1 float; full rounds pay the base
+                # cost
+                uplink = jnp.where(stats.sent_scalar, 1.0,
+                                   store.full_round_cost(cost, stats))
             # wire codec: encode the payload the uplink actually ships
             # (and, for lossy codecs, re-point the bank at the values the
             # server will decode) + account the real bytes on the wire
-            if sparse:
-                gt, lbg_k, wire = encode(gt, lbg_k, stats, wire_seed)
-            elif sparse_wire is not None:
-                wire = jnp.where(stats.sent_scalar, codec.scalar_bytes,
-                                 sparse_wire)
-            else:
-                gt, wire = codec.encode_dense(gt, uplink, wire_seed)
+            with scope(CODEC):
+                if sparse:
+                    gt, lbg_k, wire = encode(gt, lbg_k, stats, wire_seed)
+                elif sparse_wire is not None:
+                    wire = jnp.where(stats.sent_scalar, codec.scalar_bytes,
+                                     sparse_wire)
+                else:
+                    gt, wire = codec.encode_dense(gt, uplink, wire_seed)
             return (gt, lbg_k, resid_k, loss, uplink, stats.sent_scalar,
                     wire)
 
@@ -1754,9 +1792,10 @@ class FLEngine:
                  scalar, wire) = sched.run_buffered(
                     client_fn, aggregator, params, batch, lbg, residual,
                     buf, w0, dispatchf, deliverf, stalef, disc)
-                new_params = jax.tree.map(
-                    lambda p, a: p - cfg.lr * a.astype(p.dtype), params,
-                    agg_out)
+                with scope(FOLD):
+                    new_params = jax.tree.map(
+                        lambda p, a: p - cfg.lr * a.astype(p.dtype), params,
+                        agg_out)
                 metrics = {
                     "loss": jnp.sum(losses * wl),
                     "uplink_floats": jnp.sum(uplink),
@@ -1780,8 +1819,9 @@ class FLEngine:
             agg, new_lbg, new_res, losses, uplink, scalar, wire = sched.run(
                 client_fn, aggregator, params, batch, lbg, residual, w,
                 maskf)
-            new_params = jax.tree.map(
-                lambda p, a: p - cfg.lr * a.astype(p.dtype), params, agg)
+            with scope(FOLD):
+                new_params = jax.tree.map(
+                    lambda p, a: p - cfg.lr * a.astype(p.dtype), params, agg)
             if pshard is not None:
                 # keep the updated params on their TP layout round over
                 # round (the donated input buffers are reused in place)
@@ -1813,8 +1853,9 @@ class FLEngine:
             gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
                 lambda b, l, r: client_fn(params, b, l, r))(
                     b_c, lbg_c, resid_c)
-            acc = agg.accumulate(acc, w_c, gt)
-            nl = _keep_sampled(m_c, nl, lbg_c)
+            with scope(FOLD):
+                acc = agg.accumulate(acc, w_c, gt)
+                nl = _keep_sampled(m_c, nl, lbg_c)
             return acc, nl, loss, uplink, scalar, wire
 
         return chunk_fn
@@ -1847,9 +1888,10 @@ class FLEngine:
         agg = self.agg
 
         def final(params, acc, losses, uplink, scalar, wire, w, maskf):
-            out = agg.finalize(acc)
-            new_params = jax.tree.map(
-                lambda p, a: p - cfg.lr * a.astype(p.dtype), params, out)
+            with scope(FOLD):
+                out = agg.finalize(acc)
+                new_params = jax.tree.map(
+                    lambda p, a: p - cfg.lr * a.astype(p.dtype), params, out)
             metrics = {
                 "loss": jnp.sum(losses * w),
                 "uplink_floats": jnp.sum(uplink * maskf),
@@ -1912,16 +1954,31 @@ class FLEngine:
     def _sample_batches(self, rng: np.random.RandomState):
         """Per-round client batches, laid out by the scheduler's
         ``prepare_batch`` (vmap: (K, tau, b, ...); chunked:
-        (n_chunks, chunk, tau, b, ...), padded host-side).
+        (n_chunks, chunk, tau, b, ...), padded host-side) and staged on
+        the device. This is the host half of the round hot path that
+        :class:`RoundPrefetcher` overlaps with device execution.
+        """
+        with span(HOST_PREP):
+            stacked = self._draw_batches(rng)
+        if self._host_bank:
+            # out-of-core path: batches stay host-side — the bank
+            # streamer uploads each chunk's rows next to its bank rows,
+            # so device batch bytes are O(chunk), K-independent, and the
+            # prefetch thread never stages an O(K) H2D transfer
+            return {k: np.asarray(v) for k, v in stacked.items()}
+        with span(H2D):
+            return {k: jnp.asarray(v) for k, v in stacked.items()}
+
+    def _draw_batches(self, rng: np.random.RandomState):
+        """The round's draws and gathered batch in the scheduler's layout,
+        as host arrays.
 
         The K per-client index draws stay sequential — the rng stream is
         part of the reproducibility contract (identical draws to the
         original per-client loop) — but materialization is ONE vectorized
         fancy-index per data key from the concatenated client data
         straight into the (K, tau, b, ...) buffer: no per-client gather
-        loop, no intermediate list + ``np.stack`` copy. This is the host
-        half of the round hot path that :class:`RoundPrefetcher` overlaps
-        with device execution.
+        loop, no intermediate list + ``np.stack`` copy.
         """
         cfg = self.cfg
         idx = np.empty((cfg.num_clients, cfg.tau, cfg.batch_size), np.int64)
@@ -1961,14 +2018,7 @@ class FLEngine:
                 stacked[STALE_KEY] = d.astype(np.float32)
             if self._tau_vec is not None:
                 stacked[TAU_KEY] = np.asarray(self._tau_vec, np.int32)
-        stacked = self.sched.prepare_batch(stacked)
-        if self._host_bank:
-            # out-of-core path: batches stay host-side — the bank
-            # streamer uploads each chunk's rows next to its bank rows,
-            # so device batch bytes are O(chunk), K-independent, and the
-            # prefetch thread never stages an O(K) H2D transfer
-            return {k: np.asarray(v) for k, v in stacked.items()}
-        return {k: jnp.asarray(v) for k, v in stacked.items()}
+        return self.sched.prepare_batch(stacked)
 
     def _sample_mask(self, rng: np.random.RandomState) -> np.ndarray:
         """Algorithm-3 participation mask for one round.
@@ -2098,7 +2148,8 @@ class FLEngine:
                 self.params, self.lbg, self.residual, batch,
                 jnp.asarray(mask, jnp.float32))
             vanilla = float(mask.sum()) * tree_size(self.params)
-        m = {k: float(v) for k, v in metrics.items()}
+        with span(READBACK):
+            m = {k: float(v) for k, v in metrics.items()}
         tiers = None
         if self.tiers is not None:
             # per-tier wire attribution: edge links carried this round's
@@ -2347,22 +2398,24 @@ class RoundPrefetcher:
         atomic with a blocking ``get()``, so a ``close()`` (or producer
         death) that lands after the checks but before the dequeue used to
         park this thread on an empty queue forever."""
-        while True:
-            if self._err is not None and self._q.empty():
-                raise RuntimeError(
-                    "round prefetch thread failed") from self._err
-            # not "and empty": a put() blocked on a full queue while close()
-            # drained it lands afterwards, leaving a stale item behind
-            if self._stop.is_set():
-                raise RuntimeError("RoundPrefetcher used after close()")
-            try:
-                item = self._q.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            if item is self._SENTINEL:
-                raise RuntimeError(
-                    "round prefetch thread failed") from self._err
-            return item
+        with span(PREFETCH_WAIT):
+            while True:
+                if self._err is not None and self._q.empty():
+                    raise RuntimeError(
+                        "round prefetch thread failed") from self._err
+                # not "and empty": a put() blocked on a full queue while
+                # close() drained it lands afterwards, leaving a stale item
+                # behind
+                if self._stop.is_set():
+                    raise RuntimeError("RoundPrefetcher used after close()")
+                try:
+                    item = self._q.get(timeout=0.05)
+                except queue.Empty:
+                    continue
+                if item is self._SENTINEL:
+                    raise RuntimeError(
+                        "round prefetch thread failed") from self._err
+                return item
 
     def close(self):
         self._stop.set()
