@@ -4,6 +4,7 @@ entry points (ExperimentSpec -> run_experiment -> FLEngine).
 
     python chip_smoke.py             # one chip: phases kernels, fcn, lm
     python chip_smoke.py --chips 4   # four chips: phase lm-mesh only
+    python chip_smoke.py --only kernels   # one one-chip phase
 
 Phases (one process; each prints its own lines):
 
@@ -161,27 +162,45 @@ def phase_kernels():
     from repro.kernels.lbgm_projection import lbgm_projection_batched_pallas
     from repro.kernels.lbgm_sparse import lbgm_sparse_decision_batched_pallas
     key = jax.random.PRNGKey(0)
-    # decision: one stacked 2048x6144 block row group and a norm leaf
-    for B, nb, block, kb in ((2, 192, 65536, 655), (2, 1, 2048, 20)):
-        g = jax.random.normal(key, (B, nb, block))
-        idx = jax.random.randint(jax.random.fold_in(key, 1), (B, nb, kb),
-                                 0, block, jnp.int32)
-        t0 = time.time()
-        gg, gath, ti, tv = jax.block_until_ready(
-            lbgm_sparse_decision_batched_pallas(g, idx))
-        dt = time.time() - t0
-        for b in range(B):
-            rgg, rgath, rti, rtv = ref.lbgm_sparse_decision_ref(g[b], idx[b])
-            np.testing.assert_allclose(float(gg[b]), float(rgg), rtol=1e-5)
-            np.testing.assert_array_equal(np.asarray(gath[b]),
-                                          np.asarray(rgath))
-            si, sv = ref.sort_topk_rows(ti[b], tv[b])
-            ri, rv = ref.sort_topk_rows(rti, rtv)
-            np.testing.assert_array_equal(np.asarray(si), np.asarray(ri))
-            np.testing.assert_array_equal(np.asarray(sv), np.asarray(rv))
-        print(json.dumps({"phase": "kernels", "kernel": "lbgm_sparse_decision",
-                          "shape": [B, nb, block, kb], "exact": True,
-                          "first_call_s": dt}), flush=True)
+    # decision: a stacked 2048x6144 leaf's row group, a norm leaf, one
+    # model rank's rows of a 208-row leaf, the embedding, and one tile
+    # with kb close to the block; normal data, then data whose |values|
+    # are subnormal (and some -0.0). Values and gathers must match the
+    # reference bit for bit; the XLA reference runs on the host CPU, where
+    # nothing flushes subnormals.
+    cpu = jax.devices("cpu")[0]
+    rng = np.random.default_rng(0)
+    bits = lambda a: np.asarray(a).view(np.int32)
+    for B, nb, block, kb in ((2, 192, 65536, 655), (2, 1, 2048, 20),
+                             (2, 52, 65536, 655), (2, 608, 65536, 639),
+                             (2, 8, 256, 250)):
+        for data in ("normal", "subnormal"):
+            # made on the host: a TPU may flush subnormal products
+            g = rng.standard_normal((B, nb, block), np.float32)
+            if data == "subnormal":
+                g = g * np.float32(1e-40)
+                g = np.where(np.abs(g) < 2e-41, np.float32(-0.0), g)
+            idx = rng.integers(0, block, (B, nb, kb), np.int32)
+            t0 = time.time()
+            gg, gath, ti, tv = jax.block_until_ready(
+                lbgm_sparse_decision_batched_pallas(g, idx))
+            dt = time.time() - t0
+            for b in range(B):
+                rgg, rgath, rti, rtv = ref.lbgm_sparse_decision_ref(
+                    jax.device_put(g[b], cpu), jax.device_put(idx[b], cpu))
+                np.testing.assert_allclose(float(gg[b]), float(rgg),
+                                           rtol=1e-5)
+                np.testing.assert_array_equal(bits(gath[b]), bits(rgath))
+                si, sv = ref.sort_topk_rows(np.asarray(ti[b]),
+                                            np.asarray(tv[b]))
+                ri, rv = ref.sort_topk_rows(np.asarray(rti), np.asarray(rtv))
+                np.testing.assert_array_equal(np.asarray(si), np.asarray(ri))
+                np.testing.assert_array_equal(bits(sv), bits(rv))
+            print(json.dumps({"phase": "kernels",
+                              "kernel": "lbgm_sparse_decision",
+                              "shape": [B, nb, block, kb], "data": data,
+                              "bit_exact": True, "first_call_s": dt}),
+                  flush=True)
     # projection at the FCN's leaf sizes (784x200, 200, 200x10, 10)
     for n in (156800, 200, 2000, 10):
         g = jax.random.normal(key, (4, n))
@@ -269,6 +288,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4 runs only the lm-mesh phase (needs 4 chips)")
+    ap.add_argument("--only", choices=("kernels", "fcn", "lm"),
+                    help="run this one-chip phase alone")
     args = ap.parse_args(argv)
 
     from repro.compile_cache import enable_compile_cache
@@ -282,6 +303,8 @@ def main(argv=None) -> int:
     t0 = time.time()
     phases = ([phase_kernels, phase_fcn, phase_lm] if args.chips == 1
               else [phase_lm_mesh])
+    if args.only:
+        phases = [globals()["phase_" + args.only]]
     for phase in phases:
         t = time.time()
         print(f"== {phase.__name__[6:]}", flush=True)

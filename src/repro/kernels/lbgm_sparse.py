@@ -18,22 +18,52 @@ of a vmap'd scheduler block maps straight onto grid dim 0
 accumulator init at ``j == 0`` is correct under the sequential TPU grid.
 
 The body needs no sort and no dynamic gather, neither of which Mosaic
-lowers:
+lowers. Each block row arrives as an (n_tiles, T) matrix of contiguous
+T-lane tiles, and is handled in two passes. (On a TPU that reshape is a
+relayout, which XLA folds into the copy that lays a leaf out in blocks,
+``core.lbgm._to_blocks``: the program's path makes one copy of a leaf.)
 
 * pass 1 bisects each row's kb-th largest |value| exactly, in int32 IEEE
   bit space (monotone in value for non-negative floats), with nothing but
-  bitcasts, compares and sums — all rows of the step at once;
-* pass 2 sweeps the row in lane tiles loaded straight from the ref and
-  compacts the selected entries (and gathers the LBG positions) with
-  one-hot matmuls at HIGHEST precision, so the selected values and
-  positions come out exact.
+  bitcasts, compares and sums, all rows of the step at once. It splits a
+  row into *definite* entries (above the threshold band, m of them) and
+  *ties* (in it);
+* pass 2 selects in two levels, (tile, lane), row by row, with the output
+  slots on the lane axis of full-height MXU matmuls:
 
-The per-row (idx, val) SET equals ``lax.top_k``'s, ties included (ties
-fill in index order — ``lax.top_k``'s lowest-index rule); slot order is by
-index, not by descending value, and every consumer treats the pairs as a
-set. Block dims are squeezed over the batch axis and tiled over rows, and
-the lane axes are padded to 128, so every block satisfies Mosaic's (8, 128)
-rule.
+  1. the row is transposed once to (T, n_tiles); one (T x T) triangle
+     matmul per class gives each entry's inclusive rank inside its tile.
+     The ranks' last row is the tiles' counts; one small matmul turns them
+     into the inclusive prefix counts C_d, C_t over tiles;
+  2. slot s <= m is the s-th definite, slot m < s <= kb the (s - m)-th tie
+     (ties fill in index order, ``lax.top_k``'s lowest-index rule), slots
+     past kb stay empty. With s' the slot's ordinal in its class, its tile
+     is a_s = #{a : C[a] < s'} and its rank in the tile s' - C[a_s - 1]:
+     O(n_tiles) compares per slot on the VPU;
+  3. per group of ``SLOTS`` slots, the one-hot of a_s (n_tiles x SLOTS)
+     multiplies the row's transposed ranks (T x n_tiles, bf16) and the four
+     bytes of its f32 bit patterns (4T x n_tiles, int8): every slot fetches
+     its whole tile. The fetched rank equal to the slot's picks the lane
+     (position a_s * T + lane), and the lane's four bytes reassemble the
+     value's exact bits. The gather at the LBG positions is the same step
+     with (a, lane) = divmod(idx, T), any order, repeats allowed.
+
+Why pass 2 is exact: every matmul operand is 0/1, a rank or count of at
+most 256 in magnitude (exact in bf16) or a signed byte (int8); each fetched
+output has exactly ONE non-zero term, and every sum stays below 2^24, so a
+single-pass bf16 matmul with f32 accumulation, and an int8 one with int32
+accumulation, return them exactly. Values never pass through the MXU or
+the VPU as floats, so subnormals (which both may flush) and -0.0 survive
+bit for bit. Work per row of `block` entries: O(block) for the ranks, and
+kbp x block single-pass MACs per fetched piece, on matmuls whose
+streamed side is the full (T or 4T)-row piece matrix.
+
+The per-row (idx, val) SET equals ``lax.top_k``'s, ties included. Slots
+hold the definites in index order, then the ties in index order, not
+descending values; every consumer treats the pairs as a set. The g block
+is (ROWS, n_tiles, T) over a squeezed batch axis; the idx and output
+blocks are (ROWS, kbp) with kbp padded to 128, so every block satisfies
+Mosaic's (8, 128) rule.
 """
 from __future__ import annotations
 
@@ -45,12 +75,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANES = 128
-#: block rows per grid step (one f32 sublane tile); a leaf with fewer rows
-#: is taken whole (a block dim equal to the array's is legal), one with more
-#: is padded to a multiple with zero rows, so a step never holds more
+#: block rows per grid step (one f32 sublane tile); a leaf's rows are
+#: padded to a multiple with zero rows (Mosaic cannot broadcast the
+#: per-row thresholds of a lone row)
 ROWS = 8
-#: pass-2 compaction tile (lanes per one-hot matmul)
+#: pass-2 tile: lanes per tile of a block row (a leaf shorter than TILE
+#: takes its 128-padded block as one tile; n_tiles is padded to a multiple
+#: of 8). Per row, picking tile and lane costs O(kbp * (n_tiles + TILE))
+#: VPU work: TILE = 256 = sqrt(65536) balances the two on the 65536-entry
+#: rows. TILE may not exceed 256: a rank within a tile must be exact in
+#: bf16. VMEM per grid step at block 65536: the double-buffered g block
+#: (2 x ROWS x 256 KiB = 4 MiB) and pass 1's bit patterns (2 MiB); per row
+#: its transposed bits, two rank matrices (256 KiB each), the bf16 ranks
+#: (128 KiB) and the int8 bytes (256 KiB); per slot group the fetched
+#: bytes (4 x TILE x SLOTS int32 = 512 KiB) and ranks (128 KiB): about
+#: 8 MiB, inside the 16 MiB scoped default.
 TILE = 256
+#: output slots per fetch matmul (the one-hot's lane width)
+SLOTS = 128
 #: bisection steps for the per-row top-k threshold. 32 integer halvings of
 #: [-1, bits(max)] always terminate with lo/hi ADJACENT — hi is exactly the
 #: kb-th largest |value|'s bit pattern and the tie band holds only exact
@@ -58,24 +100,47 @@ TILE = 256
 #: resolution ~max/2^iters and silently mis-selects rows whose |values| all
 #: sit below it)
 BISECT_ITERS = 32
-
-_NT = (((1,), (1,)), ((), ()))      # contract the lane axes: a @ b.T
-_HI = jax.lax.Precision.HIGHEST
+#: a slot's rank target that no entry carries (ranks lie in [-256, 256])
+_NO_RANK = 1024.0
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+def _tiling(block: int):
+    """(T, n_tiles) of a block row: T lanes per tile, n_tiles padded to a
+    sublane multiple so every transpose and matmul operand is aligned."""
+    tile = min(TILE, _round_up(block, LANES))
+    return tile, _round_up(-(-block // tile), 8)
+
+
+def _tri(n: int, lower: bool):
+    """(n, n) bf16 0/1 triangle, diagonal included: lower[i, j] = j <= i."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return ((j <= i) if lower else (i <= j)).astype(jnp.bfloat16)
+
+
+def _bf16(mask):
+    return mask.astype(jnp.float32).astype(jnp.bfloat16)
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
 def _decision_kernel(g_ref, idx_ref, gg_ref, gath_ref, ti_ref, tv_ref, *,
-                     kb: int, tile: int, iters: int):
+                     kb: int, iters: int):
     """One (client, row-group) grid step.
 
-    Refs: g (R, Bp) f32 rows; idx (R, kbp) int32 LBG positions (-1 pads);
-    gg (1, LANES) per-client accumulator; gath/ti/tv (R, kbp) f32 outputs.
+    Refs: g (R, n_tiles, T) f32 rows; idx (R, kbp) int32 LBG positions
+    (-1 pads); gg (1, LANES) per-client accumulator; gath/tv (R, kbp) f32
+    and ti (R, kbp) int32 outputs.
     """
-    R, Bp = g_ref.shape
+    R, nt, T = g_ref.shape
     kbp = idx_ref.shape[1]
+    shift = T.bit_length() - 1                    # T is 128 or 256
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -85,80 +150,121 @@ def _decision_kernel(g_ref, idx_ref, gg_ref, gath_ref, ti_ref, tv_ref, *,
     gg_ref[...] += jnp.sum(g * g)
 
     # ---- pass 1: bisect each row's kb-th largest |value| t* into (lo, hi]
-    # in bit space. Invariant: count(ai > lo) >= kb > count(ai > hi)
-    # (ai >= 0, so the initial lo = -1 counts Bp >= kb; nothing exceeds
-    # the max). lo + (hi - lo) // 2, NOT (lo + hi) // 2: bit patterns of
-    # values >= 2.0 exceed 2^30, so the naive midpoint overflows int32.
-    ai = jax.lax.bitcast_convert_type(jnp.abs(g), jnp.int32)
-    top = jax.lax.bitcast_convert_type(
-        jnp.max(jnp.abs(g), axis=1, keepdims=True), jnp.int32)
+    # in bit space, all rows of the step at once. |value| by clearing the
+    # sign bit, never by float arithmetic, which may flush subnormals.
+    # Invariant: count(ai > lo) >= kb > count(ai > hi) (ai >= 0, so the
+    # initial lo = -1 counts the whole row; nothing exceeds the max).
+    # lo + (hi - lo) // 2, NOT (lo + hi) // 2: bit patterns of values
+    # >= 2.0 exceed 2^30, so the naive midpoint overflows int32.
+    ai = jax.lax.bitcast_convert_type(g, jnp.int32) & 0x7FFFFFFF
+    # sum over tiles (whole vregs) before lanes (a cross-lane reduce)
+    count = lambda mask: jnp.sum(jnp.sum(mask.astype(jnp.float32), axis=1,
+                                         keepdims=True), axis=2,
+                                 keepdims=True)           # (R, 1, 1)
 
     def bis(_, lh):
         lo, hi = lh
         mid = lo + ((hi - lo) >> 1)
-        big = jnp.sum((ai > mid).astype(jnp.float32), axis=1,
-                      keepdims=True) >= kb
+        big = count(ai > mid) >= kb
         return jnp.where(big, mid, lo), jnp.where(big, hi, mid)
 
-    lo, hi = jax.lax.fori_loop(
-        0, iters, bis, (jnp.full((R, 1), -1, jnp.int32), top))
-    # "definite" entries sit strictly above the band; ties (== t*) fill
-    # the remaining slots in index order. For rows with fewer than kb
-    # nonzeros the band is the zeros, so every nonzero is still kept.
-    m = jnp.sum((ai > hi).astype(jnp.float32), axis=1, keepdims=True)
+    top = jnp.max(jnp.max(ai, axis=1, keepdims=True), axis=2, keepdims=True)
+    lo_r, hi_r = jax.lax.fori_loop(
+        0, iters, bis, (jnp.full((R, 1, 1), -1, jnp.int32), top))
+    # "definite" entries sit strictly above the band; ties (== t*) fill the
+    # remaining slots in index order. For rows with fewer than kb nonzeros
+    # the band is the zeros, so every nonzero is still kept (classify in
+    # bit space: lo may be -1, not a float to compare against).
+    m_r = count(ai > hi_r)
+    which = jax.lax.broadcasted_iota(jnp.int32, (R, 1, 1), 0)
 
-    # ---- pass 2: tiled compaction + gather (one-hot matmuls)
-    gath_ref[...] = jnp.zeros_like(gath_ref)
-    ti_ref[...] = jnp.zeros_like(ti_ref)
-    tv_ref[...] = jnp.zeros_like(tv_ref)
-    # inclusive-cumsum operator tri[i, j] = (i <= j); 0/1 is exact in bf16
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
-           <= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
-           ).astype(jnp.bfloat16)
-    # 1-indexed output slot of each one-hot row; pad slots (>= kb) never
-    # match, which is also the cap for selections past kb
-    srow = jax.lax.broadcasted_iota(jnp.int32, (kbp, tile), 0)
-    slot_id = jnp.where(srow < kb, srow + 1, -1).astype(jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-    sub = jax.lax.broadcasted_iota(jnp.int32, (tile, kbp), 0)
-    idx = idx_ref[...]
+    lower_t = _tri(T, lower=True)                 # in-tile prefix (T, T)
+    upper_n = _tri(nt, lower=False)               # over tiles (nt, nt)
+    sub8 = jax.lax.broadcasted_iota(jnp.int32, (8, nt), 0)
+    lane_t = jax.lax.broadcasted_iota(jnp.int32, (T, SLOTS), 0)
+    tile_s = jax.lax.broadcasted_iota(jnp.int32, (nt, SLOTS), 0)
+    slot0 = jax.lax.broadcasted_iota(jnp.int32, (1, SLOTS), 1) + 1
+    row_r = jax.lax.broadcasted_iota(jnp.int32, (R, SLOTS), 0)
 
-    def tl(t, carry):
-        cdef, ctie = carry
-        off = pl.multiple_of(t * tile, tile)
-        g_t = g_ref[:, pl.ds(off, tile)]                  # (R, T)
-        # classify in the same bit space the threshold lives in (lo may be
-        # -1, which is not a valid float to compare against)
-        ai_t = jax.lax.bitcast_convert_type(jnp.abs(g_t), jnp.int32)
-        dm = ai_t > hi
-        sm = (ai_t > lo) & (ai_t <= hi)
-        cum_d = jnp.dot(dm.astype(jnp.bfloat16), tri,
-                        preferred_element_type=jnp.float32) + cdef
-        cum_t = jnp.dot(sm.astype(jnp.bfloat16), tri,
-                        preferred_element_type=jnp.float32) + ctie
-        # definites take slots 1..m (m < kb), ties m+1..; 0 = unselected
-        slot = jnp.where(dm, cum_d, jnp.where(sm, m + cum_t, 0.0))
-        pos = (lane + off).astype(jnp.float32)            # (1, T)
-        hit = (sub + off)                                 # (T, kbp)
-        for r in range(R):
-            g_r = g_t[r:r + 1, :]
-            oh = (slot[r:r + 1, :] == slot_id).astype(jnp.float32)
-            tv_ref[r:r + 1, :] += jax.lax.dot_general(
-                g_r, oh, _NT, precision=_HI,
-                preferred_element_type=jnp.float32)
-            ti_ref[r:r + 1, :] += jax.lax.dot_general(
-                pos, oh, _NT, precision=_HI,
-                preferred_element_type=jnp.float32)
-            # gather at the LBG positions (positions < 2^24, exact in f32)
-            oh2 = (hit == idx[r:r + 1, :]).astype(jnp.float32)
-            gath_ref[r:r + 1, :] += jnp.dot(
-                g_r, oh2, precision=_HI,
-                preferred_element_type=jnp.float32)
-        return (cdef + jnp.sum(dm.astype(jnp.float32), axis=1, keepdims=True),
-                ctie + jnp.sum(sm.astype(jnp.float32), axis=1, keepdims=True))
+    def row(r, carry):
+        own = lambda v: jnp.sum(jnp.where(which == r, v, 0), axis=0)
+        lo, hi, m = own(lo_r), own(hi_r), own(m_r)            # (1, 1)
+        # ---- pass 2, level 2 first: the row transposed (lanes of a tile
+        # on sublanes; an int transpose, since a small f32 one may pass
+        # through the MXU, which flushes subnormals) and each entry's
+        # inclusive rank inside its tile, definites +, ties -, others 0
+        bits = jax.lax.bitcast_convert_type(g_ref[r], jnp.int32).T  # (T, nt)
+        ait = bits & 0x7FFFFFFF
+        dm = ait > hi
+        sm = (ait > lo) & (ait <= hi)
+        r_def = _dot(lower_t, _bf16(dm))
+        r_tie = _dot(lower_t, _bf16(sm))
+        rank = jnp.where(dm, r_def, jnp.where(sm, -r_tie, 0.0)
+                         ).astype(jnp.bfloat16)
+        # ---- level 1: the tiles' counts are the ranks' last row; their
+        # inclusive prefix over tiles (counts <= T are bf16-exact, sums
+        # < 2^24), turned into columns (nt, 1) by an int transpose
+        cnt = jnp.where(sub8 == 0, r_def[T - 1:], jnp.where(
+            sub8 == 1, r_tie[T - 1:], 0.0)).astype(jnp.bfloat16)
+        pre = _dot(cnt, upper_n).astype(jnp.int32).T          # (nt, 8)
+        c_def = pre[:, :1].astype(jnp.float32)
+        c_tie = pre[:, 1:2].astype(jnp.float32)
+        # the fetch operand: the four bytes of each bit pattern, signed
+        # into int8 (an int8 matmul with int32 sums is exact)
+        pieces = jnp.concatenate(
+            [(bits << (24 - 8 * p)) >> 24 for p in range(4)],
+            axis=0).astype(jnp.int8)                          # (4T, nt)
 
-    zero = jnp.zeros((R, 1), jnp.float32)
-    jax.lax.fori_loop(0, Bp // tile, tl, (zero, zero))
+        def pick(tile, hit):
+            """The bit pattern at each slot's `hit` lane of its one-hot
+            column `tile`: one int8 fetch, bytes selected one at a time."""
+            fetch = jnp.dot(pieces, tile.astype(jnp.int8),
+                            preferred_element_type=jnp.int32)  # (4T, SLOTS)
+            out = jnp.zeros((1, SLOTS), jnp.int32)
+            for p in range(4):
+                byte = jnp.sum(jnp.where(hit, fetch[p * T:(p + 1) * T], 0),
+                               axis=0, keepdims=True)
+                out = out | ((byte & 0xFF) << (8 * p))
+            return out
+
+        def group(q, c):
+            off = pl.multiple_of(q * SLOTS, SLOTS)
+            s = (slot0 + off).astype(jnp.float32)             # (1, SLOTS)
+            is_def = s <= m
+            want = jnp.where(is_def, s, s - m)                # class ordinal
+            cum = jnp.where(is_def, c_def, c_tie)             # (nt, SLOTS)
+            before = cum < want
+            a = jnp.sum(before.astype(jnp.float32), axis=0, keepdims=True)
+            rho = want - jnp.max(jnp.where(before, cum, 0.0), axis=0,
+                                 keepdims=True)
+            target = jnp.where(is_def, rho,
+                               jnp.where(s <= kb, -rho, _NO_RANK))
+            a = a.astype(jnp.int32)
+            tile = tile_s == a                                # (nt, SLOTS)
+            hit = _dot(rank, _bf16(tile)) == target           # (T, SLOTS)
+            lane = jnp.sum(jnp.where(hit, lane_t, 0), axis=0, keepdims=True)
+            cols = pl.ds(off, SLOTS)
+
+            def put(ref, val):
+                # row r of an (R, SLOTS) window: Mosaic stores whole
+                # sublane tiles at a dynamic lane offset
+                ref[:, cols] = jnp.where(row_r == r, val, ref[:, cols])
+
+            put(tv_ref, jax.lax.bitcast_convert_type(pick(tile, hit),
+                                                     jnp.float32))
+            put(ti_ref, (a << shift) + lane)
+            # the gather: tile idx // T, lane idx % T (-1 pads fetch zeros)
+            i = jnp.sum(jnp.where(row_r == r, idx_ref[:, cols], 0), axis=0,
+                        keepdims=True)
+            put(gath_ref, jax.lax.bitcast_convert_type(
+                pick(tile_s == (i >> shift), lane_t == (i & (T - 1))),
+                jnp.float32))
+            return c
+
+        jax.lax.fori_loop(0, kbp // SLOTS, group, 0)
+        return carry
+
+    jax.lax.fori_loop(0, R, row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -170,11 +276,12 @@ def lbgm_sparse_decision_batched_pallas(blocks: jax.Array, idx: jax.Array,
     top_val (B, nb, kb) f32)`` — each client's rows of g read once, each
     top-k row emitted in index order.
 
-    The lane axes are padded before the call: g with zeros up to a tile
-    multiple (pass 1's strict compares never select a pad zero ahead of
-    real data — pads sit at the highest positions and a row holds at
+    Each row is padded with zeros to n_tiles * T entries and viewed as
+    (n_tiles, T) (pass 1's strict compares never select a pad zero ahead
+    of real data — pads sit at the highest positions and a row holds at
     least ``block >= kb`` real entries — and pads add exact zeros to
-    ||g||^2), idx with -1 up to a multiple of 128 (matches no position).
+    ||g||^2);
+    idx is padded with -1 up to a multiple of 128 (matches no position).
     Pad rows (up to a multiple of ``ROWS``) add zeros to ||g||^2 and are
     sliced off the outputs.
     """
@@ -185,34 +292,32 @@ def lbgm_sparse_decision_batched_pallas(blocks: jax.Array, idx: jax.Array,
     assert blocks.shape[:2] == idx.shape[:2]
     B, nb, block = blocks.shape
     kb = idx.shape[2]
-    tile = min(TILE, _round_up(block, LANES))
-    Bp = _round_up(block, tile)
+    tile, nt = _tiling(block)
     kbp = _round_up(kb, LANES)
-    rows = min(ROWS, nb)
-    nbp = _round_up(nb, rows)
+    nbp = _round_up(nb, ROWS)
     blocks = jnp.pad(blocks.astype(jnp.float32),
-                     ((0, 0), (0, nbp - nb), (0, Bp - block)))
+                     ((0, 0), (0, nbp - nb), (0, nt * tile - block))
+                     ).reshape(B, nbp, nt, tile)
     idx = jnp.pad(idx.astype(jnp.int32),
                   ((0, 0), (0, nbp - nb), (0, kbp - kb)), constant_values=-1)
-    kernel = functools.partial(_decision_kernel, kb=kb, tile=tile,
-                               iters=BISECT_ITERS)
-    row_spec = lambda w: pl.BlockSpec((pl.squeezed, rows, w),
-                                      lambda b, j: (b, j, 0))
-    out_rows = jax.ShapeDtypeStruct((B, nbp, kbp), jnp.float32)
+    kernel = functools.partial(_decision_kernel, kb=kb, iters=BISECT_ITERS)
+    row_spec = pl.BlockSpec((pl.squeezed, ROWS, kbp), lambda b, j: (b, j, 0))
+    out_rows = lambda dt: jax.ShapeDtypeStruct((B, nbp, kbp), dt)
     gg, gath, ti, tv = pl.pallas_call(
         kernel,
-        grid=(B, nbp // rows),
-        in_specs=[row_spec(Bp), row_spec(kbp)],
+        grid=(B, nbp // ROWS),
+        in_specs=[pl.BlockSpec((pl.squeezed, ROWS, nt, tile),
+                               lambda b, j: (b, j, 0, 0)), row_spec],
         out_specs=[pl.BlockSpec((pl.squeezed, 1, LANES),
                                 lambda b, j: (b, 0, 0)),
-                   row_spec(kbp), row_spec(kbp), row_spec(kbp)],
+                   row_spec, row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((B, 1, LANES), jnp.float32),
-                   out_rows, out_rows, out_rows],
+                   out_rows(jnp.float32), out_rows(jnp.int32),
+                   out_rows(jnp.float32)],
         interpret=interpret,
         name="lbgm_sparse_decision",
     )(blocks, idx)
-    return (gg[:, 0, 0], gath[:, :nb, :kb],
-            ti[:, :nb, :kb].astype(jnp.int32), tv[:, :nb, :kb])
+    return gg[:, 0, 0], gath[:, :nb, :kb], ti[:, :nb, :kb], tv[:, :nb, :kb]
 
 
 def lbgm_sparse_decision_pallas(blocks: jax.Array, idx: jax.Array,

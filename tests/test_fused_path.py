@@ -65,7 +65,9 @@ def test_projection_vmap_routes_to_batched_kernel(key):
 
 
 @pytest.mark.parametrize("nb,block,kb", [(1, 700, 33), (3, 512, 17),
-                                         (16, 1000, 9)])
+                                         (16, 1000, 9),
+                                         # multi-tile rows at the LM's width
+                                         (2, 65536, 655)])
 def test_sparse_decision_kernel_matches_ref(key, nb, block, kb):
     blocks = jax.random.normal(key, (nb, block))
     perm = jnp.argsort(
@@ -394,28 +396,73 @@ def test_lbg_kw_reserved_key_actionable_error(fcn_setup):
 # ----------------------------- (e) threshold-select decision kernel edges
 
 
-@pytest.mark.parametrize("nb,block,kb", [(1, 700, 33), (3, 512, 17),
-                                         (16, 1000, 9), (4, 256, 256),
-                                         (12, 300, 7)])
-def test_two_pass_kernel_matches_ref_setwise(key, nb, block, kb):
+def _edge_rows(key, nb, block, kb, data):
+    """(blocks, idx) for the decision kernel's edge cases, made in numpy so
+    subnormals survive (XLA's CPU arithmetic flushes them)."""
+    rng = np.random.default_rng(int(jax.random.randint(key, (), 0, 2**30)))
+    g = rng.standard_normal((nb, block)).astype(np.float32)
+    idx = np.argsort(rng.standard_normal((nb, block)), axis=1)[:, :kb]
+    if data == "packed":
+        # every selection in one 256-lane tile (kb == 256): tile 5
+        g *= np.float32(1e-3)
+        g[:, 5 * 256:6 * 256] = rng.uniform(1, 2, (nb, 256))
+    elif data == "ties":
+        # 10 definites, then 21 equal |values| straddling lanes 255 | 256,
+        # half of which fill the slots in index order
+        g *= np.float32(1e-3)
+        g[:, 600:610] = 5.0
+        g[:, 250:271] = np.where(np.arange(21) % 2, 1.0, -1.0)
+    elif data == "zero_row":
+        g[0] = 0.0
+    elif data == "subnormal":
+        g = g * np.float32(1e-40)
+        g[np.abs(g) < 2e-41] = np.float32(-0.0)
+        g[:, 3] = np.float32(-0.0)
+    elif data == "fresh_bank":
+        idx = np.zeros((nb, kb), np.int64)     # a fresh bank: all zeros
+    return jnp.asarray(g), jnp.asarray(idx, jnp.int32)
+
+
+@pytest.mark.parametrize("nb,block,kb,data", [
+    pytest.param(*c, "normal", id="-".join(map(str, c)))
+    for c in [(1, 700, 33), (3, 512, 17), (16, 1000, 9), (4, 256, 256),
+              (12, 300, 7)]] + [
+    (3, 4096, 256, "packed"), (2, 1024, 20, "ties"),
+    (2, 512, 17, "zero_row"), (2, 2048, 40, "subnormal"),
+    (3, 1000, 30, "fresh_bank")])
+def test_two_pass_kernel_matches_ref_setwise(key, nb, block, kb, data):
     """The decision kernel (no in-kernel top_k / take_along_axis) must
     select the exact same (idx, val) SET per block row as the sorted
     oracle — slot order is by index, so compare through the canonical
-    form — with the gathered values and ||g||^2 agreeing too."""
+    form — with the gathered values bit for bit and ||g||^2 agreeing too.
+    Edge data: all selections packed into one tile, ties straddling a
+    tile boundary, an all-zero row, subnormal and -0.0 values, and a fresh
+    bank's all-zero (repeated) LBG positions."""
     from repro.kernels.lbgm_sparse import lbgm_sparse_decision_pallas
-    blocks = jax.random.normal(key, (nb, block))
-    perm = jnp.argsort(
-        jax.random.normal(jax.random.fold_in(key, 2), (nb, block)), axis=1)
-    idx = perm[:, :kb].astype(jnp.int32)
+    if data == "normal":
+        blocks = jax.random.normal(key, (nb, block))
+        perm = jnp.argsort(jax.random.normal(jax.random.fold_in(key, 2),
+                                             (nb, block)), axis=1)
+        idx = perm[:, :kb].astype(jnp.int32)
+    else:
+        blocks, idx = _edge_rows(key, nb, block, kb, data)
     gg, gath, ti, tv = lbgm_sparse_decision_pallas(
         blocks, idx, interpret=True)
     rgg, rgath, rti, rtv = ref.lbgm_sparse_decision_ref(blocks, idx)
+    bits = lambda a: np.asarray(a).view(np.int32)
     np.testing.assert_allclose(float(gg), float(rgg), rtol=1e-5)
-    np.testing.assert_array_equal(np.asarray(gath), np.asarray(rgath))
+    np.testing.assert_array_equal(bits(gath), bits(rgath))
     si, sv = ref.sort_topk_rows(ti, tv)
     ri, rv = ref.sort_topk_rows(rti, rtv)
     np.testing.assert_array_equal(np.asarray(si), np.asarray(ri))
-    np.testing.assert_array_equal(np.asarray(sv), np.asarray(rv))
+    np.testing.assert_array_equal(bits(sv), bits(rv))
+    # slot order: the entries above the kb-th |value| by index, then the
+    # entries equal to it by index
+    mag = bits(tv) & 0x7FFFFFFF
+    for i, a in zip(np.asarray(ti), mag):
+        above = a > a.min()
+        assert not (np.diff(above.astype(int)) > 0).any()
+        assert (np.diff(i[above]) > 0).all() and (np.diff(i[~above]) > 0).all()
 
 
 def test_two_pass_kernel_tiny_magnitudes(key):

@@ -63,6 +63,8 @@ def _calls(hlo, kernel):
     (192, 65536, 655),     # one 2048x6144 leaf, k_frac = 0.01
     (1, 2048, 20),         # one d_model norm leaf
     (52, 65536, 655),      # one model rank's rows of a 208-row leaf, m = 4
+    (608, 65536, 639),     # the 18992x2048 embedding (594 rows hold data)
+    (8, 256, 250),         # one tile, kb close to the block
 ])
 def test_decision_kernel_compiles_for_v5e(one_chip, nb, block, kb):
     hlo = _compile(
