@@ -426,7 +426,7 @@ def _edge_rows(key, nb, block, kb, data):
 @pytest.mark.parametrize("nb,block,kb,data", [
     pytest.param(*c, "normal", id="-".join(map(str, c)))
     for c in [(1, 700, 33), (3, 512, 17), (16, 1000, 9), (4, 256, 256),
-              (12, 300, 7)]] + [
+              (12, 300, 7), (2, 100, 10)]] + [
     (3, 4096, 256, "packed"), (2, 1024, 20, "ties"),
     (2, 512, 17, "zero_row"), (2, 2048, 40, "subnormal"),
     (3, 1000, 30, "fresh_bank")])
@@ -435,9 +435,10 @@ def test_two_pass_kernel_matches_ref_setwise(key, nb, block, kb, data):
     select the exact same (idx, val) SET per block row as the sorted
     oracle — slot order is by index, so compare through the canonical
     form — with the gathered values bit for bit and ||g||^2 agreeing too.
-    Edge data: all selections packed into one tile, ties straddling a
-    tile boundary, an all-zero row, subnormal and -0.0 values, and a fresh
-    bank's all-zero (repeated) LBG positions."""
+    Edge data: a block shorter than one 128-lane tile, all selections
+    packed into one tile, ties straddling a tile boundary, an all-zero
+    row, subnormal and -0.0 values, and a fresh bank's all-zero (repeated)
+    LBG positions."""
     from repro.kernels.lbgm_sparse import lbgm_sparse_decision_pallas
     if data == "normal":
         blocks = jax.random.normal(key, (nb, block))
