@@ -16,12 +16,18 @@ Phases (one process; each prints its own lines):
 * ``lm`` — qwen3-1.7b at published widths, cut to 4 layers and 1/8 of the
   vocabulary, on markov data: 8 clients, chunked scheduler, top-k store,
   int8 wire codec (the batched sparse-decision kernel).
+* ``moe`` — the held-expert layer of ``kanana-2-30b-a3b`` at the
+  benchmark cell's widths and batch (8 of 128 experts, top-6, shared
+  experts, 4 x 256 tokens): the program's masked einsum over the held
+  experts against the plain per-expert reference
+  (``benchmarks/chip/models/deepseek_v3.py``), forward and gradients,
+  with the program's forward + backward time.
 * ``lm-mesh`` (``--chips 4``) — the ``lm`` round on the sharded scheduler
   with the mesh-aware top-k store, on a [4, 1] client mesh and on a
   [2, 2] mesh with tensor-parallel client compute
   (``model_sharding="auto"``), each against the chunked run on one chip.
 
-Every one-chip phase runs its round with ``fused_kernels=None`` (auto: the
+Every one-chip round phase runs its round with ``fused_kernels=None`` (auto: the
 kernels compile on a TPU) and again with ``fused_kernels=False`` (plain
 XLA), asserts agreement, and asserts that the compiled round's HLO calls
 each kernel of its path (``tpu_custom_call``). Times are printed for
@@ -49,10 +55,10 @@ RTOL = 1e-4
 LM_MODEL = {"arch": "qwen3-1.7b", "reduced": False, "n_layers": 4,
             "vocab_size": 18992, "dtype": "float32"}
 LM_SEQ = 256
-#: clients per scan step on one chip: the compiled round needs 14.4 GiB
-#: at 2 (1.21 GiB arguments + 1.21 GiB outputs - 0.17 GiB aliased +
-#: 12.1 GiB temporaries, XLA memory_analysis for v5e), ~6 GiB more per
-#: extra client
+#: clients per scan step on one chip: the benchmark's round at chunk 2
+#: (the same model and traffic) compiles to 11.5 GiB for a v5e (1.04 GiB
+#: arguments + 1.04 GiB outputs - 0.14 GiB aliased + 9.6 GiB temporaries,
+#: XLA memory_analysis), about 4 GiB more per extra client
 LM_CHUNK = 2
 
 
@@ -215,6 +221,84 @@ def phase_kernels():
                           "shape": [4, n], "rtol": 1e-4}), flush=True)
 
 
+def _rel(a, b) -> float:
+    import numpy as np
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def _ms(fn, *args, n=10):
+    import jax
+    jax.block_until_ready(fn(*args))
+    t = time.time()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.time() - t) / n
+
+
+#: the held experts run at the cell's DEFAULT precision (one bfloat16
+#: pass, float32 accumulation) against a float32 HIGHEST reference:
+#: relative error of a few 1e-3, where the bfloat16 reference's is ~1e-2
+MOE_RTOL = 2e-2
+
+
+def phase_moe():
+    """The held-expert layer at the cell's widths against the reference."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.models import moe as moe_lib
+    bench = os.path.join(ROOT, "benchmarks", "chip")
+    spec = importlib.util.spec_from_file_location(
+        "bench_deepseek_v3", os.path.join(bench, "models", "deepseek_v3.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    with open(os.path.join(bench, "configs",
+                           "kanana-2-30b-a3b-5l.json")) as f:
+        cj = json.load(f)
+    base = get_config("kanana-2-30b-a3b")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(
+        base.moe, held=cj["n_routed_experts"],
+        held_offset=cj["held_expert_offset"]))
+    shapes = {k[len("blocks/moe/"):]: v[1:] for k, v in ref.shapes(cj).items()
+              if k.startswith("blocks/moe/")}
+    keys = jax.random.split(jax.random.PRNGKey(0), len(shapes) + 2)
+    p = {k: 0.02 * jax.random.normal(kk, shp)
+         for kk, (k, shp) in zip(keys, sorted(shapes.items()))}
+    x = jax.random.normal(keys[-2], (4, LM_SEQ, cj["hidden_size"]))
+    dy = jax.random.normal(keys[-1], x.shape)
+
+    def prog(p, x):
+        return jnp.sum(moe_lib.apply_dropless(p, x, cfg)[0] * dy)
+
+    def dense(p, x):
+        rp = {"moe/" + k: v for k, v in p.items()}
+        return jnp.sum(ref._moe(rp, x, cj) * dy)
+
+    grad = lambda f: jax.jit(jax.value_and_grad(f, argnums=(0, 1)))
+    _, (gp, gx) = grad(prog)(p, x)
+    with jax.default_matmul_precision("highest"):
+        _, (rgp, rgx) = grad(dense)(p, x)
+    bf = lambda t: jax.tree.map(lambda a: a.astype(jnp.bfloat16), t)
+    _, (cgp, cgx) = grad(dense)(bf(p), bf(x))
+    errs = {"x": _rel(gx, rgx), **{k: _rel(gp[k], rgp[k]) for k in gp}}
+    ctrl = {"x": _rel(cgx, rgx), **{k: _rel(cgp[k], rgp[k]) for k in cgp}}
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda p, x: ref._moe(
+            {"moe/" + k: v for k, v in p.items()}, x, cj))(p, x)
+    got, rows = jax.jit(lambda p, x: moe_lib.apply_dropless(p, x, cfg))(p, x)
+    out = _rel(got, want)
+    print(json.dumps({"phase": "moe", "forward_rel": out,
+                      "grad_rel": errs, "bf16_reference_grad_rel": ctrl,
+                      "routed_rows": float(rows)}), flush=True)
+    assert out < MOE_RTOL and max(errs.values()) < MOE_RTOL, (out, errs)
+    print(json.dumps({"phase": "moe",
+                      "fwd_bwd_ms": _ms(grad(prog), p, x)}), flush=True)
+
+
 def phase_fcn():
     auto = run_arm("fcn", "kernels", fcn_spec(None))
     xla = run_arm("fcn", "xla", fcn_spec(False))
@@ -288,7 +372,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4 runs only the lm-mesh phase (needs 4 chips)")
-    ap.add_argument("--only", choices=("kernels", "fcn", "lm"),
+    ap.add_argument("--only", choices=("kernels", "fcn", "lm", "moe"),
                     help="run this one-chip phase alone")
     args = ap.parse_args(argv)
 

@@ -3,8 +3,9 @@ from __future__ import annotations
 
 import importlib
 
-from repro.configs.base import (ArchConfig, LBGMConfig, MoEConfig, ShapeConfig,
-                                INPUT_SHAPES, param_count, active_param_count)
+from repro.configs.base import (ArchConfig, LBGMConfig, MLAConfig, MoEConfig,
+                                ShapeConfig, INPUT_SHAPES, param_count,
+                                active_param_count)
 
 _MODULES = {
     "llama4-maverick-400b-a17b": "repro.configs.llama4_maverick_400b_a17b",
@@ -17,6 +18,7 @@ _MODULES = {
     "qwen2-vl-2b": "repro.configs.qwen2_vl_2b",
     "yi-34b": "repro.configs.yi_34b",
     "deepseek-67b": "repro.configs.deepseek_67b",
+    "kanana-2-30b-a3b": "repro.configs.kanana_2_30b_a3b",
     "paper-cnn": "repro.configs.paper_cnn",
     "paper-fcn": "repro.configs.paper_fcn",
 }
@@ -35,7 +37,7 @@ def all_configs():
 
 
 __all__ = [
-    "ArchConfig", "LBGMConfig", "MoEConfig", "ShapeConfig", "INPUT_SHAPES",
+    "ArchConfig", "LBGMConfig", "MLAConfig", "MoEConfig", "ShapeConfig", "INPUT_SHAPES",
     "param_count", "active_param_count", "get_config", "all_configs",
     "ASSIGNED_ARCHS",
 ]

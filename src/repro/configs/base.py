@@ -23,6 +23,32 @@ class MoEConfig:
     top_k: int = 1
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.01   # load-balance loss coefficient
+    # "softmax": softmax top-k, capacity dispatch, load-balance loss
+    # (Mixtral / Llama 4); "sigmoid": DeepSeek-V3's sigmoid scores, top-k
+    # selected on score + bias (noaux_tc), dropless over the held experts
+    scoring: str = "softmax"
+    d_expert: int = 0               # expert width; 0 => ArchConfig.d_ff
+    n_shared: int = 0               # shared experts: one SwiGLU n_shared x wide
+    routed_scale: float = 1.0       # sigmoid: routed_scaling_factor
+    # the chip's share under expert parallelism: experts
+    # [held_offset, held_offset + held) live here (0 => all); the router
+    # keeps all num_experts outputs and routes to absent experts add nothing
+    held: int = 0
+    held_offset: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V3, no q LoRA, interleaved
+    rotary); a ``kv_lora_rank`` of 0 turns it off."""
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_dim: int = 128
 
 
 @dataclass(frozen=True)
@@ -60,6 +86,10 @@ class ArchConfig:
     vocab_size: int = 32768
     head_dim: int = 0               # 0 => d_model // n_heads
     moe: MoEConfig = field(default_factory=MoEConfig)
+    mla: MLAConfig = field(default_factory=MLAConfig)
+    # leading dense layers of a scanned MoE stack (DeepSeek's
+    # first_k_dense_replace)
+    first_dense: int = 0
     # block pattern: tuple cycled over layers. entries:
     #   "attn" (global), "swa" (sliding-window attn), "rwkv6", "rglru"
     block_pattern: Tuple[str, ...] = ("attn",)
@@ -112,10 +142,19 @@ class ArchConfig:
             dtype="float32",
             mrope_sections=(4, 6, 6) if self.mrope else self.mrope_sections,
             lbgm=dataclasses.replace(self.lbgm, num_clients=4),
+            first_dense=min(self.first_dense, 1),
         )
         if self.moe.num_experts:
             small["moe"] = dataclasses.replace(
-                self.moe, num_experts=min(self.moe.num_experts, 4))
+                self.moe, num_experts=min(self.moe.num_experts, 4),
+                top_k=min(self.moe.top_k, 2))
+            if self.moe.d_expert:
+                small["moe"] = dataclasses.replace(
+                    small["moe"], d_expert=min(self.moe.d_expert, 64))
+        if self.mla.kv_lora_rank:
+            small["mla"] = dataclasses.replace(
+                self.mla, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+                v_dim=16)
         small.update(overrides)
         return dataclasses.replace(self, **small)
 
@@ -136,6 +175,20 @@ INPUT_SHAPES = {
 }
 
 
+def _mla_param_count(cfg: ArchConfig) -> int:
+    m, d, H = cfg.mla, cfg.d_model, cfg.n_heads
+    r = m.kv_lora_rank
+    return (d * H * (m.qk_nope_dim + m.qk_rope_dim)      # q
+            + d * (r + m.qk_rope_dim) + r                 # kv_a + its norm
+            + r * H * (m.qk_nope_dim + m.v_dim)           # kv_b
+            + H * m.v_dim * d)                            # o
+
+
+def is_moe_layer(cfg: ArchConfig, layer: int) -> bool:
+    return (bool(cfg.moe.num_experts) and layer >= cfg.first_dense
+            and cfg.block_kind(layer) in ("attn", "swa"))
+
+
 def param_count(cfg: ArchConfig) -> int:
     """Analytic parameter count (embeddings + blocks + head)."""
     d, ff, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
@@ -146,7 +199,9 @@ def param_count(cfg: ArchConfig) -> int:
         total += V * d                  # lm head
     for layer in range(cfg.n_layers):
         kind = cfg.block_kind(layer)
-        if kind in ("attn", "swa"):
+        if kind in ("attn", "swa") and cfg.mla.kv_lora_rank:
+            total += _mla_param_count(cfg)
+        elif kind in ("attn", "swa"):
             total += d * n_q * hd + 2 * d * n_kv * hd + n_q * hd * d
         elif kind == "rwkv6":
             # r,k,v,g,o projections + decay lora + mixing params
@@ -154,8 +209,10 @@ def param_count(cfg: ArchConfig) -> int:
         elif kind == "rglru":
             # conv4 + input/gate projections + recurrent params
             total += 4 * d + 2 * d * d + 3 * d
-        if cfg.moe.num_experts and kind in ("attn", "swa"):
-            total += cfg.moe.num_experts * 3 * d * ff + d * cfg.moe.num_experts
+        if is_moe_layer(cfg, layer):
+            fe = cfg.moe.d_expert or ff
+            total += ((cfg.moe.n_held + cfg.moe.n_shared) * 3 * d * fe
+                      + d * cfg.moe.num_experts)
         else:
             total += 3 * d * ff
         total += 2 * d                  # norms
@@ -173,8 +230,9 @@ def active_param_count(cfg: ArchConfig) -> int:
     if not cfg.moe.num_experts:
         return param_count(cfg)
     dense = param_count(cfg)
-    d, ff = cfg.d_model, cfg.d_ff
-    moe_layers = sum(1 for l in range(cfg.n_layers)
-                     if cfg.block_kind(l) in ("attn", "swa"))
-    inactive = moe_layers * (cfg.moe.num_experts - cfg.moe.top_k) * 3 * d * ff
-    return dense - inactive
+    d, m = cfg.d_model, cfg.moe
+    fe = m.d_expert or cfg.d_ff
+    moe_layers = sum(is_moe_layer(cfg, l) for l in range(cfg.n_layers))
+    # of the held experts, a token meets top_k * held / num_experts
+    idle = m.n_held * (1.0 - m.top_k / m.num_experts)
+    return int(dense - moe_layers * idle * 3 * d * fe)

@@ -235,8 +235,9 @@ from repro.fed.registry import (LBG_STORES, SCHEDULERS, register_lbg_store,
 from repro.fed.robust import (CollectDenseAggregator,
                               CollectSparseAggregator,
                               ScalarMedianSparseAggregator, make_robust_rule)
-from repro.fed.spans import (CLIENT_SGD, CODEC, DECISION, FOLD, H2D,
-                             HOST_PREP, PREFETCH_WAIT, READBACK, scope, span)
+from repro.fed.spans import (CLIENT_SGD, CODEC, COUNTERS, DECISION, FOLD,
+                             H2D, HOST_PREP, PREFETCH_WAIT, READBACK, scope,
+                             span)
 from repro.kernels.ref import lbgm_dequant_accum_ref
 
 #: reserved batch key: per-client local-step budgets (the buffered
@@ -679,6 +680,19 @@ def _seq_weighted_sum(acc, w, gt_stack):
     return out
 
 
+def _counters(aux) -> Dict[str, jax.Array]:
+    """The program counters (``spans.COUNTERS``) a loss reports in its
+    metrics dict, as float32 scalars."""
+    return {k: jnp.asarray(aux[k], jnp.float32) for k in COUNTERS
+            if k in aux}
+
+
+def _summed_counters(stats, maskf) -> Dict[str, jax.Array]:
+    """Each counter of the per-client ``stats``, summed over the clients
+    that ``maskf`` counts."""
+    return {k: jnp.sum(v * maskf) for k, v in stats.items() if k != "loss"}
+
+
 def _keep_sampled(maskf, new, old):
     """Unsampled clients keep their previous per-client state."""
     return jax.tree.map(
@@ -793,7 +807,8 @@ class ChunkedScheduler:
             else:
                 loss, uplink, scalar, wire = ys
                 out = agg.finalize(acc)
-        return (out, new_lbg, new_res, loss.reshape(Kp)[:K],
+        return (out, new_lbg, new_res,
+                jax.tree.map(lambda x: x.reshape(Kp)[:K], loss),
                 uplink.reshape(Kp)[:K], scalar.reshape(Kp)[:K],
                 wire.reshape(Kp)[:K])
 
@@ -873,7 +888,7 @@ class BufferedScheduler(ChunkedScheduler):
         gt, loss, uplink, scalar, wire = ys
         flat = lambda x: x.reshape((-1,) + x.shape[2:])
         send, gscale = jax.tree.map(flat, gt)
-        loss, uplink, scalar, wire = (flat(loss), flat(uplink),
+        loss, uplink, scalar, wire = (jax.tree.map(flat, loss), flat(uplink),
                                       flat(scalar), flat(wire))
 
         def gate(new, old):
@@ -914,7 +929,8 @@ class BufferedScheduler(ChunkedScheduler):
                      nbuf["gscale"].reshape(n_chunks, chunk)))
                 out = agg.finalize(acc)
         dlv = lambda x: x[:K] * deliverf
-        return (out, new_lbg, new_res, nbuf, loss[:K],
+        return (out, new_lbg, new_res, nbuf,
+                jax.tree.map(lambda x: x[:K], loss),
                 dlv(nbuf["uplink"]), dlv(nbuf["scalar"]),
                 dlv(nbuf["wire"]))
 
@@ -1176,7 +1192,7 @@ class ShardedScheduler(ChunkedScheduler):
                 b = jax.tree.map(lambda v: cst(v, P(ax)), b)
                 asg, nr, loss, cost, thru = jax.vmap(
                     lambda bb, rr: pre(p, bb, rr))(b, r)
-                loss = cst(loss, P(ax))
+                loss = jax.tree.map(lambda v: cst(v, P(ax)), loss)
                 blocked = {
                     name: jax.vmap(
                         lambda g, nb=layouts[name][0],
@@ -1289,7 +1305,8 @@ class ShardedScheduler(ChunkedScheduler):
             else:
                 loss, uplink, scalar, wire = ys
                 out = agg.finalize(acc)
-        return (out, new_lbg, new_res, loss.reshape(Kp)[:K],
+        return (out, new_lbg, new_res,
+                jax.tree.map(lambda x: x.reshape(Kp)[:K], loss),
                 uplink.reshape(Kp)[:K], scalar.reshape(Kp)[:K],
                 wire.reshape(Kp)[:K])
 
@@ -1578,19 +1595,29 @@ class FLEngine:
         cfg = self.cfg
         loss_fn = self.loss_fn
 
+        # the gradient sum rides in the scan carry: one gradient-sized
+        # buffer, where stacking the tau gradients for a sum after the
+        # scan held tau of them
+        zero = lambda params: jax.tree.map(jnp.zeros_like, params)
+
         if not hetero_tau:
             def client_update(params, batches):
-                """tau local steps; batches: dict leaves (tau, b, ...)."""
-                def step(p, bt):
-                    (l, _), g = jax.value_and_grad(loss_fn,
-                                                   has_aux=True)(p, bt)
+                """tau local steps; batches: dict leaves (tau, b, ...).
+                -> (summed gradient, {"loss": mean loss, counters summed
+                over the steps})."""
+                def step(carry, bt):
+                    p, asg = carry
+                    (l, aux), g = jax.value_and_grad(loss_fn,
+                                                     has_aux=True)(p, bt)
                     p2 = jax.tree.map(
                         lambda x, gg: x - cfg.lr * gg.astype(x.dtype),
                         p, g)
-                    return p2, (g, l)
-                _, (gs, ls) = jax.lax.scan(step, params, batches)
-                asg = jax.tree.map(lambda g: jnp.sum(g, 0), gs)
-                return asg, jnp.mean(ls)
+                    return (p2, jax.tree.map(jnp.add, asg, g)), (
+                        l, _counters(aux))
+                (_, asg), (ls, cs) = jax.lax.scan(
+                    step, (params, zero(params)), batches)
+                return asg, {"loss": jnp.mean(ls),
+                             **jax.tree.map(lambda c: jnp.sum(c, 0), cs)}
 
             return client_update
 
@@ -1601,20 +1628,22 @@ class FLEngine:
             no-ops (zero gradient, frozen params), so a slow client's
             accumulated update and reported loss cover exactly its
             ``tau_k`` real steps."""
-            def step(p, xt):
+            def step(carry, xt):
+                p, asg = carry
                 i, bt = xt
-                (l, _), g = jax.value_and_grad(loss_fn, has_aux=True)(p,
-                                                                      bt)
+                (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                    p, bt)
                 on = (i < tau_k).astype(jnp.float32)
                 g = jax.tree.map(lambda gg: gg * on.astype(gg.dtype), g)
                 p2 = jax.tree.map(
                     lambda x, gg: x - cfg.lr * gg.astype(x.dtype), p, g)
-                return p2, (g, l, on)
-            _, (gs, ls, ons) = jax.lax.scan(
-                step, params, (jnp.arange(cfg.tau), batches))
-            asg = jax.tree.map(lambda g: jnp.sum(g, 0), gs)
+                cs = jax.tree.map(lambda c: c * on, _counters(aux))
+                return (p2, jax.tree.map(jnp.add, asg, g)), (l, on, cs)
+            (_, asg), (ls, ons, cs) = jax.lax.scan(
+                step, (params, zero(params)), (jnp.arange(cfg.tau), batches))
             loss = jnp.sum(ls * ons) / jnp.maximum(jnp.sum(ons), 1.0)
-            return asg, loss
+            return asg, {"loss": loss,
+                         **jax.tree.map(lambda c: jnp.sum(c, 0), cs)}
 
         return client_update
 
@@ -1797,11 +1826,12 @@ class FLEngine:
                         lambda p, a: p - cfg.lr * a.astype(p.dtype), params,
                         agg_out)
                 metrics = {
-                    "loss": jnp.sum(losses * wl),
+                    "loss": jnp.sum(losses["loss"] * wl),
                     "uplink_floats": jnp.sum(uplink),
                     "frac_scalar": jnp.sum(scalar)
                     / jnp.maximum(jnp.sum(deliverf), 1.0),
                     "wire_bytes": jnp.sum(wire),
+                    **_summed_counters(losses, dispatchf),
                 }
                 return new_params, new_lbg, new_res, new_buf, metrics
 
@@ -1828,11 +1858,12 @@ class FLEngine:
                 new_params = jax.tree.map(
                     jax.lax.with_sharding_constraint, new_params, pshard)
             metrics = {
-                "loss": jnp.sum(losses * w),
+                "loss": jnp.sum(losses["loss"] * w),
                 "uplink_floats": jnp.sum(uplink * maskf),
                 "frac_scalar": jnp.sum(scalar.astype(jnp.float32) * maskf)
                 / jnp.maximum(jnp.sum(maskf), 1.0),
                 "wire_bytes": jnp.sum(wire * maskf),
+                **_summed_counters(losses, maskf),
             }
             return new_params, new_lbg, new_res, metrics
 
@@ -1893,11 +1924,12 @@ class FLEngine:
                 new_params = jax.tree.map(
                     lambda p, a: p - cfg.lr * a.astype(p.dtype), params, out)
             metrics = {
-                "loss": jnp.sum(losses * w),
+                "loss": jnp.sum(losses["loss"] * w),
                 "uplink_floats": jnp.sum(uplink * maskf),
                 "frac_scalar": jnp.sum(scalar.astype(jnp.float32) * maskf)
                 / jnp.maximum(jnp.sum(maskf), 1.0),
                 "wire_bytes": jnp.sum(wire * maskf),
+                **_summed_counters(losses, maskf),
             }
             return new_params, metrics
 
@@ -1934,7 +1966,8 @@ class FLEngine:
             # barrier: every write-back has landed, so self.lbg (the
             # host array) is the post-round bank when this returns
             st.finish_round()
-        cat = lambda xs: jnp.concatenate(list(xs))[:K]
+        cat = lambda xs: jax.tree.map(
+            lambda *x: jnp.concatenate(x)[:K], *xs)
         loss, uplink, scalar, wire = (cat(x) for x in zip(*outs))
         self.params, metrics = self._host_final(
             self.params, acc, loss, uplink, scalar, wire, w, maskf)

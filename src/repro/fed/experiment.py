@@ -456,6 +456,10 @@ def _lm_model(seed: int = 0, arch: str = "qwen3-1.7b", reduced: bool = True,
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
+    # a dict overrides fields of a nested config (``moe``, ``mla``)
+    arch_overrides = {
+        k: dataclasses.replace(getattr(cfg, k), **v) if isinstance(v, dict)
+        else v for k, v in arch_overrides.items()}
     if arch_overrides:
         cfg = dataclasses.replace(cfg, **arch_overrides)
     params, axes = init_lm(jax.random.PRNGKey(seed), cfg)

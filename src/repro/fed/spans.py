@@ -11,6 +11,10 @@ running a span costs one small object per call.
 """
 import jax
 
+#: the model's own stages (inside fl.client_sgd) and its counters
+from repro.models.common import (COUNTERS, MLA, MODEL_SCOPES,  # noqa: F401
+                                 MOE_EXPERTS, MOE_ROUTE, MOE_ROUTED_ROWS)
+
 #: device stages (named scopes inside the jitted round)
 CLIENT_SGD = "fl.client_sgd"   # the local-SGD scan
 DECISION = "fl.decision"       # the Algorithm-1 store step

@@ -72,13 +72,14 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
               q_offset: int = 0, q_chunk: int = 1024,
               unroll: bool = False) -> jax.Array:
-    """GQA attention. q:(B,Tq,Hq,hd), k/v:(B,Tk,Hkv,hd) -> (B,Tq,Hq,hd).
+    """GQA attention. q:(B,Tq,Hq,hd), k:(B,Tk,Hkv,hd), v:(B,Tk,Hkv,hv) ->
+    (B,Tq,Hq,hv); scores are scaled by hd^-1/2.
 
     ``q_offset``: absolute position of q[0] relative to k[0] (for caches).
     ``window``: sliding-window width (keys with qpos-kpos >= window masked).
     """
     B, Tq, Hq, hd = q.shape
-    Tk, Hkv = k.shape[1], k.shape[2]
+    Tk, Hkv, hv = k.shape[1], k.shape[2], v.shape[-1]
     assert Hq % Hkv == 0
     g = Hq // Hkv
     qg = q.reshape(B, Tq, Hkv, g, hd)
@@ -96,7 +97,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         qpos = jnp.arange(Tq) + q_offset
         need_mask = causal or (window is not None)
         o = _sdpa_block(qg, k, v, mask_for(qpos) if need_mask else None)
-        return o.reshape(B, Tq, Hq, hd)
+        return o.reshape(B, Tq, Hq, hv)
 
     while Tq % q_chunk:      # largest divisor of Tq not above q_chunk
         q_chunk -= 1
@@ -113,7 +114,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         for i in range(n)])
     else:
         _, os = jax.lax.scan(body, None, (jnp.arange(n), qs))
-    return os.transpose(1, 0, 2, 3, 4, 5).reshape(B, Tq, Hq, hd)
+    return os.transpose(1, 0, 2, 3, 4, 5).reshape(B, Tq, Hq, hv)
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

@@ -16,6 +16,20 @@ import numpy as np
 Params = Dict[str, jax.Array]
 Axes = Dict[str, Tuple[str, ...]]
 
+#: stage names the model gives its ops (``jax.named_scope``); inside the
+#: federated round they sit within ``fl.client_sgd`` (``repro.fed.spans``),
+#: and a trace puts an op down to its innermost ``fl.*`` scope, so these
+#: split the client's time
+MLA = "fl.mla"                  # latent attention
+MOE_ROUTE = "fl.moe_route"      # router and route weights
+MOE_EXPERTS = "fl.moe_experts"  # held experts, shared experts
+MODEL_SCOPES = (MLA, MOE_ROUTE, MOE_EXPERTS)
+
+#: program counters a loss may report in its aux dict; ``run_round``'s
+#: metrics carry each, summed over the round's clients and local steps
+MOE_ROUTED_ROWS = "moe_routed_rows"  # rows sent to held experts, all layers
+COUNTERS = (MOE_ROUTED_ROWS,)
+
 
 class ParamStore:
     """Collects params + logical axes during model init."""

@@ -1,12 +1,19 @@
 """Decoder-LM assembly covering all assigned architecture families.
 
 Supports: dense GQA (llama/yi/deepseek/mistral/qwen3), MoE (mixtral/llama4),
+DeepSeek-V3 blocks (MLA + sigmoid-routed dropless MoE with shared experts),
 SSM (rwkv6), hybrid RG-LRU+local-attn (recurrentgemma), enc-dec (whisper
 backbone) and VLM early-fusion (qwen2-vl backbone, M-RoPE).
 
 Homogeneous stacks (single-entry block_pattern, no enc-dec) are *stacked*
 along a leading "layers" axis and run under ``lax.scan`` (+ optional remat);
-heterogeneous patterns unroll.
+leading dense layers of an MoE stack (``first_dense``) sit in front of it,
+unrolled, as ``dense_<i>``; heterogeneous patterns unroll.
+
+Each block returns its auxiliary outputs as a dict of scalars summed over
+layers: ``aux`` (the capacity router's load-balance loss, added to the
+loss) and ``moe_routed_rows`` (the dropless router's count of rows sent to
+held experts, a counter).
 """
 from __future__ import annotations
 
@@ -17,12 +24,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.models import mla as mla_lib
 from repro.models import moe as moe_lib
 from repro.models import rglru as rglru_lib
 from repro.models import rwkv6 as rwkv6_lib
 from repro.models.attention import (attention, decode_attention, mrope_rotate,
                                     rope_rotate)
-from repro.models.common import ParamStore, rms_norm, subtree, swiglu
+from repro.models.common import (MLA, MOE_ROUTED_ROWS, ParamStore, rms_norm,
+                                 subtree, swiglu)
 
 
 # ------------------------------------------------------------------ init
@@ -49,8 +58,9 @@ def _init_attn(store: ParamStore, prefix: str, cfg: ArchConfig, stack: int,
                     init="ones")
 
 
-def _init_ffn(store: ParamStore, prefix: str, cfg: ArchConfig, stack: int):
-    if cfg.moe.num_experts:
+def _init_ffn(store: ParamStore, prefix: str, cfg: ArchConfig, stack: int,
+              dense: bool = False):
+    if cfg.moe.num_experts and not dense:
         moe_lib.init_moe(store, prefix + "/moe", cfg, stack)
     else:
         d, ff = cfg.d_model, cfg.d_ff
@@ -62,12 +72,14 @@ def _init_ffn(store: ParamStore, prefix: str, cfg: ArchConfig, stack: int):
 
 
 def _init_block(store: ParamStore, prefix: str, cfg: ArchConfig, kind: str,
-                stack: int = 0, cross: bool = False):
+                stack: int = 0, cross: bool = False, dense: bool = False):
     d = cfg.d_model
     lead = (stack,) if stack else ()
     lx = ("layers",) if stack else ()
     store.param(f"{prefix}/norm1", lead + (d,), lx + ("embed",), init="ones")
-    if kind in ("attn", "swa"):
+    if kind in ("attn", "swa") and cfg.mla.kv_lora_rank:
+        mla_lib.init_mla(store, prefix + "/mla", cfg, stack)
+    elif kind in ("attn", "swa"):
         _init_attn(store, prefix, cfg, stack)
     elif kind == "rwkv6":
         rwkv6_lib.init_rwkv6(store, prefix + "/tmix", cfg, stack)
@@ -80,7 +92,7 @@ def _init_block(store: ParamStore, prefix: str, cfg: ArchConfig, kind: str,
                     init="ones")
         _init_attn(store, prefix, cfg, stack, cross=True)
     store.param(f"{prefix}/norm2", lead + (d,), lx + ("embed",), init="ones")
-    _init_ffn(store, prefix, cfg, stack)
+    _init_ffn(store, prefix, cfg, stack, dense)
 
 
 def uses_scan(cfg: ArchConfig) -> bool:
@@ -102,8 +114,11 @@ def init_lm(key: jax.Array, cfg: ArchConfig):
         for i in range(cfg.n_layers):
             _init_block(store, f"dec_{i:02d}", cfg, "attn", cross=True)
     elif uses_scan(cfg):
+        for i in range(cfg.first_dense):
+            _init_block(store, f"dense_{i:02d}", cfg, cfg.block_pattern[0],
+                        dense=True)
         _init_block(store, "blocks", cfg, cfg.block_pattern[0],
-                    stack=cfg.n_layers)
+                    stack=cfg.n_layers - cfg.first_dense)
     else:
         for i in range(cfg.n_layers):
             _init_block(store, f"layer_{i:02d}", cfg, cfg.block_kind(i))
@@ -149,16 +164,37 @@ def _apply_cross_attn(p, x, enc_out, cfg: ArchConfig):
     return jnp.einsum("bth,hd->btd", o.reshape(B, T, nq * hd), p["wx_o"])
 
 
-def _apply_ffn(p, x, cfg: ArchConfig):
-    if cfg.moe.num_experts:
-        return moe_lib.apply_moe(subtree(p, "moe"), x, cfg)
-    return swiglu(x, p["w_gate"], p["w_up"], p["w_down"]), 0.0
+def _aux_zero(cfg: ArchConfig) -> Dict[str, Any]:
+    """A stack's auxiliary outputs (see the module's docstring), zero."""
+    if not cfg.moe.num_experts:
+        return {}
+    if cfg.moe.scoring == "sigmoid":
+        return {MOE_ROUTED_ROWS: 0.0}
+    return {"aux": 0.0}
+
+
+def _aux_add(a: Dict[str, Any], b: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: a.get(k, 0.0) + b.get(k, 0.0) for k in {**a, **b}}
+
+
+def _apply_ffn(p, x, cfg: ArchConfig, dense: bool = False):
+    """-> (out, the block's auxiliary outputs)."""
+    if cfg.moe.num_experts and not dense:
+        if cfg.moe.scoring == "sigmoid":
+            out, rows = moe_lib.apply_dropless(subtree(p, "moe"), x, cfg)
+            return out, {MOE_ROUTED_ROWS: rows}
+        out, aux = moe_lib.apply_moe(subtree(p, "moe"), x, cfg)
+        return out, {"aux": aux}
+    return swiglu(x, p["w_gate"], p["w_up"], p["w_down"]), {}
 
 
 def _apply_block_train(p, x, cfg: ArchConfig, kind: str, positions, pos3=None,
-                       enc_out=None, causal_attn=True):
+                       enc_out=None, causal_attn=True, dense: bool = False):
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
-    if kind in ("attn", "swa"):
+    if kind in ("attn", "swa") and cfg.mla.kv_lora_rank:
+        with jax.named_scope(MLA):
+            h = mla_lib.apply_mla(subtree(p, "mla"), h, cfg, positions)
+    elif kind in ("attn", "swa"):
         if causal_attn:
             h = _apply_attn_train(p, h, cfg, kind, positions, pos3)
         else:  # encoder self-attention
@@ -178,7 +214,7 @@ def _apply_block_train(p, x, cfg: ArchConfig, kind: str, positions, pos3=None,
         hx = rms_norm(x, p["norm_x"], cfg.norm_eps)
         x = x + _apply_cross_attn(p, hx, enc_out, cfg)
     h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
-    h2, aux = _apply_ffn(p, h2, cfg)
+    h2, aux = _apply_ffn(p, h2, cfg, dense)
     return x + h2, aux
 
 
@@ -204,9 +240,9 @@ def build_mrope_positions(cfg: ArchConfig, B: int, T: int):
 def forward_hidden(params: Dict[str, jax.Array], cfg: ArchConfig,
                    tokens: jax.Array,
                    extra_embeds: Optional[jax.Array] = None
-                   ) -> Tuple[jax.Array, jax.Array]:
+                   ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Backbone forward to the final hidden states. tokens (B,T) ->
-    (hidden (B,T,d), aux loss).
+    (hidden (B,T,d), auxiliary outputs summed over layers).
 
     ``extra_embeds``: modality-stub embeddings. audio (enc-dec): encoder
     input frames (B, Te, d). vlm: patch embeddings (B, n_vis, d) that
@@ -222,7 +258,7 @@ def forward_hidden(params: Dict[str, jax.Array], cfg: ArchConfig,
             nv = extra_embeds.shape[1]
             x = jnp.concatenate([extra_embeds.astype(x.dtype), x[:, nv:]],
                                 axis=1)
-    aux_total = 0.0
+    aux_total = _aux_zero(cfg)
 
     enc_out = None
     if cfg.encdec:
@@ -233,25 +269,33 @@ def forward_hidden(params: Dict[str, jax.Array], cfg: ArchConfig,
         for i in range(cfg.n_encoder_layers):
             e, aux = _apply_block_train(subtree(params, f"enc_{i:02d}"), e,
                                         cfg, "attn", None, causal_attn=False)
-            aux_total += aux
+            aux_total = _aux_add(aux_total, aux)
         enc_out = rms_norm(e, params["enc_norm"], cfg.norm_eps)
         for i in range(cfg.n_layers):
             x, aux = _apply_block_train(subtree(params, f"dec_{i:02d}"), x,
                                         cfg, "attn", positions,
                                         enc_out=enc_out)
-            aux_total += aux
+            aux_total = _aux_add(aux_total, aux)
     elif uses_scan(cfg):
         kind = cfg.block_pattern[0]
+        for i in range(cfg.first_dense):
+            blk = functools.partial(
+                _apply_block_train, subtree(params, f"dense_{i:02d}"),
+                cfg=cfg, kind=kind, positions=positions, pos3=pos3,
+                dense=True)
+            if cfg.remat:
+                blk = jax.checkpoint(blk)
+            x, _ = blk(x)
         stacked = subtree(params, "blocks")
 
         def body(carry, layer_p):
             h, aux_acc = carry
             h, aux = _apply_block_train(layer_p, h, cfg, kind, positions,
                                         pos3)
-            return (h, aux_acc + aux), None
+            return (h, _aux_add(aux_acc, aux)), None
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        (x, aux_total), _ = jax.lax.scan(body_fn, (x, 0.0), stacked)
+        (x, aux_total), _ = jax.lax.scan(body_fn, (x, aux_total), stacked)
     else:
         for i in range(cfg.n_layers):
             blk = functools.partial(
@@ -260,7 +304,7 @@ def forward_hidden(params: Dict[str, jax.Array], cfg: ArchConfig,
             if cfg.remat:
                 blk = jax.checkpoint(blk)
             x, aux = blk(x)
-            aux_total += aux
+            aux_total = _aux_add(aux_total, aux)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux_total
@@ -271,7 +315,8 @@ def _head(params, cfg: ArchConfig):
 
 
 def forward(params, cfg: ArchConfig, tokens, extra_embeds=None):
-    """Full-logit forward (small models / tests). -> (logits (B,T,V), aux)."""
+    """Full-logit forward (small models / tests). -> (logits (B,T,V),
+    auxiliary outputs)."""
     x, aux = forward_hidden(params, cfg, tokens, extra_embeds)
     return jnp.einsum("btd,dv->btv", x, _head(params, cfg)), aux
 
@@ -287,8 +332,11 @@ def lm_loss(params, cfg: ArchConfig, tokens, labels, extra_embeds=None,
     """Next-token CE with a *chunked* softmax over T so the (B,T,V) logits
     never materialize (vocab up to 256k x 1M tokens would not fit).
     labels = next tokens (caller-shifted); negative labels are masked.
+    The metrics carry the load-balance term ``aux`` and any counters of
+    the blocks (``moe_routed_rows``).
     """
-    x, aux = forward_hidden(params, cfg, tokens, extra_embeds)
+    x, stats = forward_hidden(params, cfg, tokens, extra_embeds)
+    aux = stats.get("aux", 0.0)
     head = _head(params, cfg)
     B, T, d = x.shape
     c = min(ce_chunk, T)
@@ -317,4 +365,4 @@ def lm_loss(params, cfg: ArchConfig, tokens, labels, extra_embeds=None,
         (tot, cnt), _ = jax.lax.scan(chunk_ce, (0.0, 0.0), (xs, ls))
     ce = tot / jnp.maximum(cnt, 1.0)
     loss = ce + aux
-    return loss, {"ce": ce, "aux": aux}
+    return loss, {**stats, "ce": ce, "aux": aux}

@@ -73,6 +73,7 @@ def test_full_config_matches_assignment(arch):
         "qwen2-vl-2b": (28, 1536, 12, 2, 8960, 151936),
         "yi-34b": (60, 7168, 56, 8, 20480, 64000),
         "deepseek-67b": (95, 8192, 64, 8, 22016, 102400),
+        "kanana-2-30b-a3b": (48, 2048, 32, 32, 6144, 128256),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
            cfg.d_ff, cfg.vocab_size)

@@ -102,3 +102,34 @@ def test_quantized_fold_compiles_for_v5e(one_chip, codec_cls):
                    ((CHUNK, nb, kb), jnp.int32),
                    ((CHUNK, nb, kb), jnp.float32), ((2,), jnp.uint32))
     assert "tpu_custom_call" not in hlo
+
+
+@pytest.mark.parametrize("clients", [1, 2])
+def test_held_experts_compile_for_v5e(one_chip, clients):
+    """The held-expert layer of kanana-2-30b-a3b at the benchmark cell's
+    widths and batch (8 of 128 experts, top-6, 4 x 256 tokens), forward
+    and backward, vmapped over the scheduler's clients, compiles for a
+    v5e as plain XLA (no custom call)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import moe as moe_lib
+    base = get_config("kanana-2-30b-a3b")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(base.moe,
+                                                            held=8))
+    d, f, H, E = cfg.d_model, cfg.moe.d_expert, 8, cfg.moe.num_experts
+    fs = cfg.moe.n_shared * f
+    shapes = {"router": (d, E), "w_gate": (H, d, f), "w_up": (H, d, f),
+              "w_down": (H, f, d), "shared_gate": (d, fs),
+              "shared_up": (d, fs), "shared_down": (fs, d)}
+    names = sorted(shapes)
+
+    def loss(*args):
+        p = dict(zip(names, args[:-1]))
+        return jnp.sum(moe_lib.apply_dropless(p, args[-1], cfg)[0] ** 2)
+
+    step = jax.vmap(jax.grad(loss, argnums=tuple(range(len(names) + 1))))
+    hlo = _compile(step, one_chip,
+                   *[((clients,) + shapes[n], jnp.float32) for n in names],
+                   ((clients, 4, 256, d), jnp.float32))
+    assert "tpu_custom_call" not in hlo
