@@ -9,7 +9,8 @@ entry points (ExperimentSpec -> run_experiment -> FLEngine).
 Phases (one process; each prints its own lines):
 
 * ``kernels`` — the Pallas kernels of the main path at the LM's leaf
-  shapes against their XLA references (``kernels/ref.py``).
+  shapes against their XLA references (``kernels/ref.py``), each run on
+  the chip; the quantized fold with both paths' times.
 * ``fcn`` — the paper FCN at its published widths on mixture data with a
   label-skew split, default vmap scheduler + dense LBG store (the batched
   projection kernel).
@@ -102,7 +103,8 @@ def round_kernels(engine):
                               batch, mask).compile().as_text()
     calls = [l for l in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in l]
-    return sorted({k for k in ("lbgm_projection", "lbgm_sparse_decision")
+    return sorted({k for k in ("lbgm_projection", "lbgm_sparse_decision",
+                               "lbgm_dequant_fold")
                    if any(k in l for l in calls)})
 
 
@@ -219,6 +221,67 @@ def phase_kernels():
                 rtol=1e-4, atol=1e-3)
         print(json.dumps({"phase": "kernels", "kernel": "lbgm_projection",
                           "shape": [4, n], "rtol": 1e-4}), flush=True)
+    phase_fold()
+
+
+def phase_fold():
+    """The quantized fold kernel against the XLA gather-scatter fold it
+    replaced (``ref.lbgm_dequant_accum_ref``), both on the chip, at the
+    int8 cell's 2048x6144 leaf (chunk 2) and a kanana-2-30b-a3b-5l expert
+    leaf (4 x 8 x 2048 x 768, chunk 1): NaN positions equal, values
+    within one rounding, and the bits that differ (with the largest ulp
+    gap) and each path's time for one chunk are printed. int8: the chunk's clients, all real;
+    fp8: a phantom client first (w = 0, NaN gscale and values), and a real
+    client sends two NaNs."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.comm.wire import Fp8Codec, Int8Codec
+    from repro.kernels import ref
+    from repro.kernels.lbgm_sparse import (fold_carry_shape,
+                                           lbgm_dequant_fold_pallas)
+    rng = np.random.default_rng(1)
+    xla = jax.jit(ref.lbgm_dequant_accum_ref)
+    for C, nb, block, kb in ((2, 192, 65536, 655), (1, 768, 65536, 655)):
+        acc = rng.standard_normal((nb, block), np.float32)
+        # the block is a whole number of tiles: the carry is a reshape
+        carry = jnp.asarray(acc.reshape(fold_carry_shape(nb, block)))
+        for codec in (Int8Codec(stochastic=False),
+                      Fp8Codec(stochastic=False)):
+            n = C + (codec.name == "fp8")
+            idx = np.stack([np.stack([rng.choice(block, kb, replace=False)
+                                      for _ in range(nb)])
+                            for _ in range(n)]).astype(np.int32)
+            qv, scale = jax.vmap(lambda v: codec.quantize(v, None))(
+                jnp.asarray(rng.standard_normal((n, nb, kb), np.float32)))
+            w = rng.random(n).astype(np.float32) + 0.5
+            gs = rng.random(n).astype(np.float32)
+            if n > C:
+                w[0], gs[0] = 0.0, np.nan
+                qv = qv.at[0].set(jnp.nan).at[n - 1, 0, 1].set(jnp.nan)
+                qv = qv.at[n - 1, nb - 1, kb - 1].set(jnp.nan)
+            rest = (jnp.asarray(w), jnp.asarray(gs), jnp.asarray(idx), qv,
+                    scale)
+            got = np.asarray(lbgm_dequant_fold_pallas(carry, *rest)
+                             ).reshape(nb, block)
+            want = np.asarray(xla(jnp.asarray(acc), *rest))
+            nan = np.isnan(want)
+            np.testing.assert_array_equal(np.isnan(got), nan)
+            # bits are reported, not required: a backend that fuses
+            # acc + coeff * q into one multiply-add in one path only rounds
+            # once there, off by one rounding of a sum below 8 in magnitude
+            np.testing.assert_allclose(got[~nan], want[~nan], rtol=1e-6,
+                                       atol=1e-6)
+            ulp = np.abs(got[~nan].view(np.int32).astype(np.int64)
+                         - want[~nan].view(np.int32).astype(np.int64))
+            print(json.dumps({
+                "phase": "kernels", "kernel": "lbgm_dequant_fold",
+                "codec": codec.name, "shape": [n, nb, block, kb],
+                "nan": int(nan.sum()), "bit_exact": bool(ulp.max() == 0),
+                "max_ulp": int(ulp.max()),
+                "positions_off": int((ulp > 0).sum()),
+                "kernel_ms": _ms(lbgm_dequant_fold_pallas, carry, *rest),
+                "xla_ms": _ms(xla, jnp.asarray(acc), *rest)}), flush=True)
 
 
 def _rel(a, b) -> float:
@@ -313,7 +376,8 @@ def phase_lm():
     # codec refuses: this arm ships fp32. Round 1 is codec-independent
     # (every client's first round is a full round from the same params).
     xla = run_arm("lm", "xla+fp32", lm_spec(False, codec="none"))
-    assert auto["kernels"] == ["lbgm_sparse_decision"], auto["kernels"]
+    assert auto["kernels"] == ["lbgm_dequant_fold",
+                               "lbgm_sparse_decision"], auto["kernels"]
     assert xla["kernels"] == [], xla["kernels"]
     agree(auto, xla, all_rounds=False)
 
@@ -346,7 +410,8 @@ def phase_lm_mesh():
                           model_sharding="auto", **mesh),
                   inspect=bank_placement)
     for r, (c, m) in ((r41, (4, 1)), (r22, (2, 2))):
-        assert r["kernels"] == ["lbgm_sparse_decision"], r
+        assert r["kernels"] == ["lbgm_dequant_fold",
+                                "lbgm_sparse_decision"], r
         n_chunks, chunk, nb, kb = r["bank_shape"]
         # block rows shard over the model axis where they divide
         want = [n_chunks, chunk // c, nb // m if nb % m == 0 else nb, kb]

@@ -153,8 +153,8 @@ class WireCodec:
     :meth:`encode_dense` at the tail of ``client_fn`` — after the uplink
     pipeline and the LBGM store step, i.e. on exactly what would be
     serialized — and the aggregator seam dequantizes via
-    :meth:`decode_leaf` (or inside the dequantizing scatter-accumulate,
-    ``kernels.ref.lbgm_dequant_accum_ref``).
+    :meth:`decode_leaf` (or inside the dequantizing fold,
+    ``kernels.lbgm_sparse.lbgm_dequant_fold_pallas``).
     """
 
     name = "none"

@@ -189,9 +189,9 @@ with one power-of-two scale per block row. Encoding happens in
 ``client_fn`` *after* the uplink pipeline (the bank stores the
 server-decodable values, so recycle rounds stay deployment-faithful);
 decoding happens at the aggregator seam — for quantized streaming
-aggregation the dequantize is fused into the scatter-accumulate
-(:class:`SparseCodecAggregator` -> ``kernels.ref.lbgm_dequant_accum_ref``), so
-no fp32 payload stack is ever materialized. Per-client ``wire_bytes`` ride
+aggregation the dequantize is fused into the fold (the
+:class:`SparseCodecAggregator` kernel), so no fp32 payload stack is ever
+materialized. Per-client ``wire_bytes`` ride
 the scheduler outputs next to ``uplink`` and land in the
 :class:`~repro.comm.accounting.CommLedger` (savings vs vanilla fp32 dense).
 
@@ -238,7 +238,8 @@ from repro.fed.robust import (CollectDenseAggregator,
 from repro.fed.spans import (CLIENT_SGD, CODEC, COUNTERS, DECISION, FOLD,
                              H2D, HOST_PREP, PREFETCH_WAIT, READBACK, scope,
                              span)
-from repro.kernels.ref import lbgm_dequant_accum_ref
+from repro.kernels.lbgm_sparse import (fold_carry_shape,
+                                       lbgm_dequant_fold_pallas)
 
 #: reserved batch key: per-client local-step budgets (the buffered
 #: scheduler's compute heterogeneity) — stripped before the SGD scan
@@ -573,26 +574,38 @@ class SparseTopKAggregator:
 class SparseCodecAggregator(SparseTopKAggregator):
     """Streaming aggregation of QUANTIZED sparse payloads.
 
-    Same strictly sequential per-client fold, layout, and finalize as
+    The same strictly sequential per-client fold as
     :class:`SparseTopKAggregator`, but each client's payload arrives in
     the wire layout ``{idx, val (int8/fp8), scale}`` and the dequantize
-    (widen + per-block-row scale multiply) happens *inside* the
-    accumulate: one ``kernels.ref.lbgm_dequant_accum_ref`` gather-modify-
-    scatter scan per leaf per chunk, so the fp32 (C, nb, kb) payload stack
-    is never materialized — the values widen on the fly as they scatter
-    into the fp32 accumulator. Plain XLA on every backend: its work is
-    O(C·nb·kb), which a dense one-hot Pallas fold (O(C·nb·block)) cannot
-    beat.
+    (widen + per-block-row scale multiply) happens inside the fold: one
+    ``kernels.lbgm_sparse.lbgm_dequant_fold_pallas`` call per leaf per
+    chunk (interpreted off the TPU), so the fp32 (C, nb, kb) payload stack
+    is never materialized. The carry holds each block row as the kernel's
+    (n_tiles, T) tile matrix (``fold_carry_shape``), read and written once
+    per chunk, in place. Each client's row expands to that dense matrix
+    by a one-hot MXU matmul, O(C·nb·block) MACs; the O(C·nb·kb) XLA
+    gather-scatter it replaces (``kernels.ref.lbgm_dequant_accum_ref``,
+    now the tests' oracle) ran at a TPU scatter's serial rate, about
+    22 ns an entry on a v5e.
     """
 
     payload_keys = ("idx", "val", "scale")
 
+    def init(self, params):
+        return {name: jnp.zeros(fold_carry_shape(nb, block), jnp.float32)
+                for name, (_, _, nb, block) in self._layout.items()}
+
     def accumulate(self, acc, w, out):
         send, gscale = out   # idx/val (C, nb, kb); scale (C, nb, 1)
-        return {name: lbgm_dequant_accum_ref(acc[name], w, gscale,
-                                             sk["idx"], sk["val"],
-                                             sk["scale"])
+        return {name: lbgm_dequant_fold_pallas(acc[name], w, gscale,
+                                               sk["idx"], sk["val"],
+                                               sk["scale"])
                 for name, sk in send.items()}
+
+    def finalize(self, acc):
+        return super().finalize(
+            {name: acc[name].reshape(nb, -1)[:, :block]
+             for name, (_, _, nb, block) in self._layout.items()})
 
 
 def make_aggregator(cfg: FLConfig, store, params, codec):
@@ -612,7 +625,7 @@ def make_aggregator(cfg: FLConfig, store, params, codec):
     and reduced once per round (see ``repro.fed.robust``).
 
     The *codec* is the third axis: a lossy codec hands sparse payloads to
-    the dequantizing scatter-accumulate (:class:`SparseCodecAggregator`) on the
+    the dequantizing fold (:class:`SparseCodecAggregator`) on the
     streaming path, and hands the collect adapters its ``decode_leaf`` /
     ``payload_keys`` so the robust rules see fp32 values again. The
     ``scalar_median`` rule additionally demands the sparse payload

@@ -1,4 +1,7 @@
-"""Fused sparse-LBG decision kernel (TPU Pallas).
+"""Sparse-LBG kernels (TPU Pallas): the client's fused decision and the
+server's quantized fold. The decision is described first; the fold
+(:func:`lbgm_dequant_fold_pallas`) reuses its (tile, lane) view of a block
+row and is described at its kernel.
 
 The sparse (top-k) Algorithm-1 client step in ``core/lbgm.py`` makes THREE
 separate passes over each dense gradient leaf per client:
@@ -327,3 +330,136 @@ def lbgm_sparse_decision_pallas(blocks: jax.Array, idx: jax.Array,
     gg, gath, ti, tv = lbgm_sparse_decision_batched_pallas(
         blocks[None], idx[None], interpret=interpret)
     return gg[0], gath[0], ti[0], tv[0]
+
+
+# ------------------------------------------------------------------ fold
+
+#: wire-value codes of the fold's expansion. An expanded 0 means "no
+#: client slot here", so a selected zero travels as +-_ZERO_CODE (its sign
+#: kept), and a NaN as _NAN_CODE, so that no NaN reaches the MXU (0 x NaN
+#: would spread it over its column). Both lie beyond every value of the
+#: 1-byte wire dtypes (|int8| <= 128, |e4m3| <= 448) and are exact in
+#: bf16; _NAN_CODE is no multiple of _ZERO_CODE, so zeros sent twice at
+#: one position (the all-zero rows of a bank that was never filled) still
+#: read as a zero.
+_ZERO_CODE = 4096.0
+_NAN_CODE = 6144.0
+_FOLD_WIRE_DTYPES = (jnp.dtype(jnp.int8), jnp.dtype(jnp.float8_e4m3fn))
+
+
+def fold_carry_shape(nb: int, block: int):
+    """Shape of the fold's fp32 carry for one (nb, block) leaf layout: each
+    block row as its (n_tiles, T) tile matrix, zero-padded past ``block``
+    (:func:`_tiling`, the decision kernel's view of a row)."""
+    tile, nt = _tiling(block)
+    return nb, nt, tile
+
+
+def _fold_kernel(idx_ref, code_ref, coeff_ref, acc_ref, out_ref):
+    """One grid step: R block rows, every client of the chunk.
+
+    Refs: idx (C, R, kbp) int32 block-local positions (-1 pads); code
+    (C, R, kbp) f32 wire-value codes (0 for pads and for clients with
+    w <= 0); coeff (C, R, 1) f32 per-row multipliers; acc/out (R, nt, T)
+    f32, the carry's rows (aliased).
+    """
+    C, R, kbp = idx_ref.shape
+    _, nt, T = acc_ref.shape
+    shift = T.bit_length() - 1                    # T is 128 or 256
+    which = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+    tile_a = jax.lax.broadcasted_iota(jnp.int32, (nt, kbp), 0)
+    lane_t = jax.lax.broadcasted_iota(jnp.int32, (T, kbp), 0)
+    nt_dims = (((1,), (1,)), ((), ()))            # a @ b.T
+
+    def row(r, carry):
+        # row r of an (R, n) block: one sublane select and sum, exact
+        own = lambda v: jnp.sum(jnp.where(which == r, v, 0), axis=0,
+                                keepdims=True)
+
+        def client(c, acc):
+            i = own(idx_ref[c])                                # (1, kbp)
+            v = own(code_ref[c])
+            # lanes first: Mosaic broadcasts one axis at a time
+            k = own(jnp.broadcast_to(coeff_ref[c], (R, T)))    # (1, T)
+            # the row's payload as a dense (nt, T) matrix: slot s lands at
+            # (idx // T, idx % T). Positions are unique within a row, so
+            # each output holds at most one non-zero product: exact.
+            a = _bf16(tile_a == (i >> shift))                  # (nt, kbp)
+            b = jnp.where(lane_t == (i & (T - 1)), v, 0.0
+                          ).astype(jnp.bfloat16)               # (T, kbp)
+            d = jax.lax.dot_general(a, b, nt_dims,
+                                    preferred_element_type=jnp.float32)
+            mag = jnp.abs(d)
+            q = jnp.where(mag < _ZERO_CODE, d,
+                          jnp.where(mag == _NAN_CODE, jnp.nan, d * 0.0))
+            # acc + (coeff * q), the reference's association (XLA's CPU
+            # backend, interpreting, may fuse it into one multiply-add);
+            # adding -0.0 leaves every other position's bits as they were
+            return acc + jnp.where(d != 0.0, k * q, -0.0)
+
+        out_ref[r] = jax.lax.fori_loop(0, C, client, acc_ref[r])
+        return carry
+
+    jax.lax.fori_loop(0, R, row, 0)
+
+
+def lbgm_dequant_fold_pallas(acc: jax.Array, w: jax.Array,
+                             gscale: jax.Array, idx: jax.Array,
+                             qv: jax.Array, scale: jax.Array,
+                             interpret: Optional[bool] = None):
+    """Dequantize one chunk's sparse payloads and fold them into the carry.
+
+    acc: (nb, n_tiles, T) f32 carry (:func:`fold_carry_shape`); w, gscale:
+    (C,) f32; idx: (C, nb, kb) int32 block-local positions, unique within
+    a row; qv: (C, nb, kb) int8 or float8_e4m3fn wire values; scale:
+    (C, nb, 1) f32 row scales. Returns the carry with, client by client in
+    order, ``acc + ((w * gscale) * scale) * q`` at each client's positions
+    where ``w > 0`` (``kernels.ref.lbgm_dequant_accum_ref`` on the
+    (nb, block) view). Positions no such client selected keep their bits.
+
+    Each grid step reads ``ROWS`` rows of the carry once, expands each
+    client's payload row to its dense (n_tiles, T) matrix by one one-hot
+    bf16 MXU matmul (the wire values and the codes are exact in bf16), and
+    writes the rows back once, in place (``input_output_aliases``). A
+    carry whose rows are not a multiple of ``ROWS`` ends in a partial grid
+    step; the payload is padded to it. Work per row and client:
+    n_tiles x kbp x T MACs, with kbp the slots padded to 128.
+    """
+    if interpret is None:
+        from repro.kernels.ops import _default_interpret
+        interpret = _default_interpret()
+    return _dequant_fold(acc, w, gscale, idx, qv, scale,
+                         interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _dequant_fold(acc, w, gscale, idx, qv, scale, interpret: bool):
+    nb, nt, tile = acc.shape
+    C, _, kb = idx.shape
+    if jnp.dtype(qv.dtype) not in _FOLD_WIRE_DTYPES:
+        raise TypeError(f"the fold takes int8 or e4m3 wire values, got "
+                        f"{qv.dtype}")
+    rows = min(ROWS, nb)
+    nbp, kbp = _round_up(nb, rows), _round_up(kb, LANES)
+    q = qv.astype(jnp.float32)
+    code = jnp.where(q == 0.0, jnp.copysign(_ZERO_CODE, q),
+                     jnp.where(jnp.isnan(q), _NAN_CODE, q))
+    code = jnp.where((w > 0)[:, None, None], code, 0.0)
+    coeff = (w * gscale)[:, None, None] * scale
+    pad = lambda x, v, cols=kbp - kb: jnp.pad(
+        x, ((0, 0), (0, nbp - nb), (0, cols)), constant_values=v)
+    slot_spec = pl.BlockSpec((C, rows, kbp), lambda j: (0, j, 0))
+    acc_spec = pl.BlockSpec((rows, nt, tile), lambda j: (j, 0, 0))
+    return pl.pallas_call(
+        _fold_kernel,
+        grid=(nbp // rows,),
+        in_specs=[slot_spec, slot_spec,
+                  pl.BlockSpec((C, rows, 1), lambda j: (0, j, 0)),
+                  acc_spec],
+        out_specs=acc_spec,
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        input_output_aliases={3: 0},
+        interpret=interpret,
+        name="lbgm_dequant_fold",
+    )(pad(idx.astype(jnp.int32), -1), pad(code, 0.0),
+      pad(coeff.astype(jnp.float32), 0.0, 0), acc)

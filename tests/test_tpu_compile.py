@@ -1,8 +1,8 @@
 """Compile rehearsals for a TPU v5e that is described, not attached.
 
-The Pallas kernels of the federated round's main path (and the quantized
-fold's XLA program, fp8 included — v5e has no native fp8) at the widths the
-chip smoke test runs, compiled by the TPU compiler installed with JAX. A
+The Pallas kernels of the federated round's main path (the quantized fold
+fp8 included — v5e has no native fp8) at the widths the chip smoke test
+runs, compiled by the TPU compiler installed with JAX. A
 block spec or body the chip's compiler refuses fails here, at no chip time.
 Interpret-mode tests cannot see those faults.
 
@@ -17,8 +17,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.comm.wire import Fp8Codec, Int8Codec
 from repro.kernels.lbgm_projection import lbgm_projection_batched_pallas
-from repro.kernels.lbgm_sparse import lbgm_sparse_decision_batched_pallas
-from repro.kernels.ref import lbgm_dequant_accum_ref
+from repro.kernels.lbgm_sparse import (lbgm_dequant_fold_pallas,
+                                       lbgm_sparse_decision_batched_pallas)
 
 #: clients per scan step of the chip smoke test's LM round
 CHUNK = 2
@@ -87,21 +87,59 @@ def test_projection_kernel_compiles_for_v5e(one_chip, n):
 @pytest.mark.parametrize("codec_cls", [Int8Codec, Fp8Codec])
 def test_quantized_fold_compiles_for_v5e(one_chip, codec_cls):
     """Quantize one chunk's payload rows to the wire dtype and fold them
-    with the dequantizing scatter-accumulate, at the int8 layout of a
-    2048x6144 leaf."""
+    with the dequantizing fold kernel, at the int8 layout of a 2048x6144
+    leaf."""
     codec = codec_cls(stochastic=True)
     nb, block, kb = 192, 65536, 655
 
     def fold(acc, w, gs, idx, val, key):
         qv, scale = jax.vmap(codec.quantize)(
             val, jax.random.split(key, CHUNK))
-        return lbgm_dequant_accum_ref(acc, w, gs, idx, qv, scale)
+        return lbgm_dequant_fold_pallas(acc, w, gs, idx, qv, scale,
+                                        interpret=False)
 
-    hlo = _compile(fold, one_chip, ((nb, block), jnp.float32),
+    hlo = _compile(fold, one_chip, ((nb, 256, 256), jnp.float32),
                    ((CHUNK,), jnp.float32), ((CHUNK,), jnp.float32),
                    ((CHUNK, nb, kb), jnp.int32),
                    ((CHUNK, nb, kb), jnp.float32), ((2,), jnp.uint32))
-    assert "tpu_custom_call" not in hlo
+    assert len(_calls(hlo, "lbgm_dequant_fold")) == 1
+
+
+@pytest.mark.parametrize("shape,chunk", [
+    ((2048, 6144), CHUNK),      # qwen3-1.7b: nb 192, kb 655, the int8 cell
+    ((4, 8, 2048, 768), 1),     # kanana-2-30b-a3b-5l's stacked held experts:
+], ids=["qwen3-2048x6144", "kanana-experts"])  # nb 768, kb 655, chunk 1
+def test_codec_fold_compiles_in_place_for_v5e(one_chip, monkeypatch, shape,
+                                              chunk):
+    """``SparseCodecAggregator.accumulate`` on one leaf compiles to one fold
+    kernel call that updates the donated carry in place: no second
+    carry-sized buffer."""
+    from repro.core.lbgm import _block_layout
+    from repro.fed.engine import SparseCodecAggregator
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    params = {"w": jax.ShapeDtypeStruct(shape, jnp.float32)}
+    agg = SparseCodecAggregator(params, 0.01)
+    nb, block, kb = _block_layout(params["w"].size, 0.01)
+    carry = jax.eval_shape(agg.init, params)["w"]
+
+    def fold(acc, w, gs, idx, qv, scale):
+        send = {"w": {"idx": idx, "val": qv, "scale": scale}}
+        return agg.accumulate({"w": acc}, w, (send, gs))["w"]
+
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    compiled = jax.jit(fold, donate_argnums=0).lower(
+        sds(carry.shape, jnp.float32), sds((chunk,), jnp.float32),
+        sds((chunk,), jnp.float32), sds((chunk, nb, kb), jnp.int32),
+        sds((chunk, nb, kb), jnp.int8),
+        sds((chunk, nb, 1), jnp.float32)).compile()
+    calls = _calls(compiled.as_text(), "lbgm_dequant_fold")
+    assert len(calls) == 1 and "output_to_operand_aliasing" in calls[0]
+    ma = compiled.memory_analysis()
+    carry_bytes = 4 * nb * block
+    assert carry.shape == (nb, 256, 256)
+    assert ma.alias_size_in_bytes == carry_bytes
+    assert ma.temp_size_in_bytes < carry_bytes // 4, ma
 
 
 @pytest.mark.parametrize("clients", [1, 2])

@@ -324,7 +324,7 @@ def test_dequant_accum_kernel_matches_ref(wire_dtype, seed):
 @pytest.mark.parametrize("wire_dtype", ["int8", "fp8"])
 def test_engine_fused_dequant_accum_bit_equals_xla(fcn_setup, wire_dtype):
     """The quantized streaming fold (widen + power-of-two scale inside the
-    scatter) equals the unquantized fold of the decoded payloads — the
+    fold kernel) equals the unquantized fold of the decoded payloads — the
     products are identical, only XLA's FMA contraction of the running sum
     may differ by an ulp where clients share a position — and an engine
     with that codec folds through it."""
@@ -343,9 +343,11 @@ def test_engine_fused_dequant_accum_bit_equals_xla(fcn_setup, wire_dtype):
         send_q[name] = {"idx": idx, "val": qv, "scale": scale}
         send_f[name] = {"idx": idx, "val": jax.vmap(codec.decode_leaf)(
             send_q[name])}
-    acc = q_agg.init(params)
-    got = q_agg.accumulate(acc, w, (send_q, gscale))
-    want = f_agg.accumulate(acc, w, (send_f, gscale))
+    # each fold on its own carry layout, compared in the params layout
+    got = q_agg.finalize(q_agg.accumulate(q_agg.init(params), w,
+                                          (send_q, gscale)))
+    want = f_agg.finalize(f_agg.accumulate(f_agg.init(params), w,
+                                           (send_f, gscale)))
     for name in params:
         np.testing.assert_allclose(np.asarray(got[name]),
                                    np.asarray(want[name]), rtol=1e-6,
