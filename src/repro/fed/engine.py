@@ -1,206 +1,91 @@
 """Unified federated execution engine (paper Algorithms 1 & 3).
 
-``FLEngine`` is the single round-runner behind every FL reproduction in this
-repo (Figs. 5-8 benchmarks and the plug-and-play example). One jit'd round
-function is
-assembled from three pluggable pieces, each resolved by string key through
-the registries in ``repro.fed.registry`` (the extension seam the
-declarative ``ExperimentSpec`` API builds on):
+``FLEngine`` is the single round-runner behind every FL reproduction in
+this repo. One jit'd round function is assembled from pieces resolved by
+string key through the registries in ``repro.fed.registry`` (the
+extension seam the declarative ``ExperimentSpec`` API builds on).
 
 1. **Client scheduler** (``SCHEDULERS``) — how the K clients' local
-   training is mapped onto the device:
+   training is mapped onto the device. Every scheduler runs one step on a
+   chunk of clients, :func:`chunk_step`: ``client_fn`` vmapped over the
+   chunk, the payloads folded into the aggregation carry (or handed out,
+   in collect mode), and the new bank rows gated by the participation
+   mask.
 
-   * ``"vmap"``   — all K clients batched in one ``jax.vmap`` (the original
-     runtime). Peak *transient* memory is O(K·M): every client's tau-step
-     local-SGD working set (activations, gradients, the per-client g_tilde
-     stack) is live at once.
-   * ``"chunked"`` — ``jax.lax.scan`` over blocks of at most ``chunk_size``
-     clients, ``vmap`` only within a block. Peak transient memory is
-     O(chunk·M), which is what unlocks K >> 100 cohorts: the persistent LBG
-     bank still scales with K, but the round working set no longer does.
-     The actual block size is the largest divisor of K not exceeding
-     ``chunk_size`` (never more memory than requested, no wasted compute);
-     when K is near-prime and that divisor would be tiny, the engine keeps
-     ``chunk_size`` and zero-weight pads the last block instead.
-   * ``"sharded"`` — the chunked layout with each block additionally
-     mapped over the named 2-D ``(clients, model)`` FL mesh via
-     ``shard_map`` (``FLConfig.mesh`` spec — ``None``/``int n``/``[c, m]``
-     — resolved through ``launch.mesh.make_fl_mesh``). The chunk's client
-     axis shards over ``clients`` (per-device transient memory
-     O(chunk·M / c), all clients of a chunk training concurrently — the
-     scale axis for 512+ client cohorts); with ``m > 1`` the sparse LBG
-     bank, the Algorithm-1 decision, and the aggregation carry
-     additionally shard their block rows over ``model`` (per-device bank
-     bytes O(K·k_frac·M / (c·m)) — the memory axis for the >=34B archs,
-     where the look-back bank dominates).
+   * ``"vmap"`` — the whole cohort is one chunk: O(K·M) transient memory.
+   * ``"chunked"`` — a ``lax.scan`` of the step over blocks of at most
+     ``chunk_size`` clients (:func:`pick_chunk`), the banks updated in
+     place in the scan carry: O(chunk·M) transient memory, while the
+     persistent bank still scales with K. The scan, the padding to the
+     chunk grid and the per-client epilogue live in
+     :class:`ChunkedScheduler` only.
+   * ``"sharded"`` — the chunked scan with the step under ``shard_map`` on
+     the named 2-D ``(clients, model)`` mesh (``FLConfig.mesh``). It
+     overrides only how a chunk's bank rows are indexed, the shard_map
+     around the step, and the ``model_sharding="auto"`` chunk function.
+   * ``"buffered"`` — FedBuff-style asynchronous aggregation: the chunked
+     scan computes every dispatched client, and each payload parks in a
+     per-client staleness buffer until it folds in its arrival round
+     (``repro.fed.latency``).
 
-   * ``"buffered"`` — FedBuff-style buffered *asynchronous* aggregation on
-     the chunked layout: stragglers are latency, not absence. A per-client
-     latency model (``FLConfig.latency`` / ``latency_kw`` —
-     ``repro.fed.latency``; delays drawn per round from the dedicated
-     fault stream, so the async replay is seed-exact) routes each
-     dispatched client's sparse ``(idx, val)·(w·gscale)`` payload into a
-     bounded staleness buffer (one in-flight slot per client) instead of
-     the participation fold; the commutative block-layout aggregation
-     carry folds each payload in the round it *arrives*, its
-     dispatch-round weight discounted by the model's staleness weight
-     (``1/(1+s)^alpha``, where-gated to exactly 1.0 at ``s == 0``).
-     Per-client compute heterogeneity rides the batch dict as a
-     variable-``tau`` vector (reserved key ``"_tau"``): slow clients run
-     fewer local steps rather than vanish. Wire/uplink bytes are
-     accounted in the *arrival* round (delivery-time CommLedger).
-     With ``latency="none"`` and no dropout the plan degenerates to
-     dispatch == deliver == mask with zero staleness, and the round —
-     weights, fold order, metrics, banks — is bit-for-bit equal to
-     ``"chunked"`` (tier-1 tested). Requires the sparse aggregation
-     contract (top-k store, ``fused_kernels`` not False); composes with
-     every aggregator rule (staleness-aware weighting reaches the robust
-     rules through the weight vector) and every wire codec (the buffer
-     stores payloads in their wire layout).
-
-   All schedulers accumulate the server aggregate through the engine's
-   *aggregator* with the *same* strictly sequential per-client ``lax.scan``
-   (carry += w_k * g_k, k = 0..K-1), so their float addition order is
-   identical and vmap/chunked (and sharded on a 1-device mesh) produce
-   bit-for-bit equal params and metrics on the same seed (tested in
-   ``tests/test_engine.py`` / ``tests/test_sharded_scheduler.py``); a
-   multi-device sharded round only reassociates the final psum
-   (fp32-tolerance equal, identical uplink accounting). A scheduler is a
-   factory ``(cfg, num_clients) -> obj`` with ``chunk``/``pad`` ints plus
-   ``prepare_batch(host_arrays)`` and
-   ``run(client_fn, agg, params, batch, lbg, resid, w, maskf)`` (``agg``
-   is the aggregator below); an optional ``layout_banks(bank)`` hook lets
+   Every fold is the aggregator's strictly sequential per-client
+   accumulate in client order, so the schedulers share one float addition
+   order: "sharded" on a [1, 1] mesh and "buffered" at zero latency are
+   "chunked" bit for bit, and a multi-device sharded round only
+   reassociates the final psum. A scheduler is a factory ``(cfg,
+   num_clients) -> obj`` with ``chunk``/``pad`` ints plus
+   ``prepare_batch(host_arrays)`` and ``run(client_fn, agg, params, batch,
+   lbg, resid, w, maskf)``; an optional ``layout_banks(bank)`` hook lets
    it own the state banks' physical layout.
 
-   ``FLConfig.aggregator`` selects the *server rule* the aggregator
-   implements. ``"mean"`` (default) is the streaming fold above —
-   bit-for-bit the pre-robustness histories. Any robust rule from
-   ``repro.fed.robust`` (``trimmed_mean`` / ``coordinate_median`` /
-   ``geometric_median``, extendable via ``@register_aggregator``)
-   switches every scheduler into **collect mode**: the per-client
-   payloads (dense g_tilde, or the sparse (idx, val) + gscale
-   scalar-round payload, densified server-side) ride the scan outputs
-   into a (K, ...) stack and the rule reduces them in one weighted
-   cross-client estimate — O(K·M) peak, the honest price of a median.
+2. **Aggregator** (:func:`make_aggregator`) — the server rule
+   (``FLConfig.aggregator``) and the payload it folds. ``"mean"`` streams:
+   :class:`SparseTopKAggregator` folds a top-k store's ``(idx, val)``
+   payload scaled by ``w·gscale`` (``gscale`` = rho on a recycle round, 1
+   on a full round), :class:`SparseCodecAggregator` the quantized payload
+   through the ``lbgm_dequant_fold`` kernel, and :class:`DenseAggregator`
+   dense g_tilde (the ``fused_kernels=False`` oracle). A robust rule
+   (``repro.fed.robust``) puts the step in collect mode: the per-client
+   payloads are stacked and reduced once per round, O(K·M) peak.
+   Hierarchical tiers (``repro.fed.hierarchy``) wrap the streaming
+   aggregator.
 
-   Client faults come from ``repro.fed.attacks``: ``FLConfig.attack`` /
-   ``attack_frac`` / ``attack_kw`` flag a fixed seed-derived Byzantine
-   cohort whose payloads are corrupted inside ``client_fn`` *before* the
-   uplink pipeline and LBG store step (so a recycle round's rho is
-   poisoned too); ``label_flip`` corrupts the cohort's data at engine
-   build instead. ``dropout_frac`` injects straggler dropout through the
-   participation-mask path. Byzantine flags and per-round attack seeds
-   ride the batch dict under reserved ``"_byz"``/``"_atk_*"`` keys (so
-   they inherit every scheduler's batch layout and the prefetcher's H2D
-   overlap); all fault randomness draws from a dedicated stream, so
-   clean runs are bit-for-bit unchanged and attacked runs replay
-   deterministically under the same seed.
+3. **LBGStore** (``LBG_STORES``) — the look-back bank and Algorithm 1's
+   accept/recycle decision: dense, top-k, top-k laid out over the mesh,
+   top-k host-resident (``"topk-host"``: streamed a chunk at a time by
+   :class:`_HostBankStreamer` through the same :func:`chunk_step`), or
+   null (vanilla FL). A store implements ``init(params, K)``,
+   ``client_step(grad, lbg_k)`` and ``full_round_cost(base_cost, stats)``
+   and registers with ``@register_lbg_store("name")``.
 
-   The aggregator is how the per-round hot path does work proportional to
-   what the round transmits (``FLConfig.fused_kernels``):
+4. **Uplink pipeline and wire codec** — ``make_uplink_pipeline`` (top-K /
+   ATOMO / SignSGD plus error feedback, ``COMPRESSORS``) runs before the
+   decision; the codec (``FLConfig.codec``, ``repro.comm.wire``) encodes
+   the payload the uplink ships and counts its bytes.
 
-   * ``DenseAggregator`` — the legacy path: every client materializes a
-     dense params-shaped g_tilde and the carry adds O(M) per client.
-   * ``SparseTopKAggregator`` — sparse scalar-round aggregation for the
-     top-k stores: each client contributes only its (idx, val) payload,
-     scatter-added into a per-leaf block-layout accumulator with the
-     client's ``w_k * gscale_k`` folded in (``gscale`` = rho on a recycle
-     round, 1 on a full round), still strictly sequentially (deterministic
-     order). The chunked/sharded inner loop drops from O(chunk·M) to
-     O(chunk·k_frac·M) flops and HBM traffic — on the scalar-heavy rounds
-     the paper demonstrates, the aggregation cost tracks the ~1-float
-     uplink instead of the model size. Full rounds are bit-for-bit equal
-     to the dense path (same values, same order); scalar rounds fold
-     w·rho before the scatter (fp32-tolerance). ``fused_kernels=False``
-     restores the dense path exactly.
-
-   Host-side, the round loop is double-buffered: ``RoundPrefetcher`` (used
-   by ``FLEngine.run`` and ``run_experiment``) prepares round t+1's
-   batches/mask on a daemon thread while the device executes round t —
-   the ROADMAP's "async round overlap" item. The prefetch thread is the
-   rng's only consumer while active, so the draw stream (and therefore
-   every number in the history) is identical to the synchronous path.
-
-2. **LBGStore** (``LBG_STORES``) — how each client's look-back gradient is
-   stored and how Algorithm 1's accept/recycle decision is made:
-
-   * ``DenseLBGStore`` (``"dense"``, legacy alias ``"full"``) —
-     paper-faithful dense pytree bank, one params-shaped LBG per client
-     (wraps ``repro.core.lbgm.lbgm_client_step``).
-   * ``TopKLBGStore`` (``"topk"``) — sparse (indices, values) bank at
-     ``k_frac`` density (wraps ``lbgm_topk_client_step``); the bank shrinks
-     from O(K·M) to O(K·k_frac·M), the enabling step for large-model
-     cohorts.
-   * ``NullLBGStore`` (``"null"``) — vanilla FL (``use_lbgm=False``):
-     gradients pass through, every round is a full round.
-   * ``ShardedTopKLBGStore`` (``"topk-sharded"``) — the top-K bank laid
-     out for the sharded scheduler: rows live on the device that trains
-     their client (client-axis sharding via ``layout_banks``), and the
-     accept/recycle decision reuses ``topk_step_core`` through
-     ``repro.core.lbgm_sharded.make_local_topk_step`` — fully
-     device-local, so LBGM adds zero cross-device traffic.
-   * ``HostTopKLBGStore`` (``"topk-host"``) — the top-K bank kept
-     host-resident as NumPy: ``run_round`` switches to an out-of-core
-     chunk loop where a ``_HostBankStreamer`` daemon thread uploads
-     chunk c+1's bank/batch rows while the device computes chunk c and
-     writes the updated rows back on the same thread. Per-round device
-     bank bytes are O(chunk·k_frac·M) regardless of K — 100k-client
-     cohorts on fixed device memory, bit-for-bit equal to ``"topk"``.
-
-   **Hierarchical tiers** (``FLConfig.tiers`` — ``repro.fed.hierarchy``)
-   interpose edge->region->global aggregation behind the aggregator
-   seam: a ``HierarchicalAggregator`` folds per-edge partial carries
-   alongside the inner streaming aggregator's untouched flat carry (so
-   the global update stays bit-for-bit the flat fold), and the
-   CommLedger attributes per-tier wire bytes (edge links carry the
-   sparse client payloads; each active edge/region forwards one dense
-   partial-carry model upstream). Collect-mode robust rules keep their
-   flat numerics — for them the tier map is accounting-only.
-
-   **Checkpointing** (``FLConfig.ckpt_every`` / ``ckpt_path`` —
-   ``repro.checkpoint.ckpt``): every N completed rounds the engine
-   atomically persists params, banks, residuals, the buffered in-flight
-   slots, all host rng streams, and the CommLedger;
-   ``FLEngine.run(resume=True)`` / ``repro.fed.run --resume`` continue
-   the run bit-for-bit (the prefetch producer snapshots its post-draw
-   host state with every round, so the checkpoint cut is exact even
-   with rounds queued ahead).
-
-   A store implements ``init(params, K)``, ``client_step(grad, lbg_k)`` and
-   ``full_round_cost(base_cost, stats)``; new storage schemes (e.g.
-   quantized or host-offloaded LBGs) plug in via
-   ``@register_lbg_store("name")`` on a ``cfg -> store`` factory.
-
-3. **Uplink pipeline** (``COMPRESSORS``) — base compressor + error feedback
-   composed behind ``repro.compression.make_uplink_pipeline`` (top-K /
-   ATOMO / SignSGD, paper P3/P4), applied to the accumulated stochastic
-   gradient before the LBGM decision.
+``client_fn`` is ``post(pre(...))`` (:meth:`FLEngine._client_halves`):
+``pre`` strips the engine-reserved batch keys and runs local SGD, the
+Byzantine attack (``repro.fed.attacks``) and the pipeline; ``post`` runs
+the decision, the uplink count and the codec. ``model_sharding="auto"``
+swaps in only the store's block-row decision step. Each round ends in
+:func:`server_step` and :func:`round_metrics`, shared by the synchronous,
+buffered and topk-host rounds.
 
 Uplink accounting follows the paper's metric of floating-point parameters
-shared per worker: a scalar (recycle) round uploads exactly 1 float, a full
-round pays the pipeline/store cost.
+shared per worker: a scalar (recycle) round uploads exactly 1 float, a
+full round pays the pipeline/store cost; the wire codec adds the real
+bytes, and both land in the :class:`~repro.comm.accounting.CommLedger`.
 
-On top of that float count, the **wire codec** (``FLConfig.codec`` /
-``codec_kw`` — ``repro.comm.wire``) decides how those floats are encoded on
-the wire and accounts the real bytes: ``"none"`` ships fp32 (bit-for-bit
-the pre-codec histories), ``"delta_idx"`` varint-compresses the sparse
-payload indices, ``"int8"``/``"fp8"`` stochastically quantize the values
-with one power-of-two scale per block row. Encoding happens in
-``client_fn`` *after* the uplink pipeline (the bank stores the
-server-decodable values, so recycle rounds stay deployment-faithful);
-decoding happens at the aggregator seam — for quantized streaming
-aggregation the dequantize is fused into the fold (the
-:class:`SparseCodecAggregator` kernel), so no fp32 payload stack is ever
-materialized. Per-client ``wire_bytes`` ride
-the scheduler outputs next to ``uplink`` and land in the
-:class:`~repro.comm.accounting.CommLedger` (savings vs vanilla fp32 dense).
+On the host, :class:`RoundPrefetcher` prepares round t+1 while the device
+runs round t, drawing the same rng stream as the synchronous path. Faults,
+latencies and codec seeds draw from streams of their own, so a clean run
+is unchanged by them, and checkpoints (``FLConfig.ckpt_every``) resume
+bit for bit.
 
 Each stage of the round carries a name from ``repro.fed.spans`` for a
-profiler trace: the named scopes ``fl.client_sgd`` (local SGD),
-``fl.decision`` (the store step), ``fl.codec`` (the client-side encode) and
-``fl.fold`` (every aggregator call, the bank write-back and the server
-step) are placed once in ``client_fn`` and at the schedulers' fold sites,
-so every scheduler inherits them; the host spans ``fl.prefetch_wait``,
+profiler trace: the scopes ``fl.client_sgd``, ``fl.decision`` and
+``fl.codec`` in ``client_fn``, and ``fl.fold`` around every fold, bank
+write and the server step; the host spans ``fl.prefetch_wait``,
 ``fl.host_prep``, ``fl.h2d`` and ``fl.readback`` mark the host's share.
 """
 from __future__ import annotations
@@ -418,23 +303,21 @@ class HostTopKLBGStore(TopKLBGStore):
     """Sparse (idx, val) bank kept host-resident (``"topk-host"``).
 
     Same decision math, cost model, and aggregator as
-    :class:`TopKLBGStore` — the per-client step is *bit-for-bit* the
-    in-memory store's — but ``init`` allocates the (Kp, nb, kb) bank as
+    :class:`TopKLBGStore`, but ``init`` allocates the (Kp, nb, kb) bank as
     NumPy on the host instead of a device array. The engine detects
     ``host_resident`` and switches ``run_round`` into the out-of-core
-    chunk loop: a :class:`_HostBankStreamer` daemon thread uploads chunk
-    ``c+1``'s bank rows (and batch rows) host->device while the device
-    computes chunk ``c``, and writes chunk ``c``'s updated rows back to
-    the host array on the same thread — so per-round *device* bank bytes
-    are O(chunk * k_frac * M) regardless of K. That is what unlocks
-    100k-client cohorts on a fixed-memory device (ROADMAP open item 2);
-    the chunked scheduler's in-memory path keeps the whole O(K * k_frac
-    * M) bank live on device.
+    chunk loop: each chunk runs :func:`chunk_step` under one jit
+    (``FLEngine._chunk_fn``) while a :class:`_HostBankStreamer` daemon
+    thread uploads chunk ``c+1``'s bank and batch rows and writes chunk
+    ``c``'s updated rows back to the host array — so per-round *device*
+    bank bytes are O(chunk * k_frac * M) regardless of K, where the
+    chunked scheduler keeps the whole O(K * k_frac * M) bank on device.
+    The step being the chunked scheduler's, histories are bit-for-bit
+    equal to ``"topk"`` on the same seed (tier-1 tested).
 
     Requires ``scheduler="chunked"`` with streaming aggregation and no
     error-feedback residual (validated at FLConfig construction / engine
-    build); histories are bit-for-bit equal to ``"topk"`` on the same
-    seed (tier-1 tested).
+    build).
     """
 
     #: engine marker: run_round streams bank chunks from host memory
@@ -713,6 +596,74 @@ def _keep_sampled(maskf, new, old):
             maskf.reshape((-1,) + (1,) * (n.ndim - 1)) > 0, n, o), new, old)
 
 
+def _pad_to_grid(pad, *vecs):
+    """Per-client (K,) vectors zero-padded to the chunk grid (K + pad,):
+    phantom pad clients carry zero weight and a zero mask."""
+    if not pad:
+        return vecs
+    return tuple(jnp.concatenate([v, jnp.zeros(pad, v.dtype)])
+                 for v in vecs)
+
+
+def _flat_clients(x):
+    """(n_chunks, chunk, ...) scan output -> (Kp, ...)."""
+    return x.reshape((-1,) + x.shape[2:])
+
+
+def chunk_step(client_fn, params, fold, acc, b_c, l_c, r_c, w_c, m_c):
+    """The one step every chunked path runs on a chunk of clients.
+
+    ``client_fn`` is vmapped over the chunk's batch, bank rows and
+    residual rows. ``fold(acc, w_c, payload)`` folds the payloads into the
+    carry; with ``fold=None`` the payloads are handed out in the carry's
+    place (collect mode, the buffered scheduler). The new bank and
+    residual rows are gated by the chunk's mask, so an unsampled client
+    keeps its state. Returns ``(acc or payload, new_lbg, new_res, loss,
+    uplink, scalar, wire)``.
+    """
+    gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
+        lambda b, l, r: client_fn(params, b, l, r))(b_c, l_c, r_c)
+    out = gt
+    if fold is not None:
+        with scope(FOLD):
+            out = fold(acc, w_c, gt)
+    with scope(FOLD):
+        nl = _keep_sampled(m_c, nl, l_c)
+        nr = _keep_sampled(m_c, nr, r_c)
+    return out, nl, nr, loss, uplink, scalar, wire
+
+
+def round_weights(weights, mask):
+    """``(maskf, w)``: the participation mask as float32, and the
+    clients' data-size weights normalized over it."""
+    maskf = mask.astype(jnp.float32)
+    w = weights * maskf
+    return maskf, w / jnp.maximum(jnp.sum(w), 1e-12)
+
+
+def server_step(lr, params, agg):
+    """The server update ``p - lr * agg``."""
+    with scope(FOLD):
+        return jax.tree.map(lambda p, a: p - lr * a.astype(p.dtype),
+                            params, agg)
+
+
+def round_metrics(losses, w, uplink, scalar, wire, maskf, countf=None):
+    """The round's metrics dict. The loss is weighted by ``w``; uplink,
+    wire bytes and the scalar-round share count the clients ``maskf``
+    counts, and the program counters those of ``countf`` (default
+    ``maskf``)."""
+    countf = maskf if countf is None else countf
+    return {
+        "loss": jnp.sum(losses["loss"] * w),
+        "uplink_floats": jnp.sum(uplink * maskf),
+        "frac_scalar": jnp.sum(scalar.astype(jnp.float32) * maskf)
+        / jnp.maximum(jnp.sum(maskf), 1.0),
+        "wire_bytes": jnp.sum(wire * maskf),
+        **_summed_counters(losses, countf),
+    }
+
+
 @register_scheduler("vmap")
 class VmapScheduler:
     """All K clients in one vmap; O(K·M) transient working set."""
@@ -724,18 +675,16 @@ class VmapScheduler:
         return stacked  # leaves stay (K, tau, b, ...)
 
     def run(self, client_fn, agg, params, batch, lbg, resid, w, maskf):
-        gt, new_lbg, new_res, loss, uplink, scalar, wire = jax.vmap(
-            lambda b, l, r: client_fn(params, b, l, r))(batch, lbg, resid)
-        with scope(FOLD):
-            if getattr(agg, "collect", False):
-                # robust rules need the whole per-client stack at once —
-                # vmap already has it in hand
-                out = agg.reduce(w, gt)
-            else:
-                out = agg.finalize(agg.accumulate(agg.init(params), w, gt))
-            new_lbg = _keep_sampled(maskf, new_lbg, lbg)
-            new_res = _keep_sampled(maskf, new_res, resid)
-        return out, new_lbg, new_res, loss, uplink, scalar, wire
+        # the cohort is one chunk, and its fold is the whole aggregation
+        if getattr(agg, "collect", False):
+            # robust rules need the whole per-client stack at once —
+            # vmap already has it in hand
+            fold = lambda _, w, gt: agg.reduce(w, gt)
+        else:
+            fold = lambda _, w, gt: agg.finalize(
+                agg.accumulate(agg.init(params), w, gt))
+        return chunk_step(client_fn, params, fold, None, batch, lbg, resid,
+                          w, maskf)
 
 
 @register_scheduler("chunked")
@@ -769,61 +718,71 @@ class ChunkedScheduler:
             return x.reshape((x.shape[0] // chunk, chunk) + x.shape[1:])
         return {k: to_chunks(v) for k, v in stacked.items()}
 
-    def run(self, client_fn, agg, params, batch, lbg, resid, w, maskf):
-        K, chunk, pad = self.num_clients, self.chunk, self.pad
-        if pad:
-            w = jnp.concatenate([w, jnp.zeros(pad, w.dtype)])
-            maskf = jnp.concatenate([maskf, jnp.zeros(pad, maskf.dtype)])
-        Kp = K + pad
-        n_chunks = Kp // chunk
-        slice_at = lambda t, i: jax.tree.map(
-            lambda x: jax.lax.dynamic_slice_in_dim(x, i * chunk, chunk), t)
-        update_at = lambda t, u, i: jax.tree.map(
-            lambda x, v: jax.lax.dynamic_update_slice_in_dim(
-                x, v, i * chunk, axis=0), t, u)
+    # how a chunk's rows are read from and written to a (Kp, ...) bank
+    def _read(self, bank, i):
+        return jax.tree.map(lambda x: jax.lax.dynamic_slice_in_dim(
+            x, i * self.chunk, self.chunk), bank)
 
-        collect = getattr(agg, "collect", False)
+    def _write(self, bank, rows, i):
+        return jax.tree.map(lambda x, v: jax.lax.dynamic_update_slice_in_dim(
+            x, v, i * self.chunk, axis=0), bank, rows)
 
-        def chunk_body(carry, xs):
+    def _step_fn(self, client_fn, agg, collect, params, lbg):
+        """``(acc, b_c, l_c, r_c, w_c, m_c) -> chunk_step(...)``, folding
+        with ``agg.accumulate`` unless ``collect``."""
+        fold = None if collect else agg.accumulate
+        return lambda acc, *xs: chunk_step(client_fn, params, fold, acc,
+                                           *xs)
+
+    def _scan(self, client_fn, agg, collect, params, batch, lbg, resid, w,
+              maskf):
+        """The chunk step over the chunk grid in one ``lax.scan``, the
+        banks updated in place in the carry. ``w``/``maskf`` are already
+        padded to the grid. With ``collect`` each chunk's payload rides
+        the scan outputs after the per-client metrics. Returns ``(acc,
+        new_lbg, new_res, ys)``."""
+        chunk = self.chunk
+        n_chunks = w.shape[0] // chunk
+        step = self._step_fn(client_fn, agg, collect, params, lbg)
+
+        def body(carry, xs):
             acc, lbg_bank, res_bank = carry
             i, b_c, w_c, m_c = xs
-            l_c, r_c = slice_at(lbg_bank, i), slice_at(res_bank, i)
-            gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
-                lambda b, l, r: client_fn(params, b, l, r))(b_c, l_c, r_c)
+            l_c, r_c = self._read(lbg_bank, i), self._read(res_bank, i)
+            out, nl, nr, *ys = step(acc, b_c, l_c, r_c, w_c, m_c)
             if collect:
-                # a robust rule cannot fold a median chunk-by-chunk: stack
-                # the raw per-client payloads as scan outputs instead
-                # (O(Kp·payload) — the documented collect-mode memory)
-                ys = (loss, uplink, scalar, wire, gt)
+                ys.append(out)
             else:
-                with scope(FOLD):
-                    acc = agg.accumulate(acc, w_c, gt)
-                ys = (loss, uplink, scalar, wire)
+                acc = out
             with scope(FOLD):
-                lbg_bank = update_at(lbg_bank, _keep_sampled(m_c, nl, l_c),
-                                     i)
-                res_bank = update_at(res_bank, _keep_sampled(m_c, nr, r_c),
-                                     i)
-            return (acc, lbg_bank, res_bank), ys
+                lbg_bank = self._write(lbg_bank, nl, i)
+                res_bank = self._write(res_bank, nr, i)
+            return (acc, lbg_bank, res_bank), tuple(ys)
 
         with scope(FOLD):
             acc0 = jnp.zeros(()) if collect else agg.init(params)
         (acc, new_lbg, new_res), ys = jax.lax.scan(
-            chunk_body, (acc0, lbg, resid),
+            body, (acc0, lbg, resid),
             (jnp.arange(n_chunks), batch, w.reshape(n_chunks, chunk),
              maskf.reshape(n_chunks, chunk)))
+        return acc, new_lbg, new_res, ys
+
+    def run(self, client_fn, agg, params, batch, lbg, resid, w, maskf):
+        K = self.num_clients
+        w, maskf = _pad_to_grid(self.pad, w, maskf)
+        # a robust rule cannot fold a median chunk-by-chunk: it gets the
+        # raw per-client payloads stacked as scan outputs instead
+        # (O(Kp·payload) — the documented collect-mode memory)
+        collect = getattr(agg, "collect", False)
+        acc, new_lbg, new_res, ys = self._scan(
+            client_fn, agg, collect, params, batch, lbg, resid, w, maskf)
+        # ys: (loss, uplink, scalar, wire), then the payloads if collect
         with scope(FOLD):
-            if collect:
-                loss, uplink, scalar, wire, gt = ys
-                out = agg.reduce(w, jax.tree.map(
-                    lambda x: x.reshape((-1,) + x.shape[2:]), gt))
-            else:
-                loss, uplink, scalar, wire = ys
-                out = agg.finalize(acc)
+            out = (agg.reduce(w, jax.tree.map(_flat_clients, ys[4]))
+                   if collect else agg.finalize(acc))
+        per_client = lambda x: _flat_clients(x)[:K]
         return (out, new_lbg, new_res,
-                jax.tree.map(lambda x: x.reshape(Kp)[:K], loss),
-                uplink.reshape(Kp)[:K], scalar.reshape(Kp)[:K],
-                wire.reshape(Kp)[:K])
+                *jax.tree.map(per_client, tuple(ys[:4])))
 
 
 @register_scheduler("buffered")
@@ -832,12 +791,11 @@ class BufferedScheduler(ChunkedScheduler):
 
     Three stages per round, one jit'd function:
 
-    1. **compute** — the standard chunked ``lax.scan`` runs every client
-       (local SGD + attack + pipeline + Algorithm-1 decision + codec
-       encode); state banks update only under the *dispatch* mask (a
-       client busy with an in-flight payload neither recomputes its bank
-       nor re-dispatches). Payloads ride the scan outputs like collect
-       mode — they go to the buffer, not straight into the fold.
+    1. **compute** — :meth:`ChunkedScheduler._scan` runs :func:`chunk_step`
+       on every chunk with no fold and the *dispatch* mask: state banks
+       update only for dispatching clients (a client busy with an
+       in-flight payload neither recomputes its bank nor re-dispatches),
+       and the payloads ride the scan outputs to the buffer.
     2. **buffer write** — each dispatching client overwrites its single
        in-flight slot (payload leaves in wire layout, gscale, its
        dispatch-round weight, and uplink/scalar/wire accounting) via a
@@ -845,13 +803,11 @@ class BufferedScheduler(ChunkedScheduler):
        bit-unchanged.
     3. **delivery fold** — the round's *delivered* slots are folded with
        weights ``w0 * disc(stale) * deliver``, normalized over the
-       delivered cohort. Streaming rules fold chunk-by-chunk inside a
-       scan with the exact per-chunk ``accumulate`` structure
-       :class:`ChunkedScheduler` compiles (same expressions, same
-       strictly sequential order — the zero-latency bit-for-bit
-       guarantee); collect rules get the full (Kp, ...) stack, so
-       staleness-aware weighting reaches mean / geometric_median /
-       scalar_median through the one weight vector they already honor.
+       delivered cohort. Streaming rules fold with ``agg.accumulate``
+       chunk by chunk over the same grid, in the same client order;
+       collect rules get the full (Kp, ...) stack, so staleness-aware
+       weighting reaches mean / geometric_median / scalar_median through
+       the one weight vector they already honor.
 
     Delivered uplink/scalar/wire are reported in the arrival round; a
     round that delivers nothing reports zeros (the ledger guards its
@@ -868,53 +824,21 @@ class BufferedScheduler(ChunkedScheduler):
 
     def run_buffered(self, client_fn, agg, params, batch, lbg, resid,
                      buf, w0, dispatchf, deliverf, stalef, disc):
+        """One buffered round: ``(agg_out, new_lbg, new_res, new_buf,
+        losses, uplink, scalar, wire)``. The per-client accounting is each
+        slot's stored value, for the caller to count over ``deliverf``."""
         K, chunk, pad = self.num_clients, self.chunk, self.pad
-        dzp, w0p = dispatchf, w0
-        if pad:
-            z = jnp.zeros(pad, jnp.float32)
-            dzp = jnp.concatenate([dispatchf, z])
-            w0p = jnp.concatenate([w0, z])
-        Kp = K + pad
-        n_chunks = Kp // chunk
-        slice_at = lambda t, i: jax.tree.map(
-            lambda x: jax.lax.dynamic_slice_in_dim(x, i * chunk, chunk), t)
-        update_at = lambda t, u, i: jax.tree.map(
-            lambda x, v: jax.lax.dynamic_update_slice_in_dim(
-                x, v, i * chunk, axis=0), t, u)
-
-        def chunk_body(carry, xs):
-            lbg_bank, res_bank = carry
-            i, b_c, m_c = xs
-            l_c, r_c = slice_at(lbg_bank, i), slice_at(res_bank, i)
-            gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
-                lambda b, l, r: client_fn(params, b, l, r))(b_c, l_c, r_c)
-            with scope(FOLD):
-                lbg_bank = update_at(lbg_bank, _keep_sampled(m_c, nl, l_c),
-                                     i)
-                res_bank = update_at(res_bank, _keep_sampled(m_c, nr, r_c),
-                                     i)
-            return (lbg_bank, res_bank), (gt, loss, uplink, scalar, wire)
-
-        (new_lbg, new_res), ys = jax.lax.scan(
-            chunk_body, (lbg, resid),
-            (jnp.arange(n_chunks), batch, dzp.reshape(n_chunks, chunk)))
-        gt, loss, uplink, scalar, wire = ys
-        flat = lambda x: x.reshape((-1,) + x.shape[2:])
-        send, gscale = jax.tree.map(flat, gt)
-        loss, uplink, scalar, wire = (jax.tree.map(flat, loss), flat(uplink),
-                                      flat(scalar), flat(wire))
+        w0p, dzp = _pad_to_grid(pad, w0, dispatchf)
+        _, new_lbg, new_res, ys = self._scan(
+            client_fn, agg, True, params, batch, lbg, resid, w0p, dzp)
+        loss, uplink, scalar, wire, gt = jax.tree.map(_flat_clients, ys)
 
         def gate(new, old):
             d = dzp.reshape((-1,) + (1,) * (new.ndim - 1))
             return jnp.where(d > 0, new.astype(old.dtype), old)
-        nbuf = {
-            "send": jax.tree.map(gate, send, buf["send"]),
-            "gscale": gate(gscale, buf["gscale"]),
-            "w0": gate(w0p, buf["w0"]),
-            "uplink": gate(uplink, buf["uplink"]),
-            "scalar": gate(scalar.astype(jnp.float32), buf["scalar"]),
-            "wire": gate(wire, buf["wire"]),
-        }
+        nbuf = jax.tree.map(gate, {
+            "send": gt[0], "gscale": gt[1], "w0": w0p, "uplink": uplink,
+            "scalar": scalar, "wire": wire}, buf)
 
         # delivery weights: stored dispatch-round weight x staleness
         # discount x delivered flag, normalized with the same (K,)
@@ -924,28 +848,20 @@ class BufferedScheduler(ChunkedScheduler):
         # by the flag) this reproduces the chunked weights bit-for-bit.
         wd = nbuf["w0"][:K] * disc(stalef) * deliverf
         wn = wd / jnp.maximum(jnp.sum(wd), 1e-12)
-        wnp = jnp.concatenate([wn, jnp.zeros(pad, wn.dtype)]) if pad \
-            else wn
+        (wnp,) = _pad_to_grid(pad, wn)
+        payload = (nbuf["send"], nbuf["gscale"])
         with scope(FOLD):
             if getattr(agg, "collect", False):
-                out = agg.reduce(wnp, (nbuf["send"], nbuf["gscale"]))
+                out = agg.reduce(wnp, payload)
             else:
-                def fold_body(acc, xs):
-                    w_c, send_c, gs_c = xs
-                    return agg.accumulate(acc, w_c, (send_c, gs_c)), None
-
+                to_chunks = lambda x: x.reshape((-1, chunk) + x.shape[1:])
                 acc, _ = jax.lax.scan(
-                    fold_body, agg.init(params),
-                    (wnp.reshape(n_chunks, chunk),
-                     jax.tree.map(lambda x: x.reshape(
-                         (n_chunks, chunk) + x.shape[1:]), nbuf["send"]),
-                     nbuf["gscale"].reshape(n_chunks, chunk)))
+                    lambda acc, xs: (agg.accumulate(acc, *xs), None),
+                    agg.init(params), jax.tree.map(to_chunks, (wnp, payload)))
                 out = agg.finalize(acc)
-        dlv = lambda x: x[:K] * deliverf
-        return (out, new_lbg, new_res, nbuf,
-                jax.tree.map(lambda x: x[:K], loss),
-                dlv(nbuf["uplink"]), dlv(nbuf["scalar"]),
-                dlv(nbuf["wire"]))
+        return (out, new_lbg, new_res, nbuf, *jax.tree.map(
+            lambda x: x[:K],
+            (loss, nbuf["uplink"], nbuf["scalar"], nbuf["wire"])))
 
 
 def pick_sharded_chunk(num_clients: int, chunk_size: int, n_dev: int) -> int:
@@ -972,63 +888,64 @@ def pick_sharded_chunk(num_clients: int, chunk_size: int, n_dev: int) -> int:
 @register_scheduler("sharded")
 class ShardedScheduler(ChunkedScheduler):
     """Chunked layout with each block mapped over the 2-D ``(clients,
-    model)`` FL mesh: the same (n_chunks, chunk) ``lax.scan``, but every
-    chunk's clients train data-parallel under ``shard_map`` along the
-    ``clients`` axis (``FLConfig.mesh``, resolved by
-    ``launch.mesh.make_fl_mesh``), so the per-DEVICE transient set is
-    O(chunk·M / n_clients_dev); with a 2-D spec (``mesh=[c, m]``) the
-    LBGM decision and the sparse banks/aggregator carry additionally
-    shard their block rows over the ``model`` axis, dropping per-device
-    bank bytes to O(K·k_frac·M / (c·m)) for the >=34B-style configs where
-    the look-back bank dominates memory.
+    model)`` FL mesh: :class:`ChunkedScheduler`'s scan of
+    :func:`chunk_step`, with the step run under ``shard_map`` so every
+    chunk's clients train data-parallel along the ``clients`` axis
+    (``FLConfig.mesh``, resolved by ``launch.mesh.make_fl_mesh``): the
+    per-DEVICE transient set is O(chunk·M / n_clients_dev). With a 2-D
+    spec (``mesh=[c, m]``) the LBGM decision and the sparse
+    banks/aggregator carry additionally shard their block rows over the
+    ``model`` axis, dropping per-device bank bytes to
+    O(K·k_frac·M / (c·m)) for the >=34B-style configs where the look-back
+    bank dominates memory.
 
     State banks are stored ``(n_chunks, chunk, ...)`` with the chunk's
     client axis sharded over the mesh — and, for a model-sharded sparse
     bank (see :meth:`configure_store` / ``bank_model_partition``), the
-    block-row axis over ``model`` — so the per-chunk bank slice/update
-    and the LBGM accept/recycle decision read only device-local rows; the
+    block-row axis over ``model`` — so the per-chunk bank read/write and
+    the LBGM accept/recycle decision touch only device-local rows; the
     cross-device traffic per chunk is one fp32 psum of the weighted
     aggregate along ``clients`` (plus loss/uplink scalars) and, when
     model-sharded, the three decision scalars psum'd along ``model``
     inside the store's step.
 
-    Device 0 of the client axis folds the scan carry into its local
-    strictly-sequential accumulation, so on a (1, 1) mesh the addition
-    order — and therefore the whole round history — is bit-identical to
-    ``ChunkedScheduler`` (and an ``(n, 1)`` mesh is bit-identical to the
-    pre-2-D 1-D client mesh); on larger meshes the psum reassociates the
-    sum across devices, the documented fp32-tolerance difference (uplink
-    accounting is still exact: the global block layout is mesh-shape
-    independent).
+    The step's fold seeds client-device 0 with the scan carry and psums
+    over ``clients`` (:meth:`_psum_fold`), so on a (1, 1) mesh the
+    addition order — and therefore the whole round history — is
+    bit-identical to ``ChunkedScheduler`` (and an ``(n, 1)`` mesh is
+    bit-identical to the pre-2-D 1-D client mesh); on larger meshes the
+    psum reassociates the sum across devices, the documented
+    fp32-tolerance difference (uplink accounting is still exact: the
+    global block layout is mesh-shape independent).
     """
 
-    AXIS = "clients"
-    MODEL_AXIS = "model"
+    client_axis = "clients"
+    model_axis = "model"
 
     def __init__(self, cfg: FLConfig, num_clients: int):
         from repro.launch.mesh import make_fl_mesh
-        self.mesh = make_fl_mesh(cfg.mesh, client_axis=self.AXIS,
-                                 model_axis=self.MODEL_AXIS)
-        self.n_client_dev = int(self.mesh.shape[self.AXIS])
-        self.n_model = int(self.mesh.shape[self.MODEL_AXIS])
+        self.mesh = make_fl_mesh(cfg.mesh, client_axis=self.client_axis,
+                                 model_axis=self.model_axis)
+        self.n_client_dev = int(self.mesh.shape[self.client_axis])
+        self.n_model = int(self.mesh.shape[self.model_axis])
         self.n_dev = int(self.mesh.devices.size)
         self.num_clients = num_clients
         self.chunk = pick_sharded_chunk(num_clients, cfg.chunk_size,
                                         self.n_client_dev)
         self.pad = (-num_clients) % self.chunk
-        # set by configure_store when the LBG bank model-shards: per-leaf
-        # {name: bool} for the sparse bank's block rows, mirrored onto the
-        # aggregator carry; None = everything model-replicated (the 1-D
-        # client-mesh behavior)
-        self._msharded: Optional[Dict[str, bool]] = None
-        # set by bind_model_axes (model_sharding="auto"): per-leaf param
-        # PartitionSpecs resolved from the model component's logical axes,
-        # the matching NamedShardings (the engine places/keeps params with
-        # them), and the global name -> (nb, block, kb) block layouts the
-        # auto chunk body reshapes gradients into. None = "replicate".
-        self._auto_specs: Optional[Dict[str, P]] = None
-        self._layouts = None
+        #: set by configure_store when the LBG bank model-shards: per-leaf
+        #: {name: bool} for the sparse bank's block rows, mirrored onto
+        #: the aggregator carry; None = everything model-replicated (the
+        #: 1-D client-mesh behavior)
+        self.model_sharded: Optional[Dict[str, bool]] = None
+        #: set by bind_model_axes (model_sharding="auto"): per-leaf param
+        #: NamedShardings resolved from the model component's logical
+        #: axes (the engine places and keeps params with them). None =
+        #: "replicate".
         self.param_shardings: Optional[Dict[str, NamedSharding]] = None
+        # the global name -> (nb, block, kb) block layouts the auto chunk
+        # function reshapes gradients into (bind_model_axes)
+        self._layouts = None
 
     # ----------------------------------------------------- model binding
     def configure_store(self, store, sparse_agg: bool, params) -> None:
@@ -1044,7 +961,7 @@ class ShardedScheduler(ChunkedScheduler):
         """
         if (self.n_model > 1 and sparse_agg
                 and hasattr(store, "bank_model_partition")):
-            self._msharded = store.bank_model_partition(params)
+            self.model_sharded = store.bank_model_partition(params)
 
     def bind_model_axes(self, axes_tree, params, layouts) -> None:
         """Switch this scheduler into ``model_sharding="auto"``.
@@ -1056,7 +973,7 @@ class ShardedScheduler(ChunkedScheduler):
         FL mesh has no fsdp "data" axis — only the model-parallel axes
         shard, and only where the mesh extent divides). ``layouts`` is the
         engine-computed global ``name -> (nb, block, kb)`` block layout
-        the auto chunk body (and the store's ``blocked_sparse_step``)
+        the auto chunk function (and the store's ``blocked_sparse_step``)
         share, so the gradient reshape and the decision slicing agree by
         construction.
 
@@ -1076,7 +993,7 @@ class ShardedScheduler(ChunkedScheduler):
                 f"model_sharding='auto': the model component's axes tree "
                 f"is missing leaves {missing} — every param leaf needs a "
                 "logical-axis tuple (see train.sharding.params_shardings)")
-        m = self.mesh.shape.get(self.MODEL_AXIS, 1)
+        m = self.mesh.shape.get(self.model_axis, 1)
 
         def leaf_spec(name):
             axes = tuple(axes_tree[name])
@@ -1085,28 +1002,27 @@ class ShardedScheduler(ChunkedScheduler):
                 out, used = [], False
                 for logical, dim in zip(axes, shape):
                     if logical == "embed" and not used and dim % m == 0:
-                        out.append(self.MODEL_AXIS)
+                        out.append(self.model_axis)
                         used = True
                     else:
                         out.append(None)
                 return P(*out)
             return param_pspec(axes, shape, "replicated", self.mesh)
 
-        self._auto_specs = {name: leaf_spec(name) for name in params}
         self._layouts = layouts
         self.param_shardings = {
-            name: NamedSharding(self.mesh, spec)
-            for name, spec in self._auto_specs.items()}
+            name: NamedSharding(self.mesh, leaf_spec(name))
+            for name in params}
 
     def _bank_leaf_spec(self, path, chunk_leading: bool):
         """PartitionSpec for one bank leaf; ``path`` is the jax key path
         ((name,) for a dense bank leaf, (name, 'idx'|'val') for a sparse
         one). ``chunk_leading=True`` adds the scan's n_chunks axis."""
-        ms = self._msharded or {}
+        ms = self.model_sharded or {}
         name = path[0].key if path else None
-        axes = (self.AXIS,)
+        axes = (self.client_axis,)
         if len(path) == 2 and ms.get(name):
-            axes = (self.AXIS, self.MODEL_AXIS)
+            axes = (self.client_axis, self.model_axis)
         return P(None, *axes) if chunk_leading else P(*axes)
 
     def _payload_specs(self, agg, lbg):
@@ -1117,10 +1033,10 @@ class ShardedScheduler(ChunkedScheduler):
         a quantized payload carries a per-block-row ``scale`` leaf the
         bank does not have, so the bank's spec tree cannot be reused
         verbatim when the bank model-shards."""
-        ms = self._msharded or {}
+        ms = self.model_sharded or {}
         pk = getattr(agg, "payload_keys", ("idx", "val"))
-        spec = lambda name: (P(self.AXIS, self.MODEL_AXIS) if ms.get(name)
-                             else P(self.AXIS))
+        spec = lambda name: (P(self.client_axis, self.model_axis)
+                             if ms.get(name) else P(self.client_axis))
         return {name: {k: spec(name) for k in pk} for name in lbg}
 
     # ------------------------------------------------------ bank placement
@@ -1130,7 +1046,7 @@ class ShardedScheduler(ChunkedScheduler):
 
         The round scan indexes whole chunks (axis 0), so sharding axis 1
         over the mesh puts every chunk's bank rows exactly where its
-        clients train — per-chunk slice/update never moves bank bytes
+        clients train — per-chunk read/write never moves bank bytes
         between devices."""
         def f(path, x):
             x = x.reshape((x.shape[0] // self.chunk, self.chunk)
@@ -1141,187 +1057,136 @@ class ShardedScheduler(ChunkedScheduler):
             return x
         return jax.tree_util.tree_map_with_path(f, bank)
 
-    def run(self, client_fn, agg, params, batch, lbg, resid, w, maskf):
-        K, chunk, pad, ax = self.num_clients, self.chunk, self.pad, self.AXIS
-        if pad:
-            w = jnp.concatenate([w, jnp.zeros(pad, w.dtype)])
-            maskf = jnp.concatenate([maskf, jnp.zeros(pad, maskf.dtype)])
-        Kp = K + pad
-        n_chunks = Kp // chunk
-        rep, cl = P(), P(ax)
-        # per-leaf specs: sparse-bank leaves may shard block rows over the
-        # model axis; the aggregator carry mirrors the same partition (its
-        # (nb, block) leaves hold the rows the local sends scatter into)
-        ms = self._msharded
-        lbg_specs = jax.tree_util.tree_map_with_path(
-            lambda path, _: self._bank_leaf_spec(path, False), lbg) \
-            if ms else cl
-        acc_specs = {name: P(self.MODEL_AXIS) if on else rep
-                     for name, on in ms.items()} if ms else rep
-
-        auto = self._auto_specs is not None
-        if auto:
-            # model_sharding="auto": the per-chunk client compute runs as
-            # plain GSPMD — no enclosing shard_map — with the params
-            # constrained to the component's resolved tensor-parallel
-            # specs and the batch constrained along `clients`, so the
-            # vmapped local-SGD forward/backward partitions over the full
-            # 2-D mesh. (An enclosing partial-auto shard_map is NOT an
-            # option: `lax.scan` bodies — the layer stack, tau local-SGD,
-            # chunked CE — trip the SPMD partitioner's manual-subgroup
-            # checks, as do top_k/scatter.) The Algorithm-1 decision +
-            # aggregation then run in ONE fully-manual shard_map over
-            # (clients, model): its in_specs hand each rank exactly the
-            # bank/accumulator/block rows the "replicate" path owns, and
-            # GSPMD implements the one TP-layout -> block-row reshard of
-            # the round at that boundary. Banks and the aggregation carry
-            # keep the exact "replicate" placement and the global block
-            # layout is unchanged, so uplink accounting is identical;
-            # histories match within fp32 reassociation tolerance.
-            pre, post = client_fn
-            mesh, MX = self.mesh, self.MODEL_AXIS
-            pspecs, layouts, msd = self._auto_specs, self._layouts, ms or {}
-            blk_spec = lambda name: (P(ax, MX, None) if msd.get(name)
-                                     else P(ax))
-
-            def manual_fn(acc_i, blk, l_, cost_, thru_, w_, m_):
-                gt, nl_, uplink, scalar, wire = jax.vmap(post)(
-                    blk, l_, cost_, thru_)
-                # identical carry seeding + clients psum to the
-                # "replicate" local_chunk below, so per-chunk accumulation
-                # order matches ChunkedScheduler
-                with scope(FOLD):
-                    first = jax.lax.axis_index(ax) == 0
-                    acc_i = jax.tree.map(
-                        lambda a: jnp.where(first, a, 0.0), acc_i)
-                    acc_i = jax.lax.psum(agg.accumulate(acc_i, w_, gt), ax)
-                    nl_ = _keep_sampled(m_, nl_, l_)
-                return acc_i, nl_, uplink, scalar, wire
-
-            def sharded_chunk(acc, p, b, l, r, w_c, m_c):
-                cst = lambda v, s: jax.lax.with_sharding_constraint(
-                    v, NamedSharding(mesh, s))
-                p = jax.tree.map(cst, p, pspecs)
-                b = jax.tree.map(lambda v: cst(v, P(ax)), b)
-                asg, nr, loss, cost, thru = jax.vmap(
-                    lambda bb, rr: pre(p, bb, rr))(b, r)
-                loss = jax.tree.map(lambda v: cst(v, P(ax)), loss)
-                blocked = {
-                    name: jax.vmap(
-                        lambda g, nb=layouts[name][0],
-                        blk=layouts[name][1]:
-                        lbgm_lib._to_blocks(g, nb, blk))(asg[name])
-                    for name in asg}
-                manual = jax.shard_map(
-                    manual_fn, mesh=mesh,
-                    in_specs=(acc_specs,
-                              {name: blk_spec(name) for name in blocked},
-                              lbg_specs, cl,
-                              jax.tree.map(lambda _: cl, thru), cl, cl),
-                    out_specs=(acc_specs, lbg_specs, cl, cl, cl),
-                    check_vma=False)
-                acc, nl, uplink, scalar, wire = manual(
-                    acc, blocked, l, cost, thru, w_c, m_c)
-                with scope(FOLD):
-                    nr = _keep_sampled(m_c, nr, r)
-                return acc, nl, nr, loss, uplink, scalar, wire
-
-        collect = getattr(agg, "collect", False)
-        if auto:
-            pass
-        elif collect:
-            # robust collect mode: no carry to fold — each device emits its
-            # local clients' raw payloads, stitched to the global (chunk,
-            # ...) stack by the out specs (sparse (idx, val) payloads keep
-            # the bank's client/model placement; the weighted reduce runs
-            # once per round on the global stack, outside shard_map)
-            def local_chunk(p, b, l, r, w_c, m_c):
-                gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
-                    lambda bb, ll, rr: client_fn(p, bb, ll, rr))(b, l, r)
-                with scope(FOLD):
-                    nl = _keep_sampled(m_c, nl, l)
-                    nr = _keep_sampled(m_c, nr, r)
-                return gt, nl, nr, loss, uplink, scalar, wire
-
-            if getattr(agg, "sparse", False):
-                gt_specs = ((self._payload_specs(agg, lbg) if ms else cl),
-                            cl)
-            else:
-                gt_specs = cl
-            sharded_chunk = jax.shard_map(
-                local_chunk, mesh=self.mesh,
-                in_specs=(rep, cl, lbg_specs, cl, cl, cl),
-                out_specs=(gt_specs, lbg_specs, cl, cl, cl, cl, cl),
-                check_vma=False)
-        else:
-            def local_chunk(acc, p, b, l, r, w_c, m_c):
-                gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
-                    lambda bb, ll, rr: client_fn(p, bb, ll, rr))(b, l, r)
-                # client-device 0 seeds its local accumulation with the
-                # scan carry, so each chunk folds into the aggregate in the
-                # same strictly sequential order as ChunkedScheduler; the
-                # psum is the identity on a 1-device client axis (the carry
-                # — dense params-shaped or sparse block-layout, per the
-                # aggregator — is replicated along `clients`; model-sharded
-                # carry leaves hold disjoint rows per model rank, never
-                # summed over model)
-                with scope(FOLD):
-                    first = jax.lax.axis_index(ax) == 0
-                    acc = jax.tree.map(lambda a: jnp.where(first, a, 0.0),
-                                       acc)
-                    acc = jax.lax.psum(agg.accumulate(acc, w_c, gt), ax)
-                    nl = _keep_sampled(m_c, nl, l)
-                    nr = _keep_sampled(m_c, nr, r)
-                return acc, nl, nr, loss, uplink, scalar, wire
-
-            sharded_chunk = jax.shard_map(
-                local_chunk, mesh=self.mesh,
-                in_specs=(acc_specs, rep, cl, lbg_specs, cl, cl, cl),
-                out_specs=(acc_specs, lbg_specs, cl, cl, cl, cl, cl),
-                check_vma=False)
-
-        idx_at = lambda t, i: jax.tree.map(
+    def _read(self, bank, i):
+        return jax.tree.map(
             lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False),
-            t)
-        put_at = lambda t, u, i: jax.tree.map(
+            bank)
+
+    def _write(self, bank, rows, i):
+        return jax.tree.map(
             lambda x, v: jax.lax.dynamic_update_index_in_dim(x, v, i, 0),
-            t, u)
+            bank, rows)
 
-        def chunk_body(carry, xs):
-            acc, lbg_bank, res_bank = carry
-            i, b_c, w_c, m_c = xs
-            l_c, r_c = idx_at(lbg_bank, i), idx_at(res_bank, i)
-            if collect:
-                gt, nl, nr, loss, uplink, scalar, wire = sharded_chunk(
-                    params, b_c, l_c, r_c, w_c, m_c)
-                ys = (loss, uplink, scalar, wire, gt)
-            else:
-                acc, nl, nr, loss, uplink, scalar, wire = sharded_chunk(
-                    acc, params, b_c, l_c, r_c, w_c, m_c)
-                ys = (loss, uplink, scalar, wire)
+    # ------------------------------------------------------- chunk step
+    def _psum_fold(self, agg):
+        """``agg.accumulate`` on each client device, summed over
+        ``clients``. Client-device 0 seeds its local accumulation with
+        the scan carry, so each chunk folds into the aggregate in the
+        same strictly sequential order as :class:`ChunkedScheduler`; the
+        psum is the identity on a 1-device client axis (the carry — dense
+        params-shaped or sparse block-layout, per the aggregator — is
+        replicated along ``clients``; model-sharded carry leaves hold
+        disjoint rows per model rank, never summed over model)."""
+        ax = self.client_axis
+
+        def fold(acc, w_c, gt):
+            first = jax.lax.axis_index(ax) == 0
+            acc = jax.tree.map(lambda a: jnp.where(first, a, 0.0), acc)
+            return jax.lax.psum(agg.accumulate(acc, w_c, gt), ax)
+
+        return fold
+
+    def _specs(self, lbg):
+        """(bank-row specs, aggregator-carry specs): sparse-bank leaves
+        may shard block rows over the model axis, and the carry mirrors
+        the same partition (its (nb, block) leaves hold the rows the
+        local sends fold into)."""
+        ms = self.model_sharded
+        if not ms:
+            return P(self.client_axis), P()
+        lbg_specs = jax.tree_util.tree_map_with_path(
+            lambda path, _: self._bank_leaf_spec(path, False), lbg)
+        return lbg_specs, {name: P(self.model_axis) if on else P()
+                           for name, on in ms.items()}
+
+    def _step_fn(self, client_fn, agg, collect, params, lbg):
+        if self.param_shardings is not None:
+            return self._auto_chunk_fn(client_fn, agg, params, lbg)
+        rep, cl = P(), P(self.client_axis)
+        lbg_specs, acc_specs = self._specs(lbg)
+        out_specs, fold = acc_specs, None
+        if collect:
+            # no carry to fold: each device emits its local clients' raw
+            # payloads, stitched to the global (chunk, ...) stack by the
+            # out specs (sparse payloads keep the bank's client/model
+            # placement; the weighted reduce runs once per round on the
+            # global stack, outside shard_map)
+            acc_specs, out_specs = rep, cl
+            if getattr(agg, "sparse", False):
+                out_specs = ((self._payload_specs(agg, lbg)
+                              if self.model_sharded else cl), cl)
+        else:
+            fold = self._psum_fold(agg)
+        local = jax.shard_map(
+            lambda acc, p, *xs: chunk_step(client_fn, p, fold, acc, *xs),
+            mesh=self.mesh, in_specs=(acc_specs, rep, cl, lbg_specs, cl, cl,
+                                      cl),
+            out_specs=(out_specs, lbg_specs, cl, cl, cl, cl, cl),
+            check_vma=False)
+        return lambda acc, *xs: local(acc, params, *xs)
+
+    def _auto_chunk_fn(self, client_fn, agg, params, lbg):
+        """The chunk function of ``model_sharding="auto"``.
+
+        The per-chunk client compute (``pre``: local SGD, attack,
+        pipeline) runs as plain GSPMD — no enclosing shard_map — with the
+        params constrained to the component's resolved tensor-parallel
+        shardings and the batch constrained along ``clients``, so the
+        vmapped local-SGD forward/backward partitions over the full 2-D
+        mesh. (An enclosing partial-auto shard_map is NOT an option:
+        ``lax.scan`` bodies — the layer stack, tau local-SGD, chunked CE —
+        trip the SPMD partitioner's manual-subgroup checks, as do
+        top_k/scatter.) The decision + codec (``post``) and the fold then
+        run in ONE fully-manual shard_map over (clients, model): its
+        in_specs hand each rank exactly the bank/accumulator/block rows
+        the "replicate" path owns, and GSPMD implements the one TP-layout
+        -> block-row reshard of the round at that boundary. Banks and the
+        aggregation carry keep the exact "replicate" placement and the
+        global block layout is unchanged, so uplink accounting is
+        identical; histories match within fp32 reassociation tolerance.
+        """
+        pre, post = client_fn
+        mesh, ax, layouts = self.mesh, self.client_axis, self._layouts
+        cl = P(ax)
+        lbg_specs, acc_specs = self._specs(lbg)
+        ms = self.model_sharded or {}
+        blk_spec = lambda name: (P(ax, self.model_axis, None)
+                                 if ms.get(name) else cl)
+        fold = self._psum_fold(agg)
+        cst = jax.lax.with_sharding_constraint
+        along_clients = NamedSharding(mesh, cl)
+
+        def manual_fn(acc, blk, l, cost, thru, w_c, m_c):
+            gt, nl, uplink, scalar, wire = jax.vmap(post)(blk, l, cost, thru)
             with scope(FOLD):
-                lbg_bank = put_at(lbg_bank, nl, i)
-                res_bank = put_at(res_bank, nr, i)
-            return (acc, lbg_bank, res_bank), ys
+                acc = fold(acc, w_c, gt)
+                nl = _keep_sampled(m_c, nl, l)
+            return acc, nl, uplink, scalar, wire
 
-        with scope(FOLD):
-            acc0 = jnp.zeros(()) if collect else agg.init(params)
-        (acc, new_lbg, new_res), ys = jax.lax.scan(
-            chunk_body, (acc0, lbg, resid),
-            (jnp.arange(n_chunks), batch, w.reshape(n_chunks, chunk),
-             maskf.reshape(n_chunks, chunk)))
-        with scope(FOLD):
-            if collect:
-                loss, uplink, scalar, wire, gt = ys
-                out = agg.reduce(w, jax.tree.map(
-                    lambda x: x.reshape((-1,) + x.shape[2:]), gt))
-            else:
-                loss, uplink, scalar, wire = ys
-                out = agg.finalize(acc)
-        return (out, new_lbg, new_res,
-                jax.tree.map(lambda x: x.reshape(Kp)[:K], loss),
-                uplink.reshape(Kp)[:K], scalar.reshape(Kp)[:K],
-                wire.reshape(Kp)[:K])
+        def step(acc, b, l, r, w_c, m_c):
+            p = jax.tree.map(cst, params, self.param_shardings)
+            b = jax.tree.map(lambda v: cst(v, along_clients), b)
+            asg, nr, loss, cost, thru = jax.vmap(
+                lambda bb, rr: pre(p, bb, rr))(b, r)
+            loss = jax.tree.map(lambda v: cst(v, along_clients), loss)
+            blocked = {name: jax.vmap(
+                lambda g, lay=layouts[name]: lbgm_lib._to_blocks(
+                    g, *lay[:2]))(asg[name]) for name in asg}
+            manual = jax.shard_map(
+                manual_fn, mesh=mesh,
+                in_specs=(acc_specs,
+                          {name: blk_spec(name) for name in blocked},
+                          lbg_specs, cl, jax.tree.map(lambda _: cl, thru),
+                          cl, cl),
+                out_specs=(acc_specs, lbg_specs, cl, cl, cl),
+                check_vma=False)
+            acc, nl, uplink, scalar, wire = manual(
+                acc, blocked, l, cost, thru, w_c, m_c)
+            with scope(FOLD):
+                nr = _keep_sampled(m_c, nr, r)
+            return acc, nl, nr, loss, uplink, scalar, wire
+
+        return step
 
 
 def make_scheduler(cfg: FLConfig, num_clients: int):
@@ -1509,8 +1374,8 @@ class FLEngine:
             # out-of-core round: one jit'd chunk step (banks/batches
             # arrive per chunk from the streamer thread, donated so the
             # updated chunk reuses the uploaded buffer) + tiny jit'd
-            # weight-prep / finalize helpers replicating round_fn's exact
-            # expressions. No whole-round jit exists on this path.
+            # weight-prep / finalize helpers built from round_fn's own
+            # helpers. No whole-round jit exists on this path.
             self._round = None
             self._chunk_fn = jax.jit(self._build_host_chunk_fn(),
                                      donate_argnums=(1, 2))
@@ -1670,10 +1535,10 @@ class FLEngine:
         count once), so every mesh shape reports the bytes one client
         ships."""
         codec = self.codec
-        sharded = getattr(self.sched, "_msharded", None) or {}
+        sharded = getattr(self.sched, "model_sharded", None) or {}
         if not any(sharded.values()):
             return codec.encode_sparse
-        axis = self.sched.MODEL_AXIS
+        axis = self.sched.model_axis
 
         def encode(out, new_lbg, stats, seed):
             out, new_lbg, _ = codec.encode_sparse(out, new_lbg, stats, seed)
@@ -1686,60 +1551,19 @@ class FLEngine:
 
         return encode
 
-    def _build_client_halves(self):
-        """``client_fn`` split at the decision seam, for the sharded
-        scheduler's ``model_sharding="auto"`` path.
+    def _client_halves(self, step):
+        """``client_fn`` in two halves, split at the decision seam.
 
-        ``pre`` runs in the outer GSPMD auto region (tensor-parallel
-        local SGD + attack + uplink pipeline — all elementwise on the
-        model-sharded gradients); ``post`` runs inside the nested
-        manual-over-``model`` region on pre-sliced block rows (decision +
-        codec encode — the ops that cannot live in auto-land). Reserved
-        batch keys the decision half still needs (the codec seed) travel
-        in a pass-through dict. The composition is semantically
-        :meth:`_build_client_fn` restricted to the sparse streaming path,
-        the only one auto mode admits.
-        """
-        store = self.store
-        pipeline = self._pipeline
-        attack = self._payload_attack
-        encode = self._sparse_encoder()
-        client_update = self._make_client_update()
-        blocked_step = store.blocked_sparse_step(self._auto_layouts)
-
-        def pre(params, batches, resid_k):
-            batches = dict(batches)
-            byz = batches.pop(BYZ_KEY, None)
-            thru = {}
-            if WIRE_KEY in batches:
-                thru[WIRE_KEY] = batches.pop(WIRE_KEY)
-            extras = {k: batches.pop(k) for k in list(batches)
-                      if k.startswith("_atk_")}
-            with scope(CLIENT_SGD):
-                asg, loss = client_update(params, batches)
-            if attack is not None:
-                asg = attack.apply(asg, byz, extras)
-            asg, resid_k, cost = pipeline(asg, resid_k)
-            return asg, resid_k, loss, cost, thru
-
-        def post(blocked_k, lbg_k, cost, thru):
-            with scope(DECISION):
-                gt, lbg_k, stats = blocked_step(blocked_k, lbg_k)
-                uplink = jnp.where(stats.sent_scalar, 1.0,
-                                   store.full_round_cost(cost, stats))
-            with scope(CODEC):
-                gt, lbg_k, wire = encode(gt, lbg_k, stats,
-                                         thru.get(WIRE_KEY))
-            return gt, lbg_k, uplink, stats.sent_scalar, wire
-
-        return pre, post
-
-    def _build_client_fn(self):
+        ``pre(params, batches, resid_k) -> (asg, resid_k, loss, cost,
+        thru)`` strips the engine-reserved batch keys and runs local SGD,
+        the attack and the uplink pipeline; ``thru`` carries the
+        per-client codec seed on to ``post(asg, lbg_k, cost, thru) ->
+        (gt, lbg_k, uplink, sent_scalar, wire)``, which runs the store's
+        decision ``step``, the uplink count and the codec encode."""
         pipeline = self._pipeline
         store = self.store
         hetero_tau = self._tau_vec is not None
         client_update = self._make_client_update(hetero_tau)
-
         sparse = self._sparse_agg
         attack = self._payload_attack
         codec = self.codec
@@ -1755,14 +1579,16 @@ class FLEngine:
                 [lbgm_lib._block_layout(int(p.size), store.k_frac)[::2]
                  for p in self.params.values()])
 
-        def client_fn(params, batches, lbg_k, resid_k):
+        def pre(params, batches, resid_k):
             # engine-reserved batch keys (Byzantine flag, per-round attack
-            # extras, per-client wire-codec seed) ride the batch dict
-            # through every scheduler layout and the prefetcher; strip
-            # them before the local-SGD scan
+            # extras, per-client wire-codec seed, local-step budget) ride
+            # the batch dict through every scheduler layout and the
+            # prefetcher; strip them before the local-SGD scan
             batches = dict(batches)
             byz = batches.pop(BYZ_KEY, None)
-            wire_seed = batches.pop(WIRE_KEY, None)
+            thru = {}
+            if WIRE_KEY in batches:
+                thru[WIRE_KEY] = batches.pop(WIRE_KEY)
             tau_k = batches.pop(TAU_KEY, None)
             extras = {k: batches.pop(k) for k in list(batches)
                       if k.startswith("_atk_")}
@@ -1779,10 +1605,12 @@ class FLEngine:
                 # adversary would produce them
                 asg = attack.apply(asg, byz, extras)
             asg, resid_k, cost = pipeline(asg, resid_k)
+            return asg, resid_k, loss, cost, thru
+
+        def post(asg, lbg_k, cost, thru):
             # sparse aggregation: gt is the ((idx, val) payload, gscale)
-            # pair the SparseTopKAggregator scatter-adds — the dense
-            # g_tilde is never materialized
-            step = store.sparse_client_step if sparse else store.client_step
+            # pair the sparse aggregators fold — the dense g_tilde is
+            # never materialized
             with scope(DECISION):
                 gt, lbg_k, stats = step(asg, lbg_k)
                 # scalar rounds upload 1 float; full rounds pay the base
@@ -1793,6 +1621,7 @@ class FLEngine:
             # (and, for lossy codecs, re-point the bank at the values the
             # server will decode) + account the real bytes on the wire
             with scope(CODEC):
+                wire_seed = thru.get(WIRE_KEY)
                 if sparse:
                     gt, lbg_k, wire = encode(gt, lbg_k, stats, wire_seed)
                 elif sparse_wire is not None:
@@ -1800,19 +1629,37 @@ class FLEngine:
                                      sparse_wire)
                 else:
                     gt, wire = codec.encode_dense(gt, uplink, wire_seed)
-            return (gt, lbg_k, resid_k, loss, uplink, stats.sent_scalar,
-                    wire)
+            return gt, lbg_k, uplink, stats.sent_scalar, wire
+
+        return pre, post
+
+    def _build_client_fn(self):
+        """One client's round, ``post(pre(...))`` with the store's
+        decision step: ``(gt, new_lbg, new_resid, loss, uplink,
+        sent_scalar, wire)``."""
+        store = self.store
+        pre, post = self._client_halves(
+            store.sparse_client_step if self._sparse_agg
+            else store.client_step)
+
+        def client_fn(params, batches, lbg_k, resid_k):
+            asg, resid_k, loss, cost, thru = pre(params, batches, resid_k)
+            gt, lbg_k, uplink, scalar, wire = post(asg, lbg_k, cost, thru)
+            return gt, lbg_k, resid_k, loss, uplink, scalar, wire
 
         return client_fn
 
     def _build_round(self):
-        cfg = self.cfg
-        auto = getattr(self.sched, "_auto_specs", None) is not None
-        client_fn = (self._build_client_halves() if auto
-                     else self._build_client_fn())
-        sched = self.sched
-        aggregator = self.agg
-        pshard = self.sched.param_shardings if auto else None
+        cfg, sched, aggregator = self.cfg, self.sched, self.agg
+        pshard = getattr(sched, "param_shardings", None)
+        if pshard is not None:
+            # model_sharding="auto": the scheduler runs ``pre`` in the
+            # GSPMD region and ``post`` in the manual-over-``model``
+            # region, on gradients already in block-row layout
+            client_fn = self._client_halves(
+                self.store.blocked_sparse_step(self._auto_layouts))
+        else:
+            client_fn = self._build_client_fn()
 
         if self._latency is not None:
             disc = self._latency.staleness_weight
@@ -1834,18 +1681,9 @@ class FLEngine:
                  scalar, wire) = sched.run_buffered(
                     client_fn, aggregator, params, batch, lbg, residual,
                     buf, w0, dispatchf, deliverf, stalef, disc)
-                with scope(FOLD):
-                    new_params = jax.tree.map(
-                        lambda p, a: p - cfg.lr * a.astype(p.dtype), params,
-                        agg_out)
-                metrics = {
-                    "loss": jnp.sum(losses["loss"] * wl),
-                    "uplink_floats": jnp.sum(uplink),
-                    "frac_scalar": jnp.sum(scalar)
-                    / jnp.maximum(jnp.sum(deliverf), 1.0),
-                    "wire_bytes": jnp.sum(wire),
-                    **_summed_counters(losses, dispatchf),
-                }
+                new_params = server_step(cfg.lr, params, agg_out)
+                metrics = round_metrics(losses, wl, uplink, scalar, wire,
+                                        deliverf, dispatchf)
                 return new_params, new_lbg, new_res, new_buf, metrics
 
             return round_fn
@@ -1856,95 +1694,59 @@ class FLEngine:
             permanently padded to the chunk grid (zero-weight phantom
             clients, always masked out); the scheduler pads the small
             per-round vectors itself."""
-            maskf = mask.astype(jnp.float32)
-            w = self.weights * maskf
-            w = w / jnp.maximum(jnp.sum(w), 1e-12)
+            maskf, w = round_weights(self.weights, mask)
             agg, new_lbg, new_res, losses, uplink, scalar, wire = sched.run(
                 client_fn, aggregator, params, batch, lbg, residual, w,
                 maskf)
-            with scope(FOLD):
-                new_params = jax.tree.map(
-                    lambda p, a: p - cfg.lr * a.astype(p.dtype), params, agg)
+            new_params = server_step(cfg.lr, params, agg)
             if pshard is not None:
                 # keep the updated params on their TP layout round over
                 # round (the donated input buffers are reused in place)
                 new_params = jax.tree.map(
                     jax.lax.with_sharding_constraint, new_params, pshard)
-            metrics = {
-                "loss": jnp.sum(losses["loss"] * w),
-                "uplink_floats": jnp.sum(uplink * maskf),
-                "frac_scalar": jnp.sum(scalar.astype(jnp.float32) * maskf)
-                / jnp.maximum(jnp.sum(maskf), 1.0),
-                "wire_bytes": jnp.sum(wire * maskf),
-                **_summed_counters(losses, maskf),
-            }
+            metrics = round_metrics(losses, w, uplink, scalar, wire, maskf)
             return new_params, new_lbg, new_res, metrics
 
         return round_fn
 
     # ------------------------------------------------ out-of-core (host)
     def _build_host_chunk_fn(self):
-        """One chunk of the topk-host round — the body is op-for-op
-        :class:`ChunkedScheduler`'s ``chunk_body`` (vmap'd client_fn,
-        the aggregator's sequential accumulate, ``_keep_sampled`` bank
-        gating), compiled standalone so the only device-resident bank
-        state is the active chunk's rows. ``acc`` and ``lbg_c`` are
+        """One chunk of the topk-host round: :func:`chunk_step` with the
+        aggregator's fold, compiled standalone so the only
+        device-resident bank state is the active chunk's rows. The store
+        keeps no residual (no error feedback), so the chunk's new bank
+        rows are the only state written back. ``acc`` and ``lbg_c`` are
         donated: the updated chunk reuses the uploaded buffer."""
         client_fn = self._build_client_fn()
-        agg = self.agg
+        fold = self.agg.accumulate
 
         def chunk_fn(params, acc, lbg_c, resid_c, b_c, w_c, m_c):
-            gt, nl, nr, loss, uplink, scalar, wire = jax.vmap(
-                lambda b, l, r: client_fn(params, b, l, r))(
-                    b_c, lbg_c, resid_c)
-            with scope(FOLD):
-                acc = agg.accumulate(acc, w_c, gt)
-                nl = _keep_sampled(m_c, nl, lbg_c)
+            acc, nl, _, loss, uplink, scalar, wire = chunk_step(
+                client_fn, params, fold, acc, b_c, lbg_c, resid_c, w_c, m_c)
             return acc, nl, loss, uplink, scalar, wire
 
         return chunk_fn
 
     def _build_host_prep(self):
-        """Round weights for the host chunk loop — the same expressions
-        (and therefore float rounding) as ``round_fn`` + the chunked
-        scheduler's zero-padding."""
-        weights = self.weights
-        pad, chunk = self._pad, self._chunk
-
+        """Round weights for the host chunk loop, as ``round_fn`` and
+        the chunked scheduler compute them."""
         def prep(mask):
-            maskf = mask.astype(jnp.float32)
-            w = weights * maskf
-            w = w / jnp.maximum(jnp.sum(w), 1e-12)
-            wp, mp = w, maskf
-            if pad:
-                wp = jnp.concatenate([w, jnp.zeros(pad, w.dtype)])
-                mp = jnp.concatenate([maskf, jnp.zeros(pad, maskf.dtype)])
-            n_chunks = wp.shape[0] // chunk
-            return (wp.reshape(n_chunks, chunk),
-                    mp.reshape(n_chunks, chunk), w, maskf)
+            maskf, w = round_weights(self.weights, mask)
+            wp, mp = _pad_to_grid(self._pad, w, maskf)
+            return (wp.reshape(-1, self._chunk), mp.reshape(-1, self._chunk),
+                    w, maskf)
 
         return prep
 
     def _build_host_final(self):
-        """Params update + round metrics — ``round_fn``'s exact
-        expressions over the concatenated per-chunk outputs."""
-        cfg = self.cfg
-        agg = self.agg
-
+        """Params update + round metrics over the concatenated per-chunk
+        outputs, as ``round_fn`` computes them."""
         def final(params, acc, losses, uplink, scalar, wire, w, maskf):
             with scope(FOLD):
-                out = agg.finalize(acc)
-                new_params = jax.tree.map(
-                    lambda p, a: p - cfg.lr * a.astype(p.dtype), params, out)
-            metrics = {
-                "loss": jnp.sum(losses["loss"] * w),
-                "uplink_floats": jnp.sum(uplink * maskf),
-                "frac_scalar": jnp.sum(scalar.astype(jnp.float32) * maskf)
-                / jnp.maximum(jnp.sum(maskf), 1.0),
-                "wire_bytes": jnp.sum(wire * maskf),
-                **_summed_counters(losses, maskf),
-            }
-            return new_params, metrics
+                out = self.agg.finalize(acc)
+            new_params = server_step(self.cfg.lr, params, out)
+            return new_params, round_metrics(losses, w, uplink, scalar,
+                                             wire, maskf)
 
         return final
 

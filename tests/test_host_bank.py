@@ -4,7 +4,7 @@ streamed chunk-wise to the device.
 Acceptance (ISSUE 10 tentpole):
   * ``lbg_variant="topk-host"`` reproduces ``"topk"`` round histories,
     final params AND final banks *bit-for-bit* on the chunked scheduler
-    (the chunk computation is op-for-op the chunked scan body), composing
+    (each chunk runs the chunked scheduler's one ``chunk_step``), composing
     with device sampling, codecs and hierarchical tiers;
   * per-round device bank bytes are O(chunk_size) — independent of
     ``num_clients`` (compiled-envelope + exact chunk-bytes assertions,

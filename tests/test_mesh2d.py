@@ -212,6 +212,37 @@ def test_1x1_mesh_equals_chunked_bitforbit(fcn_setup):
     _assert_identical_run(fl_c, fl_s, rounds=3)
 
 
+def test_model_sharding_auto_11_matches_replicate():
+    """The auto chunk function (GSPMD client compute, manual decision and
+    fold) on a [1, 1] mesh: the "lm" component gives the same losses,
+    uplink floats and wire bytes as "replicate"."""
+    import dataclasses
+
+    from repro.fed import ExperimentSpec, run_experiment
+    spec_r = ExperimentSpec.from_dict({
+        "name": "lm-auto-11",
+        "model": {"name": "lm",
+                  "kw": {"arch": "yi-34b", "reduced": True,
+                         "vocab_size": 1024}},
+        "data": {"name": "markov", "kw": {"n": 64, "n_eval": 0,
+                                          "seq_len": 32, "vocab": 1024}},
+        "partition": {"name": "iid", "kw": {}},
+        "fl": {"num_clients": 4, "tau": 2, "lr": 0.02, "batch_size": 4,
+               "use_lbgm": True, "delta_threshold": 0.5, "seed": 0,
+               "scheduler": "sharded", "chunk_size": 2, "mesh": [1, 1],
+               "lbg_variant": "topk-sharded", "lbg_kw": {"k_frac": 0.01}},
+        "rounds": 2,
+        "eval": {"every": 0, "final": False, "verbose": False},
+    })
+    spec_a = dataclasses.replace(
+        spec_r, fl=dataclasses.replace(spec_r.fl, model_sharding="auto"))
+    res_r, res_a = run_experiment(spec_r), run_experiment(spec_a)
+    assert len(res_r.records) == len(res_a.records) == 2
+    for a, b in zip(res_r.records, res_a.records):
+        assert (a.loss, a.uplink_floats, a.wire_bytes) == \
+            (b.loss, b.uplink_floats, b.wire_bytes), (a, b)
+
+
 def test_int_mesh_equals_2d_mesh_bitforbit(fcn_setup):
     """Compatibility rule: mesh=n is exactly mesh=[n, 1]."""
     kw = dict(use_lbgm=True, delta_threshold=0.2, chunk_size=5,
@@ -328,7 +359,7 @@ f81 = eng(scheduler="sharded", mesh=[8, 1], lbg_variant="topk-sharded")
 f24 = eng(scheduler="sharded", mesh=[2, 4], lbg_variant="topk-sharded")
 
 # --- bank placement: both axes, exactly where the issue says
-ms = f24.sched._msharded
+ms = f24.sched.model_sharded
 assert ms["fc1/w"] is True and ms["fc1/b"] is False, ms
 specs = {k: str(l["idx"].sharding.spec) for k, l in f24.lbg.items()}
 assert specs["fc1/w"] == "PartitionSpec(None, 'clients', 'model')", specs
@@ -462,7 +493,8 @@ spec_a = dataclasses.replace(
 # hold 1/M of their rows, vocab-axis leaves shard along d_model (their
 # gathers must stay device-local), norms replicate
 eng_a, _ = build_experiment(spec_a)
-specs = eng_a.sched._auto_specs
+specs = {k: s.spec
+         for k, s in eng_a.sched.param_shardings.items()}
 tot = loc = 0
 sharded_leaves = 0
 for k, v in eng_a.params.items():
